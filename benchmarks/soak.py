@@ -33,14 +33,8 @@ def main() -> None:
                     help="sweeps per throughput window")
     args = ap.parse_args()
 
-    from openr_tpu.ops.platform_env import (
-        enable_persistent_compile_cache,
-        fallback_to_cpu_if_unreachable,
-        honor_cpu_platform_request,
-    )
+    from openr_tpu.ops.platform_env import enable_persistent_compile_cache
 
-    honor_cpu_platform_request()
-    fallback_to_cpu_if_unreachable()
     enable_persistent_compile_cache()
 
     import jax

@@ -403,16 +403,15 @@ MANIFEST: Tuple[ArtifactSpec, ...] = (
         family="legacy_headline",
         pattern=r"BENCH_r(\d+)\.json",
         description=(
-            "rounds 1-5 of the 10k x 1024-node what-if headline "
-            "(harness capture: cmd/rc/tail + the parsed JSON line); "
-            "metric definitions evolved round to round, so the "
-            "trajectory is annotated history, never ratcheted"
+            "round 5 of the 10k x 1024-node what-if headline "
+            "(harness capture: cmd/rc/tail + the parsed JSON line; a "
+            "CPU capture, so annotated history, never ratcheted)"
         ),
         validate=_validate_legacy,
         headline=(
             HeadlineMetric("parsed.value", HIGHER, ratchet=False),
         ),
-        requires_env=False,  # rounds 1-3 predate the env stamp
+        requires_env=False,  # harness capture: no env stamp
         spoil=_spoil_rc,
     ),
     ArtifactSpec(

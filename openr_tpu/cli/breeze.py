@@ -435,7 +435,7 @@ def monitor_trace(
 
     Each line: indented span name, duration, node/module, and key attrs;
     one tree per trace id, children under their parent span.  See
-    docs/Observability.md for the span taxonomy."""
+    docs/Observability.md for the span catalogue."""
     spans = _call(ctx, "get_traces", trace_id=trace_id, limit=limit)
     if json_out:
         # stable shape (a plain span list) for scripts; the drop

@@ -30,7 +30,7 @@ from typing import Dict, Iterable, List, Optional, Sequence, Set
 
 import numpy as np
 
-from openr_tpu.ops.csr import EncodedMultiArea, bucket_for
+from openr_tpu.ops.csr import CapacityError, EncodedMultiArea, bucket_for
 
 ROW_BUCKETS = (
     64,
@@ -168,7 +168,7 @@ class CandidateTable:
         items = sorted(entries.items())
         if len(items) > self.C:
             if len(items) > self.cand_buckets[-1]:
-                raise ValueError(
+                raise CapacityError(
                     f"prefix with {len(items)} candidates exceeds the "
                     f"largest candidate bucket {self.cand_buckets[-1]}"
                 )
@@ -229,7 +229,7 @@ class CandidateTable:
                 v_minnh.append(entry.min_nexthop or 0)
         if widest > self.C:
             if widest > self.cand_buckets[-1]:
-                raise ValueError(
+                raise CapacityError(
                     f"prefix with {widest} candidates exceeds the largest "
                     f"candidate bucket {self.cand_buckets[-1]}"
                 )

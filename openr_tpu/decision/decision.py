@@ -39,6 +39,7 @@ from openr_tpu.decision.rib import (
 from openr_tpu.decision.rib_policy import RibPolicy
 from openr_tpu.decision.spf_solver import SpfSolver
 from openr_tpu.messaging.queue import RQueue, ReplicateQueue
+from openr_tpu.ops.csr import CapacityError
 from openr_tpu.types import (
     AdjacencyDatabase,
     InitializationEvent,
@@ -1012,7 +1013,7 @@ class Decision(Actor):
                         self.prefix_state,
                         self._change_seq,
                     )
-                except ValueError:  # candidate-bucket overflow → scalar
+                except CapacityError:  # candidate-bucket overflow → scalar
                     db = None
                 if db is not None:
                     return db
@@ -1060,7 +1061,7 @@ class Decision(Actor):
                 self._change_seq,
                 max_pairs=max_pairs,
             )
-        except ValueError:
+        except CapacityError:
             return None
         self.counters.bump("decision.criticality_reports")
         return result
@@ -1220,7 +1221,7 @@ class Decision(Actor):
             # counted only once an answer actually came back
             self.counters.bump(f"decision.whatif.engine.{engine_name}")
             return result
-        except ValueError:
+        except CapacityError:
             # e.g. an anycast prefix wider than the largest candidate
             # bucket.  Multi-area queries previously ANSWERED such
             # configurations through the generic scalar engine — keep
@@ -1394,5 +1395,5 @@ class Decision(Actor):
             return fleet.fleet_summary(
                 self.area_link_states, self.prefix_state, self._change_seq
             )
-        except ValueError:  # candidate-bucket overflow → ineligible
+        except CapacityError:  # candidate-bucket overflow → ineligible
             return None

@@ -77,6 +77,10 @@ class FleetRibEngine:
         self.num_batched_solves = 0
         self.num_decodes = 0
         self.num_pool_dispatches = 0
+        #: ids of the devices the mesh path's outputs landed on (read
+        #: from their shardings) — proves a collective solve used every
+        #: chip of the mesh, not only the first
+        self.mesh_device_ids: set = set()
         self.num_delta_solves = 0
         self.num_delta_roots_fetched = 0
         self.num_delta_roots_skipped = 0
@@ -187,14 +191,6 @@ class FleetRibEngine:
         A = enc.num_areas
         mesh = self._active_mesh()
         mesh_n = mesh.devices.size if mesh is not None else 1
-        if mesh is not None:
-            from openr_tpu.ops.fleet_tables import sharded_fleet_tables
-            from openr_tpu.parallel.mesh import batch_sharding, replicated
-
-            rep = replicated(mesh)
-            dev = {k: jax.device_put(v, rep) for k, v in dev.items()}
-            fleet_fn = sharded_fleet_tables(mesh, D, per_area)
-            roots_sh = batch_sharding(mesh)
         # pool path (no shard_map needed): root chunks spread round-robin
         # over the pool's HEALTHY chips as committed per-device
         # dispatches — a quarantined chip's share re-packs onto the
@@ -226,6 +222,21 @@ class FleetRibEngine:
                 for k in ("src", "dst", "w", "edge_ok"):
                     dev.pop(k)
             dense_keys = True
+        if mesh is not None:
+            from openr_tpu.ops.fleet_tables import sharded_fleet_tables
+            from openr_tpu.parallel.mesh import batch_sharding, replicated
+
+            rep = replicated(mesh)
+            dev = {k: jax.device_put(v, rep) for k, v in dev.items()}
+            roots_sh = batch_sharding(mesh)
+            fleet_fn = sharded_fleet_tables(
+                mesh, D, per_area, dense=bool(dense_keys)
+            )
+            topo_keys = (
+                ("in_src", "in_w", "in_ok", "in_rank", "in_has")
+                if dense_keys
+                else ("src", "dst", "w", "edge_ok")
+            ) + ("overloaded", "soft")
 
         def args_on(idx):
             if idx not in per_dev_args:
@@ -280,12 +291,7 @@ class FleetRibEngine:
                 with self.probe.phase(pipeline.DEVICE_COMPUTE):
                     out = fleet_fn(
                         jax.device_put(padded, roots_sh),
-                        dev["src"],
-                        dev["dst"],
-                        dev["w"],
-                        dev["edge_ok"],
-                        dev["overloaded"],
-                        dev["soft"],
+                        *(dev[k] for k in topo_keys),
                         dev["cand_area"],
                         dev["cand_node"],
                         dev["cand_ok"],
@@ -295,6 +301,9 @@ class FleetRibEngine:
                         dev["distance"],
                         dev["cand_node_in_area"],
                     )
+                self.mesh_device_ids.update(
+                    d.id for d in out[0].sharding.device_set
+                )
             else:
                 if pool_devs is not None:
                     idx = pool_devs[(off // chunk_rows) % len(pool_devs)]

@@ -62,11 +62,20 @@ def _np_ptr(arr: np.ndarray, ctype):
     return arr.ctypes.data_as(ctypes.POINTER(ctype))
 
 
+class CapacityError(ValueError):
+    """The data does not fit the device encoding (a bucket, a candidate
+    table, a metric the device SPF cannot carry): a data-scale limit the
+    caller answers on the scalar path, never a device-health signal.
+    A ``ValueError`` so existing callers still catch it; a bare
+    ``ValueError`` (jaxlib surfaces XLA errors so, and the native fill
+    reports a fault so) is not one."""
+
+
 def bucket_for(value: int, buckets: Sequence[int]) -> int:
     for b in buckets:
         if value <= b:
             return b
-    raise ValueError(f"{value} exceeds largest bucket {buckets[-1]}")
+    raise CapacityError(f"{value} exceeds largest bucket {buckets[-1]}")
 
 
 @dataclasses.dataclass
@@ -204,7 +213,7 @@ def build_in_edge_matrix(
         max_in = 0
     try:
         K = in_degree_bucket or bucket_for(max(max_in, 1), IN_DEGREE_BUCKETS)
-    except ValueError:
+    except CapacityError:
         return None
     if K < max_in:
         return None
@@ -281,9 +290,9 @@ def encode_link_state(
         max(E, 1), [b * edge_multiplier for b in node_buckets]
     )
     if padded_v < V:
-        raise ValueError(f"node bucket {padded_v} < {V} nodes")
+        raise CapacityError(f"node bucket {padded_v} < {V} nodes")
     if padded_e < E:
-        raise ValueError(f"edge bucket {padded_e} < {E} directed edges")
+        raise CapacityError(f"edge bucket {padded_e} < {E} directed edges")
 
     src = np.empty(padded_e, np.int32)
     dst = np.empty(padded_e, np.int32)
@@ -315,7 +324,7 @@ def encode_link_state(
             # metrics (a 0-cost edge would union lanes across equidistant
             # nodes where heap Dijkstra keeps them distinct).  The reference
             # never produces metric<=0 adjacencies; reject at the bridge.
-            raise ValueError(
+            raise CapacityError(
                 "non-positive metric on an up link; device SPF requires "
                 "metrics >= 1"
             )
@@ -325,7 +334,7 @@ def encode_link_state(
     else:
         # vectorized Python fallback (identical semantics)
         if np.any(col_ok[:L].astype(bool) & (col_m[:L] <= 0)):
-            raise ValueError(
+            raise CapacityError(
                 "non-positive metric on an up link; device SPF requires "
                 "metrics >= 1"
             )
@@ -439,7 +448,7 @@ def patch_encoded_topology(
         col_m[li] = link.get_max_metric()
         col_ok[li] = link.is_up()
     if np.any(col_ok[:L].astype(bool) & (col_m[:L] <= 0)):
-        raise ValueError(
+        raise CapacityError(
             "non-positive metric on an up link; device SPF requires "
             "metrics >= 1"
         )
@@ -599,7 +608,7 @@ def patch_encoded_topology_slots(
         col_m[li] = lk.get_max_metric()
         col_ok[li] = lk.is_up()
     if np.any(col_ok[:n_rows] & (col_m[:n_rows] <= 0)):
-        raise ValueError(
+        raise CapacityError(
             "non-positive metric on an up link; device SPF requires "
             "metrics >= 1"
         )
@@ -775,7 +784,7 @@ def encode_prefix_candidates(
     that fits the widest prefix (anycast prefixes advertised by many
     nodes), so the jit cache stays warm while wide prefixes still get the
     device path; `max_candidates` pins the width explicitly instead.
-    Raises ValueError past the largest bucket (caller falls back scalar).
+    Raises CapacityError past the largest bucket (caller falls back scalar).
     """
     prefixes = sorted(prefix_state.prefixes().keys())
     P = max(len(prefixes), 1)
@@ -804,7 +813,7 @@ def encode_prefix_candidates(
             if parea != area or node not in topo.node_ids:
                 continue
             if c >= C:
-                raise ValueError(
+                raise CapacityError(
                     f"prefix {prefix}: more than {C} candidates; raise "
                     "max_candidates"
                 )

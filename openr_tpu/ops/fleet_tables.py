@@ -296,13 +296,20 @@ def whatif_multi_area_tables(
 _sharded_cache: dict = {}
 
 
-def sharded_fleet_tables(mesh, max_degree: int, per_area_distance: bool):
+def sharded_fleet_tables(
+    mesh, max_degree: int, per_area_distance: bool, dense: bool = False
+):
     """Root-batch-sharded fleet kernel over a device mesh.
 
     Vantage roots are independent solves, so each device runs the exact
     single-device program on its contiguous root shard (no collectives);
     topology + candidate tables replicate.  Root batches must be
     multiples of the mesh size.  Bit-identical to the unsharded kernel.
+
+    Called as ``fn(roots, *topology, *candidate_tables)``: the topology
+    is ``(src, dst, w, edge_ok, overloaded, soft)``, or with ``dense``
+    the in-edge planes ``(in_src, in_w, in_ok, in_rank, in_has,
+    overloaded, soft)`` of :func:`fleet_multi_area_tables_dense`.
     """
     import functools
 
@@ -310,25 +317,27 @@ def sharded_fleet_tables(mesh, max_degree: int, per_area_distance: bool):
 
     from openr_tpu.parallel.mesh import BATCH_AXIS
 
-    key = (mesh, max_degree, per_area_distance)
+    key = (mesh, max_degree, per_area_distance, dense)
     if key in _sharded_cache:
         return _sharded_cache[key]
     rep = P()
     bat = P(BATCH_AXIS)
+    kernel = fleet_multi_area_tables_dense if dense else fleet_multi_area_tables
     body = functools.partial(
-        fleet_multi_area_tables.__wrapped__,
+        kernel.__wrapped__,
         max_degree=max_degree,
         per_area_distance=per_area_distance,
     )
+    n_topo = 7 if dense else 6
 
     def wrapped(roots, *tables):
-        return body(*tables[:6], roots, *tables[6:])
+        return body(*tables[:n_topo], roots, *tables[n_topo:])
 
     fn = jax.jit(
         jax.shard_map(
             wrapped,
             mesh=mesh,
-            in_specs=(bat, *([rep] * 14)),
+            in_specs=(bat, *([rep] * (n_topo + 8))),
             out_specs=(
                 P(BATCH_AXIS, None, None),  # use [B, P, C]
                 P(BATCH_AXIS, None, None),  # shortest [B, P, A]

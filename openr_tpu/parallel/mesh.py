@@ -20,16 +20,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 BATCH_AXIS = "batch"
 
 
-def shard_map_supported() -> bool:
-    """True when this jax exposes the stable ``jax.shard_map`` entry
-    point the sharded kernels are written against (its ``check_vma``
-    signature landed with the stable export).  Older environments only
-    carry the incompatible ``jax.experimental.shard_map`` API; the
-    sharded code paths (and their tests) gate on this instead of
-    failing at dispatch time."""
-    return hasattr(jax, "shard_map")
-
-
 def make_mesh(
     num_devices: Optional[int] = None,
     devices: Optional[Sequence] = None,
@@ -269,11 +259,10 @@ class DevicePool:
 
     def survivor_mesh(self) -> Optional[Mesh]:
         """Mesh over the CURRENT healthy set for the shard_map-collective
-        engines; None when the stable ``jax.shard_map`` is unavailable
-        or fewer than two chips survive (the collective path needs a
-        real mesh to beat per-device dispatch)."""
+        engines; None when fewer than two chips survive (the collective
+        path needs a real mesh to beat per-device dispatch)."""
         healthy = [self.devices[i] for i in self.healthy_indices()]
-        if len(healthy) < 2 or not shard_map_supported():
+        if len(healthy) < 2:
             return None
         return make_mesh(devices=healthy)
 

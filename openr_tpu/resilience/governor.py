@@ -5,7 +5,7 @@ batched device kernel" (PAPER §7).  That trade has three failure modes a
 production deployment must survive without an operator:
 
 1. **Hard outage** — dispatch raises (chaos ``tpu_fail``, a dead chip, a
-   severed tunnel).  Before this module the latch was one-way: only
+   lost host link).  Before this module the latch was one-way: only
    chaos flipped ``TpuBackend.device_failed``; an organic dispatch
    exception fell back scalar for THAT build and re-paid the failing
    device on every subsequent rebuild.

@@ -1,7 +1,7 @@
 """Capacity-planning sweep orchestrator (ISSUE 14).
 
-BENCH_r03's ~249k raw device solves/s existed only as hand-rolled
-what-if engine batches; this package turns that throughput into a
+The warm-start what-if engine's raw device throughput existed only as
+hand-rolled engine batches; this package turns that throughput into a
 *capacity-planning product* (ROADMAP "what-if planning as a product"):
 
 * :mod:`openr_tpu.sweep.scenario` — a declarative, deterministic

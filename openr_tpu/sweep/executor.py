@@ -5,8 +5,8 @@ fixed-size shards; each shard is dispatched as COMMITTED per-device
 work on one healthy DevicePool chip (round-robin over the survivors at
 dispatch time), solved through the warm-start repair sweep
 (:class:`~openr_tpu.ops.whatif.LinkFailureSweep` +
-:class:`~openr_tpu.ops.sweep_select.SweepRouteSelector` — the BENCH_r03
-throughput machinery) for single-area LSDBs, or through the multi-area
+:class:`~openr_tpu.ops.sweep_select.SweepRouteSelector` — the headline
+what-if machinery) for single-area LSDBs, or through the multi-area
 what-if kernel (:func:`~openr_tpu.ops.fleet_tables
 .whatif_multi_area_tables`) for multi-area ones.  Up to ``inflight``
 shards ride the streamed drain path at once (dispatch shard N+1 while

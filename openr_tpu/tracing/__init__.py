@@ -8,7 +8,7 @@ Chrome-trace/Perfetto-compatible file.  `pipeline` holds the dispatch
 phase registry + `PipelineProbe` (per-phase histograms, per-chip busy
 gauges); `flight_recorder` the bounded post-mortem ring that auto-dumps
 on invariant breach / chip quarantine / watchdog crash.  See
-docs/Observability.md for the span taxonomy and naming conventions.
+docs/Observability.md for the span catalogue and naming conventions.
 """
 
 from openr_tpu.tracing.export import chrome_trace_events, write_chrome_trace

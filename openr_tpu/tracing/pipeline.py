@@ -1,14 +1,15 @@
 """Pipeline attribution — every millisecond of a device dispatch named.
 
-BENCH_r02/r03 showed the end-to-end rebuild budget dominated by host
-work (`host_fetch_unique_tables_ms` 1696ms, `dispatch_sync_ms` 958ms)
-while the kernels took 84-150ms — but those numbers were bench-local
+Round-2/3 headline captures (through a since-removed remote platform
+plug-in) showed the end-to-end rebuild budget dominated by host work
+(`host_fetch_unique_tables_ms` 1696ms, `dispatch_sync_ms` 958ms) while
+the kernels took 84-150ms — but those numbers were bench-local
 stopwatches.  Before the pipelined host/device rebuild (ROADMAP) can
 overlap decode with compute, the live system must attribute every
 dispatch to a *phase* and a *chip*, continuously, through the same
 observability surfaces everything else uses.
 
-This module is the single source of truth for the phase taxonomy:
+This module is the single source of truth for the phase catalogue:
 
 =================  ========================================================
 phase              meaning

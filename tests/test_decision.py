@@ -461,7 +461,7 @@ def test_auto_cutover_picks_scalar_on_small_worlds():
         ps.update_prefix(f"node{i}", "0", PrefixEntry(f"10.{i}.0.0/24"))
 
     expensive = TpuBackend(SpfSolver("node0"), min_device_prefixes=None)
-    expensive.auto_dispatch_rt_ms = 1000.0  # tunnel-like
+    expensive.auto_dispatch_rt_ms = 1000.0  # slow dispatch
     db = expensive.build_route_db({"0": ls}, ps)
     assert expensive.num_small_scalar_builds == 1
     assert expensive.num_device_builds == 0

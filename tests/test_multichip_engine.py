@@ -34,12 +34,8 @@ def world():
 def _mesh(n):
     import jax
 
-    from openr_tpu.parallel.mesh import make_mesh, shard_map_supported
+    from openr_tpu.parallel.mesh import make_mesh
 
-    if not shard_map_supported():
-        # version-gated: this jax predates the stable jax.shard_map the
-        # sharded kernels target (see parallel/mesh.py) — skip, don't red
-        pytest.skip("this jax has no stable jax.shard_map")
     if len(jax.devices()) < n:
         pytest.skip(f"needs {n} devices")
     return make_mesh(n)

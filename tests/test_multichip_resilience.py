@@ -75,14 +75,11 @@ def test_device_pool_shard_packing_and_health():
     assert pool.num_quarantines == 1 and pool.num_restores == 1
 
 
-def test_device_pool_survivor_mesh_is_version_gated():
-    from openr_tpu.parallel.mesh import shard_map_supported
-
+def test_device_pool_survivor_mesh_spans_healthy_chips():
     pool = DevicePool()
-    if not shard_map_supported():
-        assert pool.survivor_mesh() is None
-    else:
-        assert pool.survivor_mesh().devices.size == 8
+    assert pool.survivor_mesh().devices.size == 8
+    pool.quarantine_device(1)
+    assert pool.survivor_mesh().devices.size == 7
 
 
 # ---------------------------------------------------------------------------

@@ -326,6 +326,36 @@ def test_dispatch_failures_trip_the_latch_after_threshold():
     assert norm_db(db3) == oracle
 
 
+@pytest.mark.parametrize(
+    "exc, scored",
+    [
+        ("capacity", False),  # the repo's own data-scale limit
+        ("value", True),  # jaxlib's XLA errors and native faults
+    ],
+)
+def test_only_capacity_errors_skip_the_breaker(exc, scored):
+    """A CapacityError is a data limit: scalar fallback, breaker
+    untouched.  Any other ValueError (jaxlib surfaces XLA errors as
+    ValueError; the native CSR fill reports a fault so) is a dispatch
+    failure the breaker counts — never a quiet scalar build."""
+    from openr_tpu.ops.csr import CapacityError
+
+    als, ps = make_world()
+    backend = make_backend(SimClock())
+    oracle = norm_db(SpfSolver("node0").build_route_db(als, ps))
+
+    def explode(*a, **k):
+        if exc == "capacity":
+            raise CapacityError("9 exceeds largest bucket 8")
+        raise ValueError("INVALID_ARGUMENT: XLA refused the launch")
+
+    backend._build_device = explode
+    assert norm_db(backend.build_route_db(als, ps)) == oracle
+    assert backend.num_dispatch_errors == int(scored)
+    assert backend.governor.num_dispatch_failures == int(scored)
+    assert backend.num_scalar_builds == 1
+
+
 def test_non_finite_guard_trips_shadow_verification():
     als, ps = make_world()
     backend = make_backend(SimClock())

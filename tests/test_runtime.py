@@ -2,6 +2,7 @@
 (reference behavior: openr/common/tests/*)."""
 
 import asyncio
+import os
 
 from openr_tpu.common.runtime import Actor, CounterMap, SimClock
 from openr_tpu.common.utils import (
@@ -223,13 +224,16 @@ def test_actor_tasks_pruned_on_completion():
     run(main())
 
 
-def test_persistent_compile_cache_gating(monkeypatch, tmp_path):
-    """enable_persistent_compile_cache: OPENR_TPU_COMPILE_CACHE=off
-    disables, an explicit path wins, and the virtual-CPU-mesh test mode
-    (xla_force_host_platform_device_count) skips by default (cross-host
-    XLA:CPU AOT reloads can warn or SIGILL)."""
+def test_persistent_compile_cache_placement(monkeypatch, tmp_path):
+    """enable_persistent_compile_cache: a JAX_COMPILATION_CACHE_DIR set
+    from outside places the cache (no directory is set in code);
+    without it the fixed checkout path is used.  Idempotent."""
+    import sys
+
     import openr_tpu.ops.platform_env as pe
 
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert pe.compile_cache_dir() == os.path.join(repo, ".jax_compile_cache")
     calls = []
 
     class FakeConfig:
@@ -240,29 +244,22 @@ def test_persistent_compile_cache_gating(monkeypatch, tmp_path):
     class FakeJax:
         config = FakeConfig()
 
-    monkeypatch.setattr(pe, "_COMPILE_CACHE_ENABLED", False)
-    import sys
-
     monkeypatch.setitem(sys.modules, "jax", FakeJax())
 
-    # off
-    monkeypatch.setenv("OPENR_TPU_COMPILE_CACHE", "off")
+    # placed from outside: JAX reads the variable itself
+    monkeypatch.setattr(pe, "_COMPILE_CACHE_ENABLED", False)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "ext"))
     pe.enable_persistent_compile_cache()
-    assert not calls and not pe._COMPILE_CACHE_ENABLED
+    assert pe._COMPILE_CACHE_ENABLED
+    assert not [k for k, _v in calls if k == "jax_compilation_cache_dir"]
 
-    # virtual-mesh mode skips when no explicit path
-    monkeypatch.delenv("OPENR_TPU_COMPILE_CACHE", raising=False)
-    monkeypatch.setenv(
-        "XLA_FLAGS", "--xla_force_host_platform_device_count=8"
-    )
-    pe.enable_persistent_compile_cache()
-    assert not calls and not pe._COMPILE_CACHE_ENABLED
-
-    # explicit path wins even in virtual-mesh mode
-    monkeypatch.setenv("OPENR_TPU_COMPILE_CACHE", str(tmp_path / "cc"))
+    # unset: the fixed <checkout>/.jax_compile_cache path
+    calls.clear()
+    monkeypatch.setattr(pe, "_COMPILE_CACHE_ENABLED", False)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    monkeypatch.setattr(pe, "compile_cache_dir", lambda: str(tmp_path / "cc"))
     pe.enable_persistent_compile_cache()
     assert ("jax_compilation_cache_dir", str(tmp_path / "cc")) in calls
-    assert pe._COMPILE_CACHE_ENABLED
     assert (tmp_path / "cc").is_dir()
     # idempotent
     n = len(calls)

@@ -468,7 +468,7 @@ def test_canonical_query_normalizes_pair_order():
 def test_trace_spans_chain_enqueue_batch_solve_kernel():
     """A served query renders as serving.enqueue → serving.batch_solve
     → decision.spf_kernel spans in one trace (the Observability.md
-    taxonomy), and the queue-wait/batch-size histograms observe."""
+    catalogue), and the queue-wait/batch-size histograms observe."""
 
     async def main():
         clock = SimClock()

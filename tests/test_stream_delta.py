@@ -488,13 +488,8 @@ def test_survivor_mesh_collective_repacks_on_quarantine():
     """PR-6 remnant: engines given BOTH a mesh and a pool re-derive the
     collective mesh from DevicePool.survivor_mesh() when a chip
     quarantines mid-run, and results stay bit-identical."""
-    from openr_tpu.parallel.mesh import DevicePool, shard_map_supported
-
-    if not shard_map_supported():
-        # version-gated: this jax predates the stable jax.shard_map the
-        # collective engines are written against
-        pytest.skip("this jax has no stable jax.shard_map")
     from openr_tpu.decision.fleet import FleetRibEngine
+    from openr_tpu.parallel.mesh import DevicePool
 
     _adj, als, ps = make_world(6)
     pool = DevicePool()
@@ -515,27 +510,18 @@ def test_survivor_mesh_collective_repacks_on_quarantine():
 
 
 def test_active_mesh_rederives_on_health_transitions():
-    """The mesh wiring itself (works regardless of shard_map support):
-    health transitions re-derive, restores re-admit, and engines
-    without a pool keep their pinned mesh."""
+    """The mesh wiring itself: health transitions re-derive, restores
+    re-admit, and engines without a pool keep their pinned mesh."""
     from openr_tpu.decision.fleet import FleetRibEngine
-    from openr_tpu.parallel.mesh import DevicePool, shard_map_supported
+    from openr_tpu.parallel.mesh import DevicePool
 
     pool = DevicePool()
     eng = FleetRibEngine(SpfSolver("node0"), mesh=object(), pool=pool)
-    m0 = eng._active_mesh()
-    if shard_map_supported():
-        assert m0 is not None and m0.devices.size == 8
-    else:
-        assert m0 is None  # survivor_mesh is version-gated
+    assert eng._active_mesh().devices.size == 8
     pool.quarantine_device(2)
-    m1 = eng._active_mesh()
-    if shard_map_supported():
-        assert m1.devices.size == 7
+    assert eng._active_mesh().devices.size == 7
     pool.restore_device(2)
-    m2 = eng._active_mesh()
-    if shard_map_supported():
-        assert m2.devices.size == 8
+    assert eng._active_mesh().devices.size == 8
     # no pool: the constructor's mesh is pinned
     pinned = object()
     eng2 = FleetRibEngine(SpfSolver("node0"), mesh=pinned)

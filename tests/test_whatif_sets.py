@@ -187,12 +187,7 @@ def test_run_sets_sharded_parity():
 
     if len(jax.devices()) < 2:
         pytest.skip("needs a multi-device mesh")
-    from openr_tpu.parallel.mesh import make_mesh, shard_map_supported
-
-    if not shard_map_supported():
-        # version-gated: this jax predates the stable jax.shard_map the
-        # sharded kernels target (see parallel/mesh.py) — skip, don't red
-        pytest.skip("this jax has no stable jax.shard_map")
+    from openr_tpu.parallel.mesh import make_mesh
 
     _ls, topo = build_world(seed=13)
     rng = np.random.default_rng(5)
