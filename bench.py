@@ -510,6 +510,7 @@ def validate_pipeline_bench(doc: dict) -> None:
         DELTA_PHASES,
         DEVICE_GET,
         DEVICE_SELECT,
+        EVENT_PHASES,
         PAD_PACK,
         PHASES,
         PROTECTION_PHASES,
@@ -539,15 +540,17 @@ def validate_pipeline_bench(doc: dict) -> None:
         # must have recorded real time (delta_extract rides the diff).
         # warm_plan/warm_repair fire only on warm-start rebuilds
         # (BENCH_WARMSTART), device_select only on delta builds, the
-        # sweep phases only in the capacity-sweep orchestrator, and the
-        # protection phases only with a live protection tier — never on
-        # the cold lifecycle these rounds measure.
+        # sweep phases only in the capacity-sweep orchestrator, the
+        # protection phases only with a live protection tier, and the
+        # event phases only on a convergence event — never on the cold
+        # lifecycle these rounds measure.
         required = (
             set(PHASES)
             - set(WARM_PHASES)
             - set(DELTA_PHASES)
             - set(SWEEP_PHASES)
             - set(PROTECTION_PHASES)
+            - set(EVENT_PHASES)
         )
         if not streamed:
             required.discard(STREAM_DRAIN)

@@ -2059,7 +2059,7 @@ class TpuBackend(DecisionBackend):
             # stale for its rows — they can no longer vouch for the
             # next full build's delta
             self._prev_sel = None
-            with self.probe.phase(pipeline.DELTA_EXTRACT):
+            with self.probe.phase(pipeline.ROUTE_PATCH):
                 return _patch_route_db(
                     self._last_db, results, self.solver.get_static_routes()
                 )
@@ -2123,7 +2123,7 @@ class TpuBackend(DecisionBackend):
                 changed_out.update(deleted)
                 self._last_changed_prefixes = changed_out
                 self._prev_sel = None  # patched build: outputs stale
-                with self.probe.phase(pipeline.DELTA_EXTRACT):
+                with self.probe.phase(pipeline.ROUTE_PATCH):
                     return _patch_route_db(
                         patch_base,
                         results,
@@ -2198,7 +2198,7 @@ class TpuBackend(DecisionBackend):
             }
             changed_out.update(deleted)
             self._last_changed_prefixes = changed_out
-            with self.probe.phase(pipeline.DELTA_EXTRACT):
+            with self.probe.phase(pipeline.ROUTE_PATCH):
                 return _patch_route_db(
                     patch_base, results, self.solver.get_static_routes()
                 )

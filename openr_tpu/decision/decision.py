@@ -230,6 +230,21 @@ class Decision(Actor):
     BULK_INGEST_MIN = 32
 
     def _on_publication(self, pub: Publication) -> None:
+        from openr_tpu.tracing import pipeline
+
+        # the protection tier's apply records its own phase, so it (and
+        # the debounce arm) runs after the ingest phase closes
+        with self._backend_probe().phase(pipeline.DECISION_INGEST):
+            changed, prev_key = self._ingest_publication(pub)
+        if changed:
+            if prev_key is not None:
+                self._maybe_apply_protection(prev_key)
+            if self._unblocked:
+                self._debounce()
+
+    def _ingest_publication(self, pub: Publication) -> tuple:
+        """``(changed, prev_key)``: the publication applied to the LSDB
+        under the GC handling its size calls for."""
         if len(pub.key_vals) >= self.BULK_INGEST_MIN:
             # large publication (cold boot / areawide churn): gen-2
             # collections re-scan the ever-growing LSDB heap (measured
@@ -247,16 +262,14 @@ class Decision(Actor):
                 if not self._boot_gc_paused and gc.isenabled():
                     gc.disable()
                     self._boot_gc_paused = True
-                self._on_publication_inner(pub)
-                return
+                return self._on_publication_inner(pub)
             from openr_tpu.common.utils import gc_paused
 
             with gc_paused():
-                self._on_publication_inner(pub)
-            return
-        self._on_publication_inner(pub)
+                return self._on_publication_inner(pub)
+        return self._on_publication_inner(pub)
 
-    def _on_publication_inner(self, pub: Publication) -> None:
+    def _on_publication_inner(self, pub: Publication) -> tuple:
         # the generation this publication transitions FROM — the
         # identity a protection patch must have been minted at
         prev_key = (
@@ -289,10 +302,7 @@ class Decision(Actor):
             self.counters.bump("decision.lsdb_updates")
             self._bump_generation()
             self._rebuild_pending = True
-            if prev_key is not None:
-                self._maybe_apply_protection(prev_key)
-            if self._unblocked:
-                self._debounce()
+        return changed, prev_key
 
     def _bump_generation(self) -> None:
         """Advance the computed-result generation and notify the serving
@@ -385,16 +395,12 @@ class Decision(Actor):
             return
         entries, deletes = made
         from openr_tpu.tracing import pipeline as _pipeline
-        from openr_tpu.tracing.pipeline import disabled_probe
 
-        probe = self._backend_probe()
-        if probe is None:
-            probe = disabled_probe()
         span = self.tracer.start_span(
             "decision.frr_apply", self.pending_trace_ctx, module="decision"
         )
         try:
-            with probe.phase(_pipeline.PROTECTION_APPLY):
+            with self._backend_probe().phase(_pipeline.PROTECTION_APPLY):
                 update = DecisionRouteUpdate(
                     type=DecisionRouteUpdateType.INCREMENTAL,
                     frr=True,
@@ -811,14 +817,9 @@ class Decision(Actor):
                 self.protection.purge_table("full_replace")
         # the RouteDb diff is the pipeline's delta-extract tail: the last
         # host stage between device output and the FIB publication
-        probe = self._backend_probe()
-        if probe is None:
-            from openr_tpu.tracing.pipeline import disabled_probe
-
-            probe = disabled_probe()
         from openr_tpu.tracing import pipeline as _pipeline
 
-        with probe.phase(_pipeline.DELTA_EXTRACT):
+        with self._backend_probe().phase(_pipeline.DELTA_EXTRACT):
             warm_changed = None
             if force_full:
                 # a warm-selective backend build PATCHED the previous
@@ -943,12 +944,18 @@ class Decision(Actor):
         return fn() if fn is not None else None
 
     def _backend_probe(self):
-        """The backend's PipelineProbe (None for scalar backends) — the
-        fleet/what-if engines record their phase samples and per-chip
-        busy time on the SAME ledger route builds use, so `pipeline.*`
+        """The backend's PipelineProbe (the shared disabled probe for
+        scalar backends) — Decision's own phases, Fib's and the
+        fleet/what-if engines' record their samples and per-chip busy
+        time on the SAME ledger route builds use, so `pipeline.*`
         histograms and `pipeline.devN.*` gauges cover the whole
-        dispatch plane."""
-        return getattr(self.backend, "probe", None)
+        event and dispatch plane."""
+        probe = getattr(self.backend, "probe", None)
+        if probe is None:
+            from openr_tpu.tracing.pipeline import disabled_probe
+
+            probe = disabled_probe()
+        return probe
 
     def _fleet(self):
         if self._fleet_engine is None:

@@ -33,6 +33,7 @@ from openr_tpu.decision.rib import (
 )
 from openr_tpu.messaging.queue import RQueue, ReplicateQueue
 from openr_tpu.resilience import STATE_CLOSED, CircuitBreaker
+from openr_tpu.tracing import pipeline
 from openr_tpu.types import InitializationEvent, MplsRoute, PerfEvents, UnicastRoute
 
 
@@ -135,11 +136,16 @@ class Fib(Actor):
         counters: Optional[CounterMap] = None,
         dryrun: bool = False,
         tracer=None,
+        probe=None,
     ) -> None:
         super().__init__("fib", clock, counters)
         from openr_tpu.tracing import disabled_tracer
 
         self.tracer = tracer if tracer is not None else disabled_tracer()
+        #: the node's PipelineProbe (Decision's): the RIB apply and route
+        #: conversions of each update record ``fib_program``; the agent
+        #: calls, another process in a deployment, are left out of it
+        self.probe = probe if probe is not None else pipeline.disabled_probe()
         self.node_name = node_name
         self.config = config
         self.agent = agent
@@ -240,28 +246,16 @@ class Fib(Actor):
                 )
 
     async def _process_route_update(self, update: DecisionRouteUpdate) -> None:
-        if update.type == DecisionRouteUpdateType.FULL_SYNC:
-            self.unicast_routes = dict(update.unicast_routes_to_update)
-            self.mpls_routes = dict(update.mpls_routes_to_update)
+        full_sync = update.type == DecisionRouteUpdateType.FULL_SYNC
+        with self.probe.phase(pipeline.FIB_PROGRAM):
+            if full_sync:
+                self.unicast_routes = dict(update.unicast_routes_to_update)
+                self.mpls_routes = dict(update.mpls_routes_to_update)
+            else:
+                self._apply_incremental(update)
+        if full_sync:
             await self._sync_routes()
         else:
-            for prefix, entry in update.unicast_routes_to_update.items():
-                prior = self.unicast_routes.get(prefix)
-                self.unicast_routes[prefix] = entry
-                if (
-                    entry.do_not_install
-                    and prior is not None
-                    and not prior.do_not_install
-                ):
-                    # installed route flipped to do_not_install: withdraw it
-                    update.unicast_routes_to_delete.append(prefix)
-            for prefix in update.unicast_routes_to_delete:
-                if prefix not in update.unicast_routes_to_update:
-                    self.unicast_routes.pop(prefix, None)
-            for label, mentry in update.mpls_routes_to_update.items():
-                self.mpls_routes[label] = mentry
-            for label in update.mpls_routes_to_delete:
-                self.mpls_routes.pop(label, None)
             await self._program_incremental(update)
         # notify PrefixManager et al of (intended-as-)programmed routes
         if self.fib_route_updates_queue is not None:
@@ -274,6 +268,25 @@ class Fib(Actor):
                 "fib.convergence_time_ms", update.perf_events.total_duration_ms()
             )
             self.perf_db.append(update.perf_events)
+
+    def _apply_incremental(self, update: DecisionRouteUpdate) -> None:
+        for prefix, entry in update.unicast_routes_to_update.items():
+            prior = self.unicast_routes.get(prefix)
+            self.unicast_routes[prefix] = entry
+            if (
+                entry.do_not_install
+                and prior is not None
+                and not prior.do_not_install
+            ):
+                # installed route flipped to do_not_install: withdraw it
+                update.unicast_routes_to_delete.append(prefix)
+        for prefix in update.unicast_routes_to_delete:
+            if prefix not in update.unicast_routes_to_update:
+                self.unicast_routes.pop(prefix, None)
+        for label, mentry in update.mpls_routes_to_update.items():
+            self.mpls_routes[label] = mentry
+        for label in update.mpls_routes_to_delete:
+            self.mpls_routes.pop(label, None)
 
     def get_perf_db(self) -> List[PerfEvents]:
         """ctrl API getPerfDb (if/OpenrCtrl.thrift:465)."""
@@ -290,21 +303,21 @@ class Fib(Actor):
             # sync probe it on the backoff schedule
             self._mark_dirty(agent_failed=False)
             return
-        try:
+        with self.probe.phase(pipeline.FIB_PROGRAM):
             adds = [
                 e.to_unicast_route()
                 for e in update.unicast_routes_to_update.values()
                 if not e.do_not_install
             ]
+            mpls_adds = [
+                e.to_mpls_route()
+                for e in update.mpls_routes_to_update.values()
+            ]
+        try:
             if adds:
                 await self.agent.add_unicast_routes(adds)
-            if update.mpls_routes_to_update:
-                await self.agent.add_mpls_routes(
-                    [
-                        e.to_mpls_route()
-                        for e in update.mpls_routes_to_update.values()
-                    ]
-                )
+            if mpls_adds:
+                await self.agent.add_mpls_routes(mpls_adds)
             # deletes are delayed to let the network reroute first
             # (route_delete_delay_ms, OpenrConfig default 1s)
             if update.unicast_routes_to_delete or update.mpls_routes_to_delete:
@@ -363,15 +376,15 @@ class Fib(Actor):
             self.counters.bump("fib.dryrun_syncs")
             self._mark_synced()
             return
+        with self.probe.phase(pipeline.FIB_PROGRAM):
+            routes = [
+                e.to_unicast_route()
+                for e in self.unicast_routes.values()
+                if not e.do_not_install
+            ]
+            mpls_routes = [e.to_mpls_route() for e in self.mpls_routes.values()]
         try:
-            await self.agent.sync_fib(
-                [
-                    e.to_unicast_route()
-                    for e in self.unicast_routes.values()
-                    if not e.do_not_install
-                ],
-                [e.to_mpls_route() for e in self.mpls_routes.values()],
-            )
+            await self.agent.sync_fib(routes, mpls_routes)
             self._backoff.report_success()
             self.breaker.record_success()
             self.counters.bump("fib.num_sync")

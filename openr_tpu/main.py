@@ -361,6 +361,9 @@ class OpenrNode:
             tracer=self.tracer,
         )
         self.init_tracker.add_listener(self.decision.on_initialization_event)
+        # the backend's PipelineProbe (None on the scalar backend): Fib
+        # records its phase on the ledger Decision and the backend use
+        probe = getattr(backend, "probe", None)
         self.fib = Fib(
             node_name=self.name,
             clock=clock,
@@ -372,6 +375,7 @@ class OpenrNode:
             counters=self.counters,
             dryrun=config.dryrun,
             tracer=self.tracer,
+            probe=probe,
         )
         # the serving plane fronts Decision's fleet/what-if engines with
         # micro-batching + result caching + admission control; it
@@ -462,7 +466,6 @@ class OpenrNode:
         # pipeline attribution gauges: per-chip busy ms / utilization
         # accumulated by the backend + fleet/what-if engines' shared
         # PipelineProbe (pipeline.devN.*)
-        probe = getattr(backend, "probe", None)
         if probe is not None:
             self.monitor.add_counter_provider(probe.gauges)
         # flight recorder: bounded post-mortem ring, auto-dumped on chip

@@ -542,20 +542,34 @@ class BackendHealthGovernor:
         per-chip attribution needs the complete culprit set, not just
         the first lie found — while the reason string stays the first
         mismatch for readable status output."""
+        from openr_tpu.tracing import pipeline
+
+        probe = self.backend.probe
         self._last_mismatch_prefixes = []
-        non_finite = [
-            prefix
-            for prefix, entry in device_db.unicast_routes.items()
-            if not math.isfinite(entry.igp_cost)
-        ]
+        with probe.phase(pipeline.SHADOW_COMPARE):
+            non_finite = [
+                prefix
+                for prefix, entry in device_db.unicast_routes.items()
+                if not math.isfinite(entry.igp_cost)
+            ]
+        with probe.phase(pipeline.SHADOW_REBUILD):
+            scalar_db = self._scalar_db(area_link_states, prefix_state)
         if non_finite:
             self._last_mismatch_prefixes = non_finite
-            return False, self._scalar_db(area_link_states, prefix_state), (
-                f"non_finite:{non_finite[0]}"
+            return False, scalar_db, f"non_finite:{non_finite[0]}"
+        with probe.phase(pipeline.SHADOW_COMPARE):
+            bad, reason = self._rib_diff(
+                device_db.unicast_routes, scalar_db.unicast_routes
             )
-        scalar_db = self._scalar_db(area_link_states, prefix_state)
-        dev = device_db.unicast_routes
-        ref = scalar_db.unicast_routes
+        if bad:
+            self._last_mismatch_prefixes = bad
+            return False, scalar_db, reason
+        return True, scalar_db, ""
+
+    @staticmethod
+    def _rib_diff(dev, ref) -> Tuple[List[str], str]:
+        """Every prefix whose device route differs from the oracle's, and
+        the first difference as a reason."""
         bad: List[str] = []
         reason = ""
         if set(dev) != set(ref):
@@ -578,10 +592,7 @@ class BackendHealthGovernor:
             elif d.do_not_install != r.do_not_install:
                 bad.append(prefix)
                 reason = reason or f"do_not_install:{prefix}"
-        if bad:
-            self._last_mismatch_prefixes = bad
-            return False, scalar_db, reason
-        return True, scalar_db, ""
+        return bad, reason
 
     def _scalar_db(self, area_link_states, prefix_state):
         return self.backend.solver.build_route_db(

@@ -19,12 +19,15 @@ phase              meaning
 ``encode``         LSDB -> padded CSR encoding (``ops/csr.py``)
 ``pad_pack``       bucketing/padding/shard packing of a batch
 ``transfer``       host->device copies (``jax.device_put``, replicas)
-``device_compute`` a committed kernel dispatch; per-device attributable —
-                   each shard is its own dispatch on its own chip, so the
-                   sample carries a ``device`` attr exactly like rows do
+``device_compute`` the host's asynchronous dispatch call of a committed
+                   kernel (not the kernel's device time); per-device
+                   attributable — each shard is its own dispatch on its
+                   own chip, so the sample carries a ``device`` attr
+                   exactly like rows do
 ``device_get``     the blocking device->host fetch draining dispatches
 ``decode``         device outputs -> RibUnicastEntries (host decode tail)
-``delta_extract``  diffing the new RouteDb against the previous one
+``delta_extract``  Decision diffing the new RouteDb against the
+                   previous one (the backend's patch is ``route_patch``)
 ``warm_plan``      host-side generation-delta classification + warm-seed
                    planning (reset-set BFS, encode patch bookkeeping,
                    warm-context maintenance — decision/backend.py)
@@ -54,14 +57,34 @@ phase              meaning
                    patch lookup + RibUnicastEntry materialization +
                    RIB splice + publish on a protected link-down event
                    (decision/decision.py)
+``route_patch``    the backend's patched RouteDb: the previous RouteDb
+                   copied and the changed prefixes' results spliced in
+                   (decision/backend.py ``_patch_route_db``)
+``shadow_rebuild`` the health governor's scalar oracle rebuild of the
+                   whole RouteDb for one shadow check
+``shadow_compare`` the rest of one shadow check: the non-finite guard
+                   and the RIB diff against the oracle's RouteDb
+``decision_ingest``  Decision ingesting one KvStore publication into its
+                   LSDB (GC handling and generation bump included)
+``fib_program``    Fib's host work on one route update: the RIB apply
+                   and the route conversions; the agent calls are not
+                   in it (the agent is another process in a deployment)
 =================  ========================================================
 
 Surfaces: every phase sample lands in a ``pipeline.{phase}.ms``
 fixed-bucket histogram and (when tracing is on) a ``pipeline.{phase}``
-child span under the active trace scope; per-chip busy time accumulates
-into ``pipeline.devN.busy_ms`` / ``pipeline.devN.utilization`` gauges
-via :meth:`PipelineProbe.gauges` (a ``Monitor.add_counter_provider``
+child span under the active trace scope; while a JAX profiler session
+is active each scope is also a ``pipeline.{phase}`` TraceAnnotation on
+the profiler's host plane, on the clock of the device ops.  Per-chip
+busy time (host time spent dispatching to or draining one chip)
+accumulates into ``pipeline.devN.busy_ms`` / ``pipeline.devN.utilization``
+gauges via :meth:`PipelineProbe.gauges` (a ``Monitor.add_counter_provider``
 provider).
+
+Phases tile: no phase scope is opened inside another, so the
+``pipeline.*.ms`` totals add up, and no scope spans an ``await`` that
+can suspend, so the profiler's spans nest on one thread.  A scope wraps
+one build stage, publication, update or check, never per-route work.
 
 orlint's ``pipeline-phase-registry`` rule enforces that no other module
 spells a ``pipeline.*`` name as a free string — phase names are drawn
@@ -71,7 +94,8 @@ from these constants or they do not exist.
 from __future__ import annotations
 
 import contextlib
-from typing import Dict, Iterable, Optional
+import sys
+from typing import Dict, Optional
 
 # -- the phase registry (the ONLY place pipeline.* names are spelled) ------
 
@@ -91,6 +115,11 @@ SWEEP_SHARD_SOLVE = "sweep_shard_solve"
 SWEEP_REDUCE = "sweep_reduce"
 PROTECTION_MINT = "protection_mint"
 PROTECTION_APPLY = "protection_apply"
+ROUTE_PATCH = "route_patch"
+SHADOW_REBUILD = "shadow_rebuild"
+SHADOW_COMPARE = "shadow_compare"
+DECISION_INGEST = "decision_ingest"
+FIB_PROGRAM = "fib_program"
 
 PHASES = (
     HOST_FETCH,
@@ -109,6 +138,11 @@ PHASES = (
     SWEEP_REDUCE,
     PROTECTION_MINT,
     PROTECTION_APPLY,
+    ROUTE_PATCH,
+    SHADOW_REBUILD,
+    SHADOW_COMPARE,
+    DECISION_INGEST,
+    FIB_PROGRAM,
 )
 
 #: phases only the warm-start generation-delta rebuild exercises — a
@@ -132,6 +166,18 @@ SWEEP_PHASES = (SWEEP_SHARD_SOLVE, SWEEP_REDUCE)
 #: nothing here, so attribution gates treat them as optional coverage
 PROTECTION_PHASES = (PROTECTION_MINT, PROTECTION_APPLY)
 
+#: phases of a convergence event outside the cold rebuild lifecycle:
+#: Decision's ingest, the backend's patched RouteDb, the governor's
+#: sampled shadow check and Fib — a rebuild measured on its own (the
+#: BENCH_PIPELINE rounds) records nothing here
+EVENT_PHASES = (
+    ROUTE_PATCH,
+    SHADOW_REBUILD,
+    SHADOW_COMPARE,
+    DECISION_INGEST,
+    FIB_PROGRAM,
+)
+
 #: phases whose time is host-side work (the pipelining refactor's
 #: overlap candidates) vs the device round trip — the host/device split
 #: BENCH_PIPELINE reports.  ``stream_drain`` counts as device time: it
@@ -147,7 +193,7 @@ HOST_PHASES = (
     SWEEP_REDUCE,
     PROTECTION_MINT,
     PROTECTION_APPLY,
-)
+) + EVENT_PHASES
 DEVICE_PHASES = (
     TRANSFER,
     DEVICE_COMPUTE,
@@ -201,20 +247,39 @@ def parse_device_key(key: str):
     return int(m.group("idx")), m.group("kind")
 
 
+def _active_annotation():
+    """``jax.profiler.TraceAnnotation`` while a profiler session is
+    active, else None.  Never imports jax: a process that has not loaded
+    it (the protocol plane alone) has no profiler session to annotate."""
+    profiler = getattr(sys.modules.get("jax"), "profiler", None)
+    if profiler is None:
+        return None
+    cls = profiler.TraceAnnotation
+    return cls if cls.is_enabled() else None
+
+
 class _PhaseScope:
     """Context manager for one timed phase (allocated per phase entry;
     the disabled probe short-circuits to a shared no-op instead)."""
 
-    __slots__ = ("_probe", "_phase", "_device", "_devices", "_span", "_t0")
+    __slots__ = ("_probe", "_phase", "_device", "_span", "_annotation", "_t0")
 
-    def __init__(self, probe, phase, device, devices):
+    def __init__(self, probe, phase, device):
         self._probe = probe
         self._phase = phase
         self._device = device
-        self._devices = devices
 
     def __enter__(self):
         probe = self._probe
+        cls = _active_annotation()
+        if cls is not None:
+            # on the profiler's host plane, beside the device ops; the
+            # annotation opens first and closes last so it holds the
+            # whole scope
+            self._annotation = cls(span_name(self._phase))
+            self._annotation.__enter__()
+        else:
+            self._annotation = None
         self._t0 = probe.clock.now()
         tracer = probe.tracer
         if tracer is not None and tracer.enabled:
@@ -233,25 +298,19 @@ class _PhaseScope:
 
     def __exit__(self, exc_type, exc, tb) -> None:
         probe = self._probe
-        ms = (probe.clock.now() - self._t0) * 1000.0
-        if probe.counters is not None:
-            probe.counters.observe(hist_key(self._phase), ms)
-        if self._span is not None:
-            if exc_type is not None:
-                self._span.attrs["error"] = exc_type.__name__
-            probe.tracer.end_span(self._span)
-        if self._device is not None:
-            probe.note_busy(self._device, ms)
-        if self._devices:
-            # a TRUE all-chip barrier charges the window to every chip
-            # it covered.  The streamed-completion dispatch loops never
-            # take this path any more — each stream_drain window passes
-            # ``device=`` and charges ONLY the completing chip, so
-            # pipeline.devN.utilization stays honest under overlap
-            # (BENCH_PIPELINE_r01's mode note about fractions exceeding
-            # wall share documented exactly this former overcount).
-            for d in self._devices:
-                probe.note_busy(d, ms)
+        try:
+            ms = (probe.clock.now() - self._t0) * 1000.0
+            if probe.counters is not None:
+                probe.counters.observe(hist_key(self._phase), ms)
+            if self._span is not None:
+                if exc_type is not None:
+                    self._span.attrs["error"] = exc_type.__name__
+                probe.tracer.end_span(self._span)
+            if self._device is not None:
+                probe.note_busy(self._device, ms)
+        finally:
+            if self._annotation is not None:
+                self._annotation.__exit__(exc_type, exc, tb)
 
 
 @contextlib.contextmanager
@@ -269,11 +328,15 @@ class PipelineProbe:
     * a probe constructed without a clock is permanently disabled and
       every ``phase(...)`` entry is a shared O(1) no-op, so library
       embedders that never wire observability pay one attribute check;
-    * per-chip busy time: ``device=`` charges a committed per-shard
-      dispatch to its chip; ``devices=`` charges a blocking drain to
-      every chip it covered.  ``gauges()`` exports
+    * per-chip busy time: ``device=`` charges the scope's host time
+      (a committed per-shard dispatch call, or the drain of one chip's
+      shard) to that chip.  ``gauges()`` exports
       ``pipeline.devN.busy_ms`` and ``pipeline.devN.utilization``
-      (busy / probe lifetime) for the Monitor provider sweep.
+      (busy / probe lifetime) for the Monitor provider sweep: host time
+      spent dispatching to or draining one chip, not device occupancy;
+    * while a JAX profiler session is active each scope is also a
+      ``pipeline.{phase}`` TraceAnnotation, whether or not the tracer is
+      on, so the profiler's trace puts host idle gaps down to phases.
     """
 
     def __init__(self, clock=None, counters=None, tracer=None) -> None:
@@ -288,23 +351,15 @@ class PipelineProbe:
 
     # -- phase scopes ------------------------------------------------------
 
-    def phase(
-        self,
-        phase: str,
-        device: Optional[int] = None,
-        devices: Optional[Iterable[int]] = None,
-    ):
+    def phase(self, phase: str, device: Optional[int] = None):
         """``with probe.phase(pipeline.ENCODE): ...`` — time one phase.
 
-        ``device`` marks a committed per-shard dispatch (chip-
+        ``device`` marks a committed per-shard dispatch or drain (chip-
         attributable sample: span carries a ``device`` attr, busy time
-        charges to that chip); ``devices`` charges a blocking drain to
-        every listed chip."""
+        charges to that chip)."""
         if not self.enabled:
             return _noop_scope()
-        return _PhaseScope(
-            self, phase, device, list(devices) if devices else None
-        )
+        return _PhaseScope(self, phase, device)
 
     def _current_ctx(self):
         """Parent pipeline spans under the active traced build (the

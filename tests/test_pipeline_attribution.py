@@ -184,7 +184,7 @@ def test_probe_records_histograms_spans_and_busy():
         pass
     with probe.phase(pipeline.DEVICE_COMPUTE, device=2):
         clock._now += 0.005  # 5 virtual ms inside the phase
-    with probe.phase(pipeline.DEVICE_GET, devices=[2, 5]):
+    with probe.phase(pipeline.DEVICE_GET, device=5):
         clock._now += 0.001
     h = counters.histogram(pipeline.hist_key(pipeline.ENCODE))
     assert h is not None and h.count == 1
@@ -195,10 +195,9 @@ def test_probe_records_histograms_spans_and_busy():
     assert "pipeline.encode" in names and "pipeline.device_compute" in names
     dc = [s for s in tracer.get_spans() if s.name == "pipeline.device_compute"]
     assert dc[0].attrs["device"] == 2
-    # busy ledger: the committed dispatch charged dev2; the blocking
-    # drain charged both chips it covered
+    # busy ledger: each scope charged only the chip it names
     busy = probe.busy_snapshot()
-    assert busy[2] == pytest.approx(6.0)
+    assert busy[2] == pytest.approx(5.0)
     assert busy[5] == pytest.approx(1.0)
     gauges = probe.gauges()
     assert pipeline.device_busy_key(2) in gauges
@@ -309,7 +308,7 @@ def test_incremental_gather_attributes_the_lead_chip():
     assert db is not None
     after = backend.pool.num_dispatches
     assert sum(after) == sum(before) + 1  # ONE chip rode the gather
-    h = counters.histogram(pipeline.hist_key(pipeline.DELTA_EXTRACT))
+    h = counters.histogram(pipeline.hist_key(pipeline.ROUTE_PATCH))
     assert h is not None and h.count >= 1  # the patch path is the tail
 
 
@@ -395,6 +394,218 @@ def test_decision_hands_engines_the_backend_probe():
     d._change_seq = 1
     assert d._backend_probe() is backend.probe
     assert d._fleet().probe is backend.probe
+
+
+# ---------------------------------------------------------------------------
+# the event path's phases: governor, backend patch, Decision ingest, Fib
+# ---------------------------------------------------------------------------
+
+
+def _run(coro):
+    import asyncio
+
+    loop = asyncio.new_event_loop()
+    try:
+        return loop.run_until_complete(coro)
+    finally:
+        loop.close()
+
+
+def _shadow_checked_builds(counters):
+    """Two device builds under a governor that shadow-checks every one."""
+    from openr_tpu.decision.backend import TpuBackend
+
+    als, ps = make_world()
+    backend = TpuBackend(
+        SpfSolver("node0"),
+        clock=SimClock(),
+        counters=counters,
+        resilience=ResilienceConfig(shadow_sample_every=1),
+        parallel=ParallelConfig(min_shard_rows=0),
+    )
+    backend.build_route_db(als, ps)
+    ps.update_prefix("node3", "0", PrefixEntry("10.99.3.0/24"))
+    backend.build_route_db(als, ps, changed_prefixes={"10.99.3.0/24"})
+    gov = backend.governor
+    assert gov.num_shadow_checks == 2 and not gov.num_shadow_mismatches
+    for phase in (pipeline.SHADOW_REBUILD, pipeline.SHADOW_COMPARE):
+        assert counters.histogram(pipeline.hist_key(phase)).count >= 2
+
+
+def _incremental_build(counters):
+    """A patched build: the patch is route_patch, and nothing inside
+    build_route_db is Decision's diff any more."""
+    als, ps = make_world()
+    backend = make_backend(SimClock(), counters)
+    backend.build_route_db(als, ps)
+    ps.update_prefix("node3", "0", PrefixEntry("10.99.3.0/24"))
+    backend.build_route_db(als, ps, changed_prefixes={"10.99.3.0/24"})
+    assert backend.num_incremental_builds == 1
+    assert counters.histogram(pipeline.hist_key(pipeline.DELTA_EXTRACT)) is None
+
+
+def _decision_and_fib(counters):
+    """Decision ingests three publications (one phase each), builds on
+    the device, diffs, and Fib programs the update with Decision's
+    probe."""
+    import json
+
+    from openr_tpu.config import DecisionConfig, FibConfig
+    from openr_tpu.decision.decision import Decision
+    from openr_tpu.fib.fib import Fib, MockFibAgent
+    from openr_tpu.messaging.queue import ReplicateQueue
+    from openr_tpu.types import (
+        InitializationEvent,
+        PrefixDatabase,
+        Publication,
+        Value,
+        adj_key,
+        prefix_key,
+    )
+
+    def value(node, payload):
+        return Value(version=1, originator_id=node, ttl=300000,
+                     value=json.dumps(payload).encode())
+
+    async def main():
+        clock = SimClock()
+        routes_q = ReplicateQueue("routeUpdates")
+        kv_q = ReplicateQueue("kvpubs")
+        decision = Decision(
+            "node0",
+            clock,
+            DecisionConfig(unblock_initial_routes_ms=120000),
+            routes_q,
+            kv_store_updates_reader=kv_q.get_reader(),
+            backend=make_backend(clock, counters),
+        )
+        agent = MockFibAgent(clock)
+        fib = Fib("node0", clock, FibConfig(), agent, routes_q.get_reader(),
+                  probe=decision._backend_probe())
+        decision.start()
+        fib.start()
+        decision.on_initialization_event(InitializationEvent.KVSTORE_SYNCED)
+        adj = build_adj_dbs(ring_edges(6))
+        kv_q.push(Publication(key_vals={
+            adj_key(n): value(n, db.to_wire()) for n, db in adj.items()}))
+        for i in (3, 4):
+            pfx = f"10.7.{i}.0/24"
+            db = PrefixDatabase(this_node_name=f"node{i}",
+                                prefix_entries=[PrefixEntry(pfx)], area="0")
+            kv_q.push(Publication(key_vals={
+                prefix_key(f"node{i}", pfx): value(f"node{i}", db.to_wire())}))
+        await clock.run_for(2.0)
+        assert "10.7.3.0/24" in agent.unicast
+        await fib.stop()
+        await decision.stop()
+
+    _run(main())
+    ingest = counters.histogram(pipeline.hist_key(pipeline.DECISION_INGEST))
+    assert ingest.count == 3  # one phase per publication
+    assert counters.histogram(pipeline.hist_key(pipeline.DELTA_EXTRACT))
+
+
+EVENT_PATHS = {
+    pipeline.SHADOW_REBUILD: _shadow_checked_builds,
+    pipeline.SHADOW_COMPARE: _shadow_checked_builds,
+    pipeline.ROUTE_PATCH: _incremental_build,
+    pipeline.DECISION_INGEST: _decision_and_fib,
+    pipeline.FIB_PROGRAM: _decision_and_fib,
+}
+
+
+@pytest.fixture
+def nested_phases(monkeypatch):
+    """``[(outer, inner)]`` of every phase scope opened inside another
+    (recorded, not raised: an actor's queue loop would swallow it)."""
+    from openr_tpu.tracing.pipeline import _PhaseScope
+
+    open_scopes, nested = [], []
+    enter, exit_ = _PhaseScope.__enter__, _PhaseScope.__exit__
+
+    def recording_enter(self):
+        if open_scopes:
+            nested.append((open_scopes[-1], self._phase))
+        open_scopes.append(self._phase)
+        return enter(self)
+
+    def recording_exit(self, *exc):
+        open_scopes.pop()
+        return exit_(self, *exc)
+
+    monkeypatch.setattr(_PhaseScope, "__enter__", recording_enter)
+    monkeypatch.setattr(_PhaseScope, "__exit__", recording_exit)
+    return nested
+
+
+@pytest.mark.parametrize("phase", pipeline.EVENT_PHASES)
+def test_event_path_records_its_phase_and_phases_tile(phase, nested_phases):
+    assert phase in pipeline.HOST_PHASES
+    counters = CounterMap()
+    EVENT_PATHS[phase](counters)
+    h = counters.histogram(pipeline.hist_key(phase))
+    assert h is not None and h.count >= 1
+    assert nested_phases == []
+
+
+# ---------------------------------------------------------------------------
+# phases on the profiler's clock
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def profiled_phase_names(tmp_path_factory):
+    """Host-plane event names of one profiler session in which a probe
+    with no tracer opened one scope of every registry phase."""
+    import glob
+    import os
+
+    import jax
+
+    from openr_tpu.common.runtime import WallClock
+
+    probe = PipelineProbe(WallClock(), CounterMap())
+    log_dir = str(tmp_path_factory.mktemp("profile"))
+    with jax.profiler.trace(log_dir):
+        for phase in pipeline.PHASES:
+            with probe.phase(phase):
+                pass
+    (path,) = glob.glob(os.path.join(log_dir, "plugins", "profile", "*",
+                                     "*.xplane.pb"))
+    data = jax.profiler.ProfileData.from_file(path)
+    return {
+        ev.name
+        for plane in data.planes if plane.name.startswith("/host:")
+        for line in plane.lines for ev in line.events
+    }
+
+
+@pytest.mark.parametrize("phase", pipeline.PHASES)
+def test_phase_is_a_span_on_the_profilers_host_plane(phase,
+                                                     profiled_phase_names):
+    assert pipeline.span_name(phase) in profiled_phase_names
+
+
+def test_no_annotation_outside_a_profiler_session():
+    import jax  # noqa: F401 - loaded, yet no session is active
+
+    assert pipeline._active_annotation() is None
+
+
+def test_pipeline_module_never_imports_jax():
+    """The protocol plane runs without jax: the registry only looks for
+    a jax that something else already loaded."""
+    import ast
+    import inspect
+
+    tree = ast.parse(inspect.getsource(pipeline))
+    imported = set()
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Import):
+            imported.update(a.name.split(".")[0] for a in node.names)
+        elif isinstance(node, ast.ImportFrom) and node.module:
+            imported.add(node.module.split(".")[0])
+    assert "jax" not in imported and "jaxlib" not in imported
 
 
 # ---------------------------------------------------------------------------
