@@ -32,7 +32,7 @@ Semantics preserved:
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Set, Tuple
+from typing import AbstractSet, Dict, FrozenSet, List, Optional, Set, Tuple
 
 from openr_tpu.decision.link_state import INF, LinkState, Path
 from openr_tpu.decision.prefix_state import NodeAndArea, PrefixState
@@ -173,6 +173,16 @@ class SpfSolver:
         self.route_selection_algorithm = route_selection_algorithm
         self._static_unicast_routes: Dict[str, RibUnicastEntry] = {}
         self.best_routes_cache: Dict[str, RouteSelectionResult] = {}
+        #: SP_ECMP next hops per (winner set, area, is_v4), alive only
+        #: while build_route_db runs: prefixes with the same winners (one
+        #: node's many prefixes) share one min-cost scan and neighbour walk
+        self._next_hop_memo: Optional[
+            Dict[Tuple[FrozenSet[NodeAndArea], str, bool],
+                 Tuple[float, FrozenSet[NextHop]]]
+        ] = None
+        #: memo lookups of the last full build that found / computed
+        self.last_build_memo_hits = 0
+        self.last_build_memo_misses = 0
 
     # -- static routes (SpfSolver.cpp:109-137) -----------------------------
 
@@ -407,15 +417,32 @@ class SpfSolver:
         area: str,
         link_state: LinkState,
         is_v4: bool,
-    ) -> Tuple[float, Set[NextHop]]:
+    ) -> Tuple[float, AbstractSet[NextHop]]:
+        memo = self._next_hop_memo
+        if memo is not None:
+            # the whole winner set, other areas' node-areas included: the
+            # min-cost scan reads all of it against this area's SPF
+            key = (frozenset(selection.all_node_areas), area, is_v4)
+            hit = memo.get(key)
+            if hit is not None:
+                self.last_build_memo_hits += 1
+                return hit
         best_metrics = self.get_next_hops_with_metric(
             selection.all_node_areas, link_state
         )
-        if not best_metrics[1]:
-            return best_metrics[0], set()
-        return best_metrics[0], self.get_next_hops(
-            selection.all_node_areas, is_v4, best_metrics, None, area, link_state
-        )
+        nhs: AbstractSet[NextHop] = set()
+        if best_metrics[1]:
+            nhs = self.get_next_hops(
+                selection.all_node_areas, is_v4, best_metrics, None, area,
+                link_state,
+            )
+        if memo is None:
+            return best_metrics[0], nhs
+        # frozen: every prefix with these winners shares the one set
+        result = (best_metrics[0], frozenset(nhs))
+        memo[key] = result
+        self.last_build_memo_misses += 1
+        return result
 
     def _select_best_paths_ksp2(
         self,
@@ -523,17 +550,22 @@ class SpfSolver:
         area_link_states: Dict[str, LinkState],
         prefix_state: PrefixState,
     ) -> Optional[DecisionRouteDb]:
+        self.last_build_memo_hits = self.last_build_memo_misses = 0
         if not any(ls.has_node(self.my_node_name) for ls in area_link_states.values()):
             return None
         route_db = DecisionRouteDb()
         self.best_routes_cache.clear()
 
-        for prefix in prefix_state.prefixes():
-            entry = self.create_route_for_prefix(
-                prefix, area_link_states, prefix_state
-            )
-            if entry is not None:
-                route_db.add_unicast_route(entry)
+        self._next_hop_memo = {}
+        try:
+            for prefix in prefix_state.prefixes():
+                entry = self.create_route_for_prefix(
+                    prefix, area_link_states, prefix_state
+                )
+                if entry is not None:
+                    route_db.add_unicast_route(entry)
+        finally:
+            self._next_hop_memo = None
 
         # static routes: prefixState wins on conflict (SpfSolver.cpp:343-349)
         for prefix, sentry in self._static_unicast_routes.items():

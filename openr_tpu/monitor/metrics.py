@@ -225,6 +225,13 @@ def _build_descriptions() -> Dict[str, str]:
         "resilience.backend.shadow_mismatches": (
             "shadow checks that caught wrong device output"
         ),
+        "resilience.backend.shadow_nexthop_memo_hits": (
+            "shadow-rebuild prefixes whose next hops came from the "
+            "per-build winner-set memo"
+        ),
+        "resilience.backend.shadow_nexthop_memo_misses": (
+            "distinct winner sets whose next hops a shadow rebuild computed"
+        ),
         "decision.backend.pool.size": "chips in the device pool",
         "decision.backend.pool.healthy": "healthy chips in the pool",
         "health.sweeps": "fleet health aggregator sweeps",

@@ -51,6 +51,12 @@ ADMIT_DEVICE = "device"
 ADMIT_PROBE = "probe"
 ADMIT_QUARANTINED = "quarantined"
 
+#: the shadow rebuilds' next-hop memo lookups (SpfSolver's per-build
+#: memo): a low hit share means few prefixes per advertiser, so the
+#: rebuild pays per prefix
+MEMO_HITS = "resilience.backend.shadow_nexthop_memo_hits"
+MEMO_MISSES = "resilience.backend.shadow_nexthop_memo_misses"
+
 
 class BackendHealthGovernor:
     """Health authority for one TpuBackend.
@@ -595,9 +601,11 @@ class BackendHealthGovernor:
         return bad, reason
 
     def _scalar_db(self, area_link_states, prefix_state):
-        return self.backend.solver.build_route_db(
-            area_link_states, prefix_state
-        )
+        solver = self.backend.solver
+        db = solver.build_route_db(area_link_states, prefix_state)
+        self.counters.bump(MEMO_HITS, solver.last_build_memo_hits)
+        self.counters.bump(MEMO_MISSES, solver.last_build_memo_misses)
+        return db
 
     # -- operator / chaos controls -------------------------------------------
 
@@ -778,6 +786,8 @@ class BackendHealthGovernor:
                 "resilience.backend.shadow_mismatches": float(
                     self.num_shadow_mismatches
                 ),
+                MEMO_HITS: float(self.counters.get(MEMO_HITS)),
+                MEMO_MISSES: float(self.counters.get(MEMO_MISSES)),
                 "resilience.backend.quarantines": float(self.num_quarantines),
                 "resilience.backend.restores": float(self.num_restores),
                 "resilience.backend.dispatch_failures": float(

@@ -88,6 +88,23 @@ def _run(port, *args):
     return r.output
 
 
+def _wait_adjacent(port, timeout_s=30.0):
+    """Block until node0's LSDB holds the node0<->node1 adjacency in
+    both directions.  A sweep's first shard compiles on the node's own
+    loop; on a loaded CPU that outlasts the emulated 3 s Spark hold time,
+    so the adjacency flaps and re-forms about a second later.  Tests that
+    read routes off the module's shared network wait it out here."""
+    import time
+
+    deadline = time.monotonic() + timeout_s
+    while True:
+        adj = _run(port, "decision", "adj")
+        if "-> node1" in adj and "-> node0" in adj:
+            return
+        assert time.monotonic() < deadline, adj
+        time.sleep(0.1)
+
+
 def test_cli_openr_group(live_node):
     assert _run(live_node, "openr", "node-name").strip() == "node0"
     v = json.loads(_run(live_node, "openr", "version"))
@@ -351,6 +368,7 @@ def test_cli_serving_watch_snapshot_and_stream_stats(live_node):
     """breeze serving watch NODE --deltas 0: one generation-stamped
     snapshot through the ctrl server-stream, then exit; stream-stats
     reflects the (now departed) subscriber."""
+    _wait_adjacent(live_node)
     out = _run(live_node, "serving", "watch", "node1", "--deltas", "0")
     snap = json.loads(out)
     assert snap["type"] == "snapshot" and snap["kind"] == "route_db"

@@ -253,6 +253,38 @@ def test_sdc_detected_quarantined_and_served_from_scalar():
     assert norm_db(db2) == norm_db(db)
 
 
+def test_shadow_check_counts_the_oracles_nexthop_memo():
+    """Each shadow rebuild adds its next-hop memo's hits and misses to
+    the governor's counters; the memo changes no verdict: corruption is
+    still caught and the scalar RouteDb served."""
+    from openr_tpu.types import PrefixEntry
+
+    als, ps = make_world()
+    for i in range(6):  # three prefixes behind each node's one winner set
+        for p in (1, 2):
+            ps.update_prefix(f"node{i}", "0", PrefixEntry(f"10.8.{i}.{p}/32"))
+    backend = make_backend(SimClock())
+    gov = backend.governor
+    hits_key = "resilience.backend.shadow_nexthop_memo_hits"
+    misses_key = "resilience.backend.shadow_nexthop_memo_misses"
+    backend.build_route_db(als, ps)
+    checks = gov.num_shadow_checks
+    assert checks >= 1
+    # per rebuild: 5 remote winner sets, 15 remote prefixes
+    assert gov.counters.get(misses_key) == 5 * checks
+    assert gov.counters.get(hits_key) == 10 * checks
+    snap = gov.counter_snapshot()
+    assert snap[hits_key] == 10 * checks and snap[misses_key] == 5 * checks
+
+    backend.inject_silent_corruption(True)
+    db = backend.build_route_db(als, ps, force_full=True)
+    assert gov.num_shadow_checks == checks + 1
+    assert gov.num_shadow_mismatches == 1 and backend.device_failed
+    assert norm_db(db) == norm_db(SpfSolver("node0").build_route_db(als, ps))
+    assert gov.counters.get(misses_key) == 5 * (checks + 1)
+    assert gov.counters.get(hits_key) == 10 * (checks + 1)
+
+
 def test_probed_recovery_after_corruption_heals():
     als, ps = make_world()
     clock = SimClock()

@@ -2,11 +2,18 @@
 openr/decision/tests/SpfSolverTest.cpp (drained-node choice, multipath,
 MPLS labels, best-route selection, min-nexthop, cross-area merge)."""
 
+import pytest
+
 from openr_tpu.decision.link_state import LinkState
 from openr_tpu.decision.prefix_state import PrefixState
 from openr_tpu.decision.rib import DecisionRouteDb, RibUnicastEntry
 from openr_tpu.decision.spf_solver import SpfSolver
-from openr_tpu.emulation.topology import build_adj_dbs, line_edges, ring_edges
+from openr_tpu.emulation.topology import (
+    build_adj_dbs,
+    grid_edges,
+    line_edges,
+    ring_edges,
+)
 from openr_tpu.types import (
     NextHop,
     PrefixEntry,
@@ -304,3 +311,195 @@ def test_path_a_in_path_b_contiguous_ordered():
     assert not LS.path_a_in_path_b([full[0], full[2]], full)  # gap
     assert not LS.path_a_in_path_b(list(reversed(full)), full)  # wrong order
     assert LS.path_a_in_path_b([], full)
+
+
+# ---------------------------------------------------------------------------
+# full build's per-build next-hop memo: same answer as the per-prefix path
+# ---------------------------------------------------------------------------
+
+
+def _grid_world():
+    ps = PrefixState()
+    for i in range(16):
+        for p in range(4):
+            advertise(ps, f"node{i}", f"10.{i}.{p}.0/24")
+    return {"0": make_area(grid_edges(4))}, ps, "node0", {}
+
+
+def _anycast_world():
+    ps = PrefixState()
+    for k in range(4):
+        # preference decides: node15 loses to the two preferred advertisers
+        advertise(ps, "node5", f"10.50.{k}.0/24", path_preference=1000)
+        advertise(ps, "node10", f"10.50.{k}.0/24", path_preference=1000)
+        advertise(ps, "node15", f"10.50.{k}.0/24", path_preference=500)
+        # distance decides
+        advertise(ps, "node3", f"10.60.{k}.0/24", distance=2)
+        advertise(ps, "node12", f"10.60.{k}.0/24", distance=1)
+        # a tie: both advertisers win, next hops toward the nearer
+        advertise(ps, "node3", f"10.70.{k}.0/24")
+        advertise(ps, "node12", f"10.70.{k}.0/24")
+        advertise(ps, "node7", f"10.70.{k}.0/24", distance=3)
+    return {"0": make_area(grid_edges(4))}, ps, "node0", {}
+
+
+def _two_area_world():
+    ls_a = make_area(
+        [("a", "b", 1), ("b", "x", 1), ("a", "c", 1), ("c", "x", 1)], area="A"
+    )
+    # x is in both areas: B's SPF scans every winner by name, A's too
+    ls_b = make_area([("a", "d", 1), ("d", "y", 1), ("a", "x", 1)], area="B")
+    ps = PrefixState()
+    for k in range(3):
+        # equal igp in both areas: the merge unions A's and B's next hops
+        advertise(ps, "x", f"10.1.{k}.0/24", area="A")
+        advertise(ps, "y", f"10.1.{k}.0/24", area="B")
+        # B is nearer: only B's next hops survive the merge
+        advertise(ps, "x", f"10.2.{k}.0/24", area="A")
+        advertise(ps, "d", f"10.2.{k}.0/24", area="B")
+        advertise(ps, "y", f"10.3.{k}.0/24", area="B")
+    return {"A": ls_a, "B": ls_b}, ps, "a", {}
+
+
+def _v4_v6_world(v4_over_v6_nexthop):
+    ps = PrefixState()
+    for i in range(9):
+        for p in range(3):
+            advertise(ps, f"node{i}", f"10.{i}.{p}.0/24")
+            advertise(ps, f"node{i}", f"2001:db8:{i}:{p}::/64")
+    return (
+        {"0": make_area(grid_edges(3))},
+        ps,
+        "node0",
+        {"v4_over_v6_nexthop": v4_over_v6_nexthop},
+    )
+
+
+def _drained_world():
+    ls = make_area(grid_edges(4), overloaded=["node5"], soft_drained={"node10": 50})
+    ps = PrefixState()
+    for k in range(3):
+        advertise(ps, "node5", f"10.1.{k}.0/24")  # hard-drained: node6 wins
+        advertise(ps, "node6", f"10.1.{k}.0/24")
+        advertise(ps, "node10", f"10.2.{k}.0/24")  # soft-drained: node9 wins
+        advertise(ps, "node9", f"10.2.{k}.0/24")
+        advertise(ps, "node5", f"10.3.{k}.0/24")  # all drained: fallback
+        advertise(ps, "node10", f"10.4.{k}.0/24")  # best node drained
+    return {"0": ls}, ps, "node0", {}
+
+
+def _min_nexthop_world():
+    ps = PrefixState()
+    for k in range(3):
+        # node4 is two ECMP paths away from node0
+        advertise(ps, "node4", f"10.1.{k}.0/24", min_nexthop=2)  # passes
+        advertise(ps, "node4", f"10.2.{k}.0/24", min_nexthop=3)  # gated
+        advertise(ps, "node4", f"10.3.{k}.0/24")  # same winners, no gate
+        advertise(ps, "node1", f"10.4.{k}.0/24", min_nexthop=2)  # gated
+    return {"0": make_area(grid_edges(3))}, ps, "node0", {}
+
+
+MEMO_WORLDS = {
+    "grid": _grid_world,
+    "anycast": _anycast_world,
+    "two_areas": _two_area_world,
+    "v4_v6": lambda: _v4_v6_world(False),
+    "v4_over_v6_nexthop": lambda: _v4_v6_world(True),
+    "drained": _drained_world,
+    "min_nexthop": _min_nexthop_world,
+}
+
+
+def _route_view(entry):
+    return (
+        set(entry.nexthops),
+        entry.igp_cost,
+        entry.best_prefix_entry,
+        entry.best_area,
+        entry.do_not_install,
+    )
+
+
+@pytest.mark.parametrize("world", sorted(MEMO_WORLDS))
+def test_full_build_equals_per_prefix_routes(world):
+    als, ps, me, kwargs = MEMO_WORLDS[world]()
+    solver = SpfSolver(me, **kwargs)
+    db = solver.build_route_db(als, ps)
+    # prefixes with the same winners really did share the memo
+    assert solver.last_build_memo_hits > 0
+    alone = SpfSolver(me, **kwargs)
+    want = {}
+    for prefix in ps.prefixes():
+        entry = alone.create_route_for_prefix(prefix, als, ps)
+        if entry is not None:
+            want[prefix] = _route_view(entry)
+    assert alone.last_build_memo_hits == alone.last_build_memo_misses == 0
+    got = {p: _route_view(e) for p, e in db.unicast_routes.items()}
+    assert got == want
+
+
+def _metric_world(metric):
+    edges = [(a, b, metric if (a, b) == ("node0", "node1") else m)
+             for a, b, m in grid_edges(3)]
+    ps = PrefixState()
+    for p in range(3):
+        advertise(ps, "node4", f"10.4.{p}.0/24")
+    return {"0": make_area(edges)}, ps
+
+
+def test_memo_next_hops_follow_a_metric_change():
+    solver = SpfSolver("node0")
+    als, ps = _metric_world(1)
+    before = solver.build_route_db(als, ps)
+    assert {
+        nh.neighbor_node_name for nh in before.unicast_routes["10.4.0.0/24"].nexthops
+    } == {"node1", "node3"}
+    als, ps = _metric_world(5)
+    after = solver.build_route_db(als, ps)
+    for p in range(3):
+        route = after.unicast_routes[f"10.4.{p}.0/24"]
+        assert {nh.neighbor_node_name for nh in route.nexthops} == {"node3"}
+        assert route.igp_cost == 2
+
+
+def test_memo_does_not_survive_the_build():
+    als, ps, me, _ = _grid_world()
+    solver = SpfSolver(me)
+    solver.build_route_db(als, ps)
+    assert solver._next_hop_memo is None
+    # a build cut short by an error drops its memo too
+    calls = []
+
+    def fail_second(*a, **k):
+        calls.append(1)
+        if len(calls) == 2:
+            raise RuntimeError("cut short")
+        return SpfSolver.create_route_for_prefix(solver, *a, **k)
+
+    solver.create_route_for_prefix = fail_second
+    with pytest.raises(RuntimeError):
+        solver.build_route_db(als, ps)
+    assert solver._next_hop_memo is None
+
+
+def test_no_two_routes_share_a_nexthop_set():
+    als, ps, me, _ = _anycast_world()
+    db = SpfSolver(me).build_route_db(als, ps)
+    sets = [e.nexthops for e in db.unicast_routes.values()]
+    assert len({id(s) for s in sets}) == len(sets)
+    # and each is a set of its own that a caller may change
+    route = db.unicast_routes["10.50.0.0/24"]
+    route.nexthops.clear()
+    assert db.unicast_routes["10.50.1.0/24"].nexthops
+
+
+def test_memo_counts_on_a_known_grid():
+    # 16 nodes x 4 prefixes; node0's own 4 need no next hops, and each
+    # other node's 4 prefixes share that node as their one winner
+    als, ps, me, _ = _grid_world()
+    solver = SpfSolver(me)
+    db = solver.build_route_db(als, ps)
+    winner_sets = 15
+    assert len(db.unicast_routes) == 15 * 4
+    assert solver.last_build_memo_misses == winner_sets
+    assert solver.last_build_memo_hits == 15 * 4 - winner_sets
