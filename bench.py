@@ -511,6 +511,7 @@ def validate_pipeline_bench(doc: dict) -> None:
         DEVICE_GET,
         DEVICE_SELECT,
         EVENT_PHASES,
+        KSP2_PHASES,
         PAD_PACK,
         PHASES,
         PROTECTION_PHASES,
@@ -541,7 +542,8 @@ def validate_pipeline_bench(doc: dict) -> None:
         # warm_plan/warm_repair fire only on warm-start rebuilds
         # (BENCH_WARMSTART), device_select only on delta builds, the
         # sweep phases only in the capacity-sweep orchestrator, the
-        # protection phases only with a live protection tier, and the
+        # protection phases only with a live protection tier, the KSP2
+        # and label phases only with KSP2 or SR-MPLS routes, and the
         # event phases only on a convergence event — never on the cold
         # lifecycle these rounds measure.
         required = (
@@ -550,6 +552,7 @@ def validate_pipeline_bench(doc: dict) -> None:
             - set(DELTA_PHASES)
             - set(SWEEP_PHASES)
             - set(PROTECTION_PHASES)
+            - set(KSP2_PHASES)
             - set(EVENT_PHASES)
         )
         if not streamed:

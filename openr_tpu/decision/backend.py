@@ -25,6 +25,7 @@ the changed set.
 
 from __future__ import annotations
 
+from collections import Counter
 from typing import Dict, List, Optional, Set, Tuple
 
 import numpy as np
@@ -383,6 +384,9 @@ class TpuBackend(DecisionBackend):
         #: itself lives in the LinkState; this only avoids rebuilding the
         #: link-id table every rebuild
         self._ksp2_engines: dict = {}
+        #: KSP2 seeding work across every engine this backend made
+        #: (``decision.backend.ksp2.*``, see Ksp2DeviceEngine.counts)
+        self.ksp2_counts: Counter = Counter()
         self.num_encode_hits = 0
         self.num_encodes = 0
         #: device-resident per-area SPF tables, valid while (_spf_enc is
@@ -900,6 +904,13 @@ class TpuBackend(DecisionBackend):
                 self.num_delta_rows_skipped
             ),
         }
+        for k in (
+            "device_batches",
+            "resolve_rows",
+            "resolve_padded_rows",
+            "seeded_dests",
+        ):
+            out[f"decision.backend.ksp2.{k}"] = float(self.ksp2_counts[k])
         for reason, n in sorted(self._slot_decline_reasons.items()):
             out[f"decision.backend.slot_decline.{reason}"] = float(n)
         for cls, reasons in sorted(
@@ -1021,9 +1032,42 @@ class TpuBackend(DecisionBackend):
         key = (area, link_state.topology_seq)
         eng = self._ksp2_engines.get(key)
         if eng is None or eng.link_state is not link_state or eng.topo is not topo:
-            eng = Ksp2DeviceEngine(link_state, topo, self.solver.my_node_name)
+            eng = Ksp2DeviceEngine(
+                link_state,
+                topo,
+                self.solver.my_node_name,
+                probe=self.probe,
+                counts=self.ksp2_counts,
+            )
             self._ksp2_engines[key] = eng
         return eng
+
+    def _ksp2_routes(
+        self, ksp2, enc, area_link_states, prefix_state
+    ) -> Dict[str, Optional[RibUnicastEntry]]:
+        """Routes of the KSP2_ED_ECMP prefixes a decode deferred
+        (``ksp2`` = (prefixes, {area: winner nodes})): one batched
+        masked re-solve per area seeds the k-path memo, then the scalar
+        KSP2 chain assembles each route over it — no host Dijkstra runs
+        (decision/ksp2.py).  Runs outside the ``decode`` phase."""
+        from openr_tpu.tracing import pipeline
+
+        prefixes, dests = ksp2
+        if not prefixes:
+            return {}
+        self._ksp2_present = True
+        for a, nodes in sorted(dests.items()):
+            ai = enc.area_index(a)
+            self._ksp2_engine(a, area_link_states[a], enc.topos[ai]).seed(
+                nodes
+            )
+        with self.probe.phase(pipeline.KSP2_ROUTES):
+            return {
+                prefix: self.solver.create_route_for_prefix(
+                    prefix, area_link_states, prefix_state
+                )
+                for prefix in prefixes
+            }
 
     #: per-platform cold-SPF kernel preference (the ROADMAP policy
     #: hook): maps a jax backend platform name ("cpu"/"tpu"/"gpu", or
@@ -1834,6 +1878,7 @@ class TpuBackend(DecisionBackend):
             )
         if self._sdc_active_for(inc_dev if inc_dev is not None else 0):
             shortest = self._corrupt_metrics(shortest)
+        ksp2 = ([], {})
         with self.probe.phase(pipeline.DECODE):
             results = self._decode_rows(
                 [(i, table.row_prefix[r]) for i, r in enumerate(rows)],
@@ -1846,7 +1891,11 @@ class TpuBackend(DecisionBackend):
                 enc,
                 area_link_states,
                 prefix_state,
+                ksp2,
             )
+        results.update(
+            self._ksp2_routes(ksp2, enc, area_link_states, prefix_state)
+        )
         return results, inc_dev
 
     def _delta_ctx_for(
@@ -2159,6 +2208,7 @@ class TpuBackend(DecisionBackend):
                 if rows is None or not len(rows):
                     continue
                 global_rows = rows + shard["lo"]
+                ksp2 = ([], {})
                 with self.probe.phase(pipeline.DECODE):
                     row_items = [
                         (i, table.row_prefix[r])
@@ -2177,8 +2227,14 @@ class TpuBackend(DecisionBackend):
                             enc,
                             area_link_states,
                             prefix_state,
+                            ksp2,
                         )
                     )
+                results.update(
+                    self._ksp2_routes(
+                        ksp2, enc, area_link_states, prefix_state
+                    )
+                )
                 for r in global_rows:
                     shard_devs[int(r)] = shard["dev"]
                 decoded_rows.extend(int(r) for r in global_rows)
@@ -2211,6 +2267,7 @@ class TpuBackend(DecisionBackend):
         for shard in self._stream_row_shards(
             dv, (dist, nh, ovl, soft), per_area, plan, None
         ):
+            ksp2 = ([], {})
             with self.probe.phase(pipeline.DECODE):
                 use = shard["use"]
                 lo = shard["lo"]
@@ -2235,8 +2292,12 @@ class TpuBackend(DecisionBackend):
                         enc,
                         area_link_states,
                         prefix_state,
+                        ksp2,
                     )
                 )
+            results.update(
+                self._ksp2_routes(ksp2, enc, area_link_states, prefix_state)
+            )
         self.num_device_builds += 1
         clean = self._retain_prev_sel(plan, D, enc, dv)
         if self._use_pool() and clean:
@@ -2254,11 +2315,12 @@ class TpuBackend(DecisionBackend):
             for prefix, entry in results.items():
                 if entry is not None:
                     route_db.add_unicast_route(entry)
-            # static-route overlay + MPLS labels: scalar (small)
+            # static-route overlay: scalar (small)
             for prefix, sentry in self.solver.get_static_routes().items():
                 if prefix not in route_db.unicast_routes:
                     route_db.add_unicast_route(sentry)
-            if self.solver.enable_node_segment_label:
+        if self.solver.enable_node_segment_label:
+            with self.probe.phase(pipeline.LABEL_ROUTES):
                 self.solver._build_node_label_routes(
                     area_link_states, route_db
                 )
@@ -2291,10 +2353,13 @@ class TpuBackend(DecisionBackend):
         enc,
         area_link_states,
         prefix_state,
+        ksp2,  # (prefixes, {area: winner nodes}), appended to
     ) -> Dict[str, Optional[RibUnicastEntry]]:
         """Decode device outputs for the given (result_index, prefix)
         pairs.  When ``gather_rows`` is set, candidate-table columns are
-        indexed by gather_rows[i]; device outputs always by i.
+        indexed by gather_rows[i]; device outputs always by i.  KSP2
+        prefixes get no result here: they and their winners are appended
+        to ``ksp2`` for the caller's ``_ksp2_routes``.
 
         The per-route loop is the host-side tail of every full build, so
         everything per-winner is vectorized up front (one object-array
@@ -2359,8 +2424,7 @@ class TpuBackend(DecisionBackend):
         # KSP2 prefixes are classified by the forwarding algorithm of the
         # MIN selection winner (SpfSolver.cpp:247-250), deferred until
         # every area's k-path memo is seeded as one device batch
-        ksp2_prefixes: List[str] = []
-        ksp2_dests: Dict[str, list] = {}
+        ksp2_prefixes, ksp2_dests = ksp2
         for i, prefix in row_items:
             c0 = u_starts_l[i]
             c1 = u_starts_l[i + 1]
@@ -2426,19 +2490,6 @@ class TpuBackend(DecisionBackend):
                 igp_cost=shortest_metric,
                 local_prefix_considered=local_considered,
             )
-        if ksp2_prefixes:
-            self._ksp2_present = True
-            for a, dests in sorted(ksp2_dests.items()):
-                ai = enc.area_index(a)
-                self._ksp2_engine(
-                    a, area_link_states[a], enc.topos[ai]
-                ).seed(dests)
-            for prefix in ksp2_prefixes:
-                # scalar KSP2 chain over the device-seeded k-path memo —
-                # no host Dijkstra runs (decision/ksp2.py)
-                results[prefix] = self.solver.create_route_for_prefix(
-                    prefix, area_link_states, prefix_state
-                )
         return results
 
     def _merged_nexthops(

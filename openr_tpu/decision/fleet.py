@@ -546,6 +546,7 @@ class FleetRibEngine:
                 for r in np.nonzero(st["use"][ri].any(axis=1))[0]
                 if table.row_prefix[r] is not None
             ]
+            ksp2 = ([], {})
             results = tb._decode_rows(
                 row_items,
                 st["use"][ri],
@@ -557,6 +558,13 @@ class FleetRibEngine:
                 st["enc"],
                 area_link_states,
                 prefix_state,
+                ksp2,
+            )
+            # tb's probe is off: its KSP2 phases stay inside this decode
+            results.update(
+                tb._ksp2_routes(
+                    ksp2, st["enc"], area_link_states, prefix_state
+                )
             )
             db = DecisionRouteDb()
             for _prefix, entry in sorted(results.items()):

@@ -22,12 +22,14 @@ Dijkstra.
 
 from __future__ import annotations
 
+from collections import Counter
 from typing import Dict, List, Optional, Sequence, Set, Tuple
 
 import numpy as np
 
 from openr_tpu.decision.link_state import Link, LinkState, Path
 from openr_tpu.ops.csr import EncodedTopology, link_failure_batch
+from openr_tpu.tracing import disabled_probe, pipeline
 
 _BIG = np.float32(3.4e38)
 
@@ -43,16 +45,25 @@ class Ksp2DeviceEngine:
     """
 
     def __init__(
-        self, link_state: LinkState, topo: EncodedTopology, root: str
+        self,
+        link_state: LinkState,
+        topo: EncodedTopology,
+        root: str,
+        probe=None,
+        counts: Optional[Counter] = None,
     ) -> None:
         self.link_state = link_state
         self.topo = topo
         self.root = root
+        self.probe = probe if probe is not None else disabled_probe()
+        #: device_batches, resolve_rows (real destinations),
+        #: resolve_padded_rows (after the BATCH_BUCKETS pad) and
+        #: seeded_dests; a backend passes one Counter to every engine
+        #: it makes, so the counts outlive the per-topology engines
+        self.counts = counts if counts is not None else Counter()
         self._link_id: Dict[Tuple[str, str, str, str], int] = {
             link.key: i for i, link in enumerate(topo.links)
         }
-        self.num_device_batches = 0
-        self.num_seeded = 0
 
     # -- public entry ------------------------------------------------------
 
@@ -67,21 +78,26 @@ class Ksp2DeviceEngine:
         if not todo:
             return
         # k=1: trace over the (memoized) base SPF — cheap, scalar-exact
-        ignore_ids: List[List[int]] = []
-        for d in todo:
-            ignored: Set[Link] = set()
-            for path in ls.get_kth_paths(root, d, 1):
-                ignored.update(path)
-            ignore_ids.append(sorted(self._link_id[l.key] for l in ignored))
+        with self.probe.phase(pipeline.KSP2_TRACE):
+            ignore_ids: List[List[int]] = []
+            for d in todo:
+                ignored: Set[Link] = set()
+                for path in ls.get_kth_paths(root, d, 1):
+                    ignored.update(path)
+                ignore_ids.append(
+                    sorted(self._link_id[l.key] for l in ignored)
+                )
 
-        dist2 = self._device_resolve(ignore_ids)
-        for row, d in enumerate(todo):
-            ignored_links = {
-                self.topo.links[i] for i in ignore_ids[row]
-            }
-            paths = self._trace_all(d, dist2[row], ignored_links)
-            ls.seed_kth_paths(root, d, 2, paths)
-            self.num_seeded += 1
+        with self.probe.phase(pipeline.KSP2_RESOLVE):
+            dist2 = self._device_resolve(ignore_ids)
+        with self.probe.phase(pipeline.KSP2_TRACE):
+            for row, d in enumerate(todo):
+                ignored_links = {
+                    self.topo.links[i] for i in ignore_ids[row]
+                }
+                paths = self._trace_all(d, dist2[row], ignored_links)
+                ls.seed_kth_paths(root, d, 2, paths)
+                self.counts["seeded_dests"] += 1
 
     # -- device batch ------------------------------------------------------
 
@@ -102,6 +118,8 @@ class Ksp2DeviceEngine:
         topo = self.topo
         n = len(ignore_ids)
         padded = bucket_for(n, self.BATCH_BUCKETS)
+        self.counts["resolve_rows"] += n
+        self.counts["resolve_padded_rows"] += padded
         # padding rows solve the unmasked topology (cheap no-op work)
         ignore_ids = ignore_ids + [[]] * (padded - n)
         masks = link_failure_batch(topo, ignore_ids)
@@ -116,7 +134,7 @@ class Ksp2DeviceEngine:
             jnp.asarray(topo.overloaded),
             jnp.asarray(roots),
         )
-        self.num_device_batches += 1
+        self.counts["device_batches"] += 1
         # one host fetch for the whole batch: one blocking round trip,
         # not one per root
         return np.asarray(jax.device_get(dist))[:n]

@@ -232,6 +232,22 @@ def _build_descriptions() -> Dict[str, str]:
         "resilience.backend.shadow_nexthop_memo_misses": (
             "distinct winner sets whose next hops a shadow rebuild computed"
         ),
+        "decision.backend.ksp2.device_batches": (
+            "batched masked re-solves dispatched for KSP2_ED_ECMP's "
+            "second path family"
+        ),
+        "decision.backend.ksp2.resolve_rows": (
+            "KSP2 destinations re-solved on the device (their k-path "
+            "memo was empty)"
+        ),
+        "decision.backend.ksp2.resolve_padded_rows": (
+            "rows of the KSP2 re-solve batches after padding to a batch "
+            "bucket"
+        ),
+        "decision.backend.ksp2.seeded_dests": (
+            "KSP2 destinations whose traced paths were seeded into the "
+            "k-path memo"
+        ),
         "decision.backend.pool.size": "chips in the device pool",
         "decision.backend.pool.healthy": "healthy chips in the pool",
         "health.sweeps": "fleet health aggregator sweeps",

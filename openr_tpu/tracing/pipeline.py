@@ -69,6 +69,18 @@ phase              meaning
 ``fib_program``    Fib's host work on one route update: the RIB apply
                    and the route conversions; the agent calls are not
                    in it (the agent is another process in a deployment)
+``ksp2_resolve``   KSP2_ED_ECMP's second-family re-solve on the device
+                   (decision/ksp2.py): the ignore-mask batch build, its
+                   transfer, the batched masked solve and the fetch of
+                   the distance fields
+``ksp2_trace``     KSP2's host path trace: the first family over the
+                   base SPF, the second over the fetched distance
+                   fields, and the seeding of LinkState's k-path memo
+``ksp2_routes``    the KSP2 prefixes' route assembly over the seeded
+                   memo: next hops, path metrics and SR-MPLS label
+                   stacks (``SpfSolver.create_route_for_prefix``)
+``label_routes``   the backend's node-segment-label MPLS routes
+                   (``SpfSolver._build_node_label_routes``)
 =================  ========================================================
 
 Surfaces: every phase sample lands in a ``pipeline.{phase}.ms``
@@ -120,6 +132,10 @@ SHADOW_REBUILD = "shadow_rebuild"
 SHADOW_COMPARE = "shadow_compare"
 DECISION_INGEST = "decision_ingest"
 FIB_PROGRAM = "fib_program"
+KSP2_RESOLVE = "ksp2_resolve"
+KSP2_TRACE = "ksp2_trace"
+KSP2_ROUTES = "ksp2_routes"
+LABEL_ROUTES = "label_routes"
 
 PHASES = (
     HOST_FETCH,
@@ -143,6 +159,10 @@ PHASES = (
     SHADOW_COMPARE,
     DECISION_INGEST,
     FIB_PROGRAM,
+    KSP2_RESOLVE,
+    KSP2_TRACE,
+    KSP2_ROUTES,
+    LABEL_ROUTES,
 )
 
 #: phases only the warm-start generation-delta rebuild exercises — a
@@ -178,6 +198,10 @@ EVENT_PHASES = (
     FIB_PROGRAM,
 )
 
+#: phases only a build with KSP2_ED_ECMP prefixes or SR-MPLS node
+#: labels exercises: an SP_ECMP-only rebuild records nothing here
+KSP2_PHASES = (KSP2_RESOLVE, KSP2_TRACE, KSP2_ROUTES, LABEL_ROUTES)
+
 #: phases whose time is host-side work (the pipelining refactor's
 #: overlap candidates) vs the device round trip — the host/device split
 #: BENCH_PIPELINE reports.  ``stream_drain`` counts as device time: it
@@ -193,6 +217,9 @@ HOST_PHASES = (
     SWEEP_REDUCE,
     PROTECTION_MINT,
     PROTECTION_APPLY,
+    KSP2_TRACE,
+    KSP2_ROUTES,
+    LABEL_ROUTES,
 ) + EVENT_PHASES
 DEVICE_PHASES = (
     TRANSFER,
@@ -202,6 +229,7 @@ DEVICE_PHASES = (
     STREAM_DRAIN,
     DEVICE_SELECT,
     SWEEP_SHARD_SOLVE,
+    KSP2_RESOLVE,
 )
 
 _PREFIX = "pipeline."

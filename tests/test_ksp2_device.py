@@ -84,8 +84,8 @@ def test_engine_seeded_paths_match_scalar_kth_paths():
     topo = encode_link_state(ls_dev)
     eng = Ksp2DeviceEngine(ls_dev, topo, root)
     eng.seed(dests)
-    assert eng.num_device_batches == 1
-    assert eng.num_seeded == len(dests)
+    assert eng.counts["device_batches"] == 1
+    assert eng.counts["seeded_dests"] == len(dests)
 
     for d in dests:
         seeded = ls_dev.get_kth_paths(root, d, 2)
@@ -106,11 +106,11 @@ def test_engine_seed_is_memoized_until_topology_change():
     topo = encode_link_state(ls)
     eng = Ksp2DeviceEngine(ls, topo, "node0")
     eng.seed(["node8", "node4"])
-    assert eng.num_device_batches == 1
+    assert eng.counts["device_batches"] == 1
     eng.seed(["node8", "node4"])  # memo hit: no second device call
-    assert eng.num_device_batches == 1
+    assert eng.counts["device_batches"] == 1
     eng.seed(["node8", "node4", "node7"])  # only the new dest solves
-    assert eng.num_device_batches == 2
+    assert eng.counts["device_batches"] == 2
 
 
 def test_tpu_backend_ksp2_fabric_matches_scalar():

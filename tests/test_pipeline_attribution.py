@@ -647,3 +647,147 @@ def test_chrome_trace_emits_per_device_lanes():
         if "decision.spf_kernel" in names
     ]
     assert len(kernel_lanes) == 2
+
+
+# ---------------------------------------------------------------------------
+# KSP2_ED_ECMP and SR-MPLS label routes: their own phases, outside decode
+# ---------------------------------------------------------------------------
+
+
+def _fabric_world(ksp2: bool):
+    """A 2-pod fabric seen from a fabric switch (every rack has a second
+    path family), node labels on, one prefix per rack switch."""
+    from openr_tpu.emulation.topology import fabric_edges
+    from openr_tpu.types import PrefixForwardingAlgorithm, PrefixForwardingType
+
+    edges = fabric_edges(num_pods=2, rsws_per_pod=4, fsws_per_pod=2,
+                         num_ssws=4)
+    nodes = sorted({n for e in edges for n in e[:2]})
+    labels = {n: 101 + i for i, n in enumerate(nodes)}
+    ls = LinkState("0", "fsw0_0")
+    for db in build_adj_dbs(edges, node_labels=labels).values():
+        ls.update_adjacency_database(db)
+    ps = PrefixState()
+    racks = [n for n in nodes if n.startswith("rsw")]
+    for i, n in enumerate(racks):
+        entry = (
+            PrefixEntry(
+                f"10.8.{i}.0/24",
+                forwarding_type=PrefixForwardingType.SR_MPLS,
+                forwarding_algorithm=PrefixForwardingAlgorithm.KSP2_ED_ECMP,
+            )
+            if ksp2
+            else PrefixEntry(f"10.8.{i}.0/24")
+        )
+        ps.update_prefix(n, "0", entry)
+    return {"0": ls}, ps, racks
+
+
+def _ksp2_backend(counters, sr_mpls=True):
+    from openr_tpu.decision.backend import TpuBackend
+
+    return TpuBackend(
+        SpfSolver("fsw0_0", enable_node_segment_label=sr_mpls),
+        clock=SimClock(),
+        counters=counters,
+        resilience=ResilienceConfig(enabled=False),
+        parallel=ParallelConfig(max_devices=1),
+    )
+
+
+@pytest.fixture
+def phase_of_call(monkeypatch):
+    """{what: [innermost open phase at each call]} for the KSP2 engine's
+    re-solve and trace, the KSP2 route assembly and the label routes."""
+    from openr_tpu.decision.ksp2 import Ksp2DeviceEngine
+    from openr_tpu.tracing.pipeline import _PhaseScope
+
+    open_scopes, seen = [], {}
+    enter, exit_ = _PhaseScope.__enter__, _PhaseScope.__exit__
+
+    def recording_enter(self):
+        open_scopes.append(self._phase)
+        return enter(self)
+
+    def recording_exit(self, *exc):
+        open_scopes.pop()
+        return exit_(self, *exc)
+
+    monkeypatch.setattr(_PhaseScope, "__enter__", recording_enter)
+    monkeypatch.setattr(_PhaseScope, "__exit__", recording_exit)
+
+    def watch(cls, name, what):
+        orig = getattr(cls, name)
+
+        def wrapped(*a, **k):
+            seen.setdefault(what, []).append(
+                open_scopes[-1] if open_scopes else None)
+            return orig(*a, **k)
+
+        monkeypatch.setattr(cls, name, wrapped)
+
+    watch(Ksp2DeviceEngine, "_device_resolve", "resolve")
+    watch(Ksp2DeviceEngine, "_trace_all", "trace")
+    watch(SpfSolver, "create_route_for_prefix", "route")
+    watch(SpfSolver, "_build_node_label_routes", "labels")
+    return seen
+
+
+def test_ksp2_sr_build_records_its_phases_outside_decode(nested_phases,
+                                                         phase_of_call):
+    counters = CounterMap()
+    als, ps, racks = _fabric_world(ksp2=True)
+    backend = _ksp2_backend(counters)
+    db = backend.build_route_db(als, ps)
+    assert backend.num_device_builds == 1 and db.mpls_routes
+    for phase in pipeline.KSP2_PHASES:
+        h = counters.histogram(pipeline.hist_key(phase))
+        assert h is not None and h.count >= 1, phase
+    assert nested_phases == []
+    # the work runs in its own phase, never inside decode
+    assert set(phase_of_call["resolve"]) == {pipeline.KSP2_RESOLVE}
+    assert set(phase_of_call["trace"]) == {pipeline.KSP2_TRACE}
+    assert set(phase_of_call["route"]) == {pipeline.KSP2_ROUTES}
+    assert set(phase_of_call["labels"]) == {pipeline.LABEL_ROUTES}
+    assert pipeline.KSP2_RESOLVE in pipeline.DEVICE_PHASES
+    assert {pipeline.KSP2_TRACE, pipeline.KSP2_ROUTES,
+            pipeline.LABEL_ROUTES} <= set(pipeline.HOST_PHASES)
+
+
+def test_ksp2_counters_count_unmemoised_destinations_and_their_bucket():
+    from openr_tpu.decision.ksp2 import Ksp2DeviceEngine
+    from openr_tpu.ops.csr import bucket_for
+
+    counters = CounterMap()
+    als, ps, racks = _fabric_world(ksp2=True)
+    backend = _ksp2_backend(counters)
+    backend.build_route_db(als, ps)
+    snap = backend.counter_snapshot()
+    rows = len(racks)
+    assert snap["decision.backend.ksp2.device_batches"] == 1
+    assert snap["decision.backend.ksp2.resolve_rows"] == rows
+    assert snap["decision.backend.ksp2.seeded_dests"] == rows
+    assert snap["decision.backend.ksp2.resolve_padded_rows"] == bucket_for(
+        rows, Ksp2DeviceEngine.BATCH_BUCKETS)
+    # memoised: a rebuild on the same topology re-solves nothing
+    backend.build_route_db(als, ps, force_full=True)
+    assert backend.counter_snapshot()[
+        "decision.backend.ksp2.resolve_rows"] == rows
+    from openr_tpu.monitor.metrics import metric_description
+
+    for k in snap:
+        if k.startswith("decision.backend.ksp2."):
+            assert metric_description(k), k
+
+
+def test_sp_ecmp_build_records_no_ksp2_phase():
+    counters = CounterMap()
+    als, ps, _racks = _fabric_world(ksp2=False)
+    backend = _ksp2_backend(counters, sr_mpls=False)
+    backend.build_route_db(als, ps)
+    assert backend.num_device_builds == 1
+    for phase in pipeline.KSP2_PHASES:
+        h = counters.histogram(pipeline.hist_key(phase))
+        assert h is None or h.count == 0, phase
+    assert backend.counter_snapshot()[
+        "decision.backend.ksp2.device_batches"] == 0
