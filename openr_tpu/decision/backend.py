@@ -384,6 +384,9 @@ class TpuBackend(DecisionBackend):
         #: itself lives in the LinkState; this only avoids rebuilding the
         #: link-id table every rebuild
         self._ksp2_engines: dict = {}
+        #: Ksp2Record per area: the traced k-paths kept across topology
+        #: generations (decision/ksp2.py), dropped on device suspicion
+        self._ksp2_records: dict = {}
         #: KSP2 seeding work across every engine this backend made
         #: (``decision.backend.ksp2.*``, see Ksp2DeviceEngine.counts)
         self.ksp2_counts: Counter = Counter()
@@ -661,6 +664,9 @@ class TpuBackend(DecisionBackend):
             # the full-build delta context is device residency too: a
             # suspect device must not vouch for "row unchanged"
             self._prev_sel = None
+            # nor for "KSP2 distance row unchanged"
+            for rec in self._ksp2_records.values():
+                rec.drop("purge")
         if self._warm_ctx is None and self._warm_changed_nodes is None:
             return
         self._warm_ctx = None
@@ -904,11 +910,17 @@ class TpuBackend(DecisionBackend):
                 self.num_delta_rows_skipped
             ),
         }
+        from openr_tpu.decision.ksp2 import FULL_RETRACE_REASONS
+
         for k in (
             "device_batches",
             "resolve_rows",
             "resolve_padded_rows",
             "seeded_dests",
+            "reused_dests",
+            "retraced_dests",
+            "full_retrace",
+            *(f"full_retrace.{r}" for r in FULL_RETRACE_REASONS),
         ):
             out[f"decision.backend.ksp2.{k}"] = float(self.ksp2_counts[k])
         for reason, n in sorted(self._slot_decline_reasons.items()):
@@ -1027,17 +1039,29 @@ class TpuBackend(DecisionBackend):
         return enc
 
     def _ksp2_engine(self, area: str, link_state, topo):
-        from openr_tpu.decision.ksp2 import Ksp2DeviceEngine
+        from openr_tpu.decision.ksp2 import Ksp2DeviceEngine, Ksp2Record
 
+        root = self.solver.my_node_name
+        rec = self._ksp2_records.get(area)
+        if rec is None or rec.link_state is not link_state or rec.root != root:
+            rec = self._ksp2_records[area] = Ksp2Record(
+                link_state, root, "cold" if rec is None else "link_state"
+            )
         key = (area, link_state.topology_seq)
         eng = self._ksp2_engines.get(key)
-        if eng is None or eng.link_state is not link_state or eng.topo is not topo:
+        if (
+            eng is None
+            or eng.link_state is not link_state
+            or eng.topo is not topo
+            or eng.record is not rec
+        ):
             eng = Ksp2DeviceEngine(
                 link_state,
                 topo,
-                self.solver.my_node_name,
+                root,
                 probe=self.probe,
                 counts=self.ksp2_counts,
+                record=rec,
             )
             self._ksp2_engines[key] = eng
         return eng

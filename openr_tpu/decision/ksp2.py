@@ -6,9 +6,10 @@ full Dijkstra with the links of paths 1..k-1 ignored, once per destination
 KSP2_ED_ECMP that is O(destinations) host Dijkstras per rebuild, the hot
 loop.  Here the re-solves run as ONE batched device call
 (``batched_spf_distances_masked``: vmapped masked Bellman-Ford over a
-[U, E] ignore-mask batch), and only the cheap part — greedy path tracing
-over the shortest-path DAG (traceOnePath, LinkState.cpp:227-247) — stays
-on the host, reconstructed from the device distance fields.
+[U, E] ignore-mask batch built on the device from a [U, K] matrix of
+ignored link ids), and only the cheap part — greedy path tracing over
+the shortest-path DAG (traceOnePath, LinkState.cpp:227-247) — stays on
+the host, reconstructed from the distance fields.
 
 Exactness: ``LinkState.run_spf`` iterates sorted adjacency, so its
 ``path_links`` order is (settle-order of predecessor, link order) — the
@@ -18,20 +19,119 @@ LinkState k-path memo (``seed_kth_paths``), after which the unmodified
 scalar KSP2 selection chain (SpfSolver._select_best_paths_ksp2, SR-MPLS
 label stacks, cross-area merge, min-nexthop gate) runs without any host
 Dijkstra.
+
+Retained traces: a family's trace is a deterministic function of
+``path_links(v)`` over the nodes v it visits, and ``path_links(v)`` reads
+only v's incident links (up, metric, ignored), the distances of v and of
+the neighbours it compares, and the neighbours' overload bits.  A
+``Ksp2Record`` keeps, per destination and family, the traced paths (by
+link key), the visited nodes and the distances the trace read.  Across
+topology generations a family is kept when no link incident to a visited
+node changed and every recorded distance is equal in the fresh field
+(the unmasked root row for family 1, the re-solved masked row for family
+2, which is kept only with its family 1).  The batched re-solve of every
+destination is therefore the change detector, and any other change (node
+membership, overload, node metric increment, adjacency label, encoding)
+drops the whole record.
 """
 
 from __future__ import annotations
 
+import operator
 from collections import Counter
-from typing import Dict, List, Optional, Sequence, Set, Tuple
+from typing import (
+    Dict,
+    FrozenSet,
+    List,
+    NamedTuple,
+    Optional,
+    Sequence,
+    Set,
+    Tuple,
+)
 
 import numpy as np
 
 from openr_tpu.decision.link_state import Link, LinkState, Path
-from openr_tpu.ops.csr import EncodedTopology, link_failure_batch
+from openr_tpu.ops.csr import EncodedTopology
 from openr_tpu.tracing import disabled_probe, pipeline
 
 _BIG = np.float32(3.4e38)
+
+LinkKey = Tuple[str, str, str, str]
+
+#: why a generation dropped the whole retained record and re-traced every
+#: destination (``decision.backend.ksp2.full_retrace.<reason>``)
+FULL_RETRACE_REASONS = {
+    "cold": "no record yet, the area's first KSP2 build",
+    "purge": "a suspect device (corruption, quarantine, full replace)",
+    "link_state": "another LinkState object or root for the area",
+    "node_set": "a node joined or left",
+    "overload": "a node's overload bit flipped",
+    "node_metric": "a node metric increment changed",
+    "adj_label": "an adjacency label changed",
+    "encoding": "a re-encode moved the node ids",
+}
+
+
+_LINK_KEY = operator.attrgetter("_key")
+#: a link's snapshot: what decides Link.is_up and get_max_metric, then
+#: the adjacency labels
+_LINK_STATE = operator.attrgetter(
+    "overload1", "overload2", "usable", "metric1", "metric2",
+    "adj_label1", "adj_label2",
+)
+
+
+class _Family(NamedTuple):
+    """One destination's traced path family and what its trace read."""
+
+    paths: Tuple[Tuple[LinkKey, ...], ...]
+    #: nodes whose path links the trace consulted
+    visited: FrozenSet[str]
+    #: node ids of the visited nodes, the neighbours whose distance they
+    #: compared and the destination, with those distances
+    check_ids: np.ndarray
+    check_dist: np.ndarray
+
+
+class Ksp2Record:
+    """One area's traced k-paths, kept across topology generations.
+
+    Owned by the backend per area so it outlives the per-generation
+    engines; bound to one ``LinkState`` object and root.  ``seq`` is the
+    generation whose link and node snapshots ``dests`` were traced or
+    verified against; ``reason`` (set while nothing is retained) is why
+    the next generation re-traces every destination."""
+
+    def __init__(self, link_state: LinkState, root: str, reason: str = "cold"):
+        self.link_state = link_state
+        self.root = root
+        self.seq: Optional[int] = None
+        self.links: Optional[Dict[LinkKey, tuple]] = None
+        self.nodes: Optional[Dict[str, tuple]] = None
+        self.node_ids: Optional[Dict[str, int]] = None
+        self.dests: Dict[str, Tuple[_Family, _Family]] = {}
+        self.reason: Optional[str] = reason
+
+    def drop(self, reason: str) -> None:
+        """Forget every retained trace, and the paths this record seeded
+        into the LinkState memo: the next build re-traces them all."""
+        if self.dests:
+            self.link_state.clear_spf_memoization()
+        self.seq = self.links = self.nodes = self.node_ids = None
+        self.dests = {}
+        self.reason = reason
+
+
+def _node_change(old: dict, new: dict) -> Optional[str]:
+    if old == new:
+        return None
+    if old.keys() != new.keys():
+        return "node_set"
+    if any(old[n][0] != v[0] for n, v in new.items()):
+        return "overload"
+    return "node_metric"
 
 
 class Ksp2DeviceEngine:
@@ -39,9 +139,11 @@ class Ksp2DeviceEngine:
 
     ``seed(dests)`` guarantees ``link_state.get_kth_paths(root, d, k)`` for
     k in (1, 2) is memoized for every d in dests without running host
-    Dijkstra for the k=2 re-solves.  Results live in the LinkState memo, so
-    repeat rebuilds on an unchanged topology are free; the memo is cleared
-    by LinkState on topology change, which re-arms this engine.
+    Dijkstra.  Results live in the LinkState memo, so repeat rebuilds on
+    an unchanged topology are free; the memo is cleared by LinkState on
+    topology change, and the next generation's first ``seed`` re-solves
+    every destination on the device but re-traces only the families whose
+    inputs moved (``record``, see the module doc).
     """
 
     def __init__(
@@ -51,17 +153,23 @@ class Ksp2DeviceEngine:
         root: str,
         probe=None,
         counts: Optional[Counter] = None,
+        record: Optional[Ksp2Record] = None,
     ) -> None:
         self.link_state = link_state
         self.topo = topo
         self.root = root
         self.probe = probe if probe is not None else disabled_probe()
         #: device_batches, resolve_rows (real destinations),
-        #: resolve_padded_rows (after the BATCH_BUCKETS pad) and
-        #: seeded_dests; a backend passes one Counter to every engine
-        #: it makes, so the counts outlive the per-topology engines
+        #: resolve_padded_rows (after the BATCH_BUCKETS pad),
+        #: seeded_dests = reused_dests (both families kept) +
+        #: retraced_dests, and full_retrace (+ .<reason>); a backend
+        #: passes one Counter to every engine it makes, so the counts
+        #: outlive the per-topology engines
         self.counts = counts if counts is not None else Counter()
-        self._link_id: Dict[Tuple[str, str, str, str], int] = {
+        self.record = (
+            record if record is not None else Ksp2Record(link_state, root)
+        )
+        self._link_id: Dict[LinkKey, int] = {
             link.key: i for i, link in enumerate(topo.links)
         }
 
@@ -77,27 +185,123 @@ class Ksp2DeviceEngine:
         ]
         if not todo:
             return
-        # k=1: trace over the (memoized) base SPF — cheap, scalar-exact
         with self.probe.phase(pipeline.KSP2_TRACE):
+            prior, touched = self._advance()
+            # family 1 over the root's unmasked distances: the memoized
+            # base SPF, which route assembly reads as well
+            dist1 = self._root_distances()
+            olds: List[Optional[Tuple[_Family, _Family]]] = []
+            fam1: List[_Family] = []
             ignore_ids: List[List[int]] = []
             for d in todo:
-                ignored: Set[Link] = set()
-                for path in ls.get_kth_paths(root, d, 1):
-                    ignored.update(path)
+                old = prior.get(d)
+                if old is not None and not self._holds(old[0], touched, dist1):
+                    old = None
+                f1 = old[0] if old is not None else self._trace(
+                    d, dist1, frozenset()
+                )
+                olds.append(old)
+                fam1.append(f1)
                 ignore_ids.append(
-                    sorted(self._link_id[l.key] for l in ignored)
+                    sorted({self._link_id[k] for p in f1.paths for k in p})
                 )
 
         with self.probe.phase(pipeline.KSP2_RESOLVE):
             dist2 = self._device_resolve(ignore_ids)
         with self.probe.phase(pipeline.KSP2_TRACE):
+            all_links = ls.all_links()
+            link_of = dict(zip(map(_LINK_KEY, all_links), all_links))
+            dests_rec = self.record.dests
             for row, d in enumerate(todo):
-                ignored_links = {
-                    self.topo.links[i] for i in ignore_ids[row]
-                }
-                paths = self._trace_all(d, dist2[row], ignored_links)
-                ls.seed_kth_paths(root, d, 2, paths)
+                f1, old = fam1[row], olds[row]
+                row2 = dist2[row]
+                if old is not None and self._holds(old[1], touched, row2):
+                    f2 = old[1]
+                    self.counts["reused_dests"] += 1
+                else:
+                    ignored = {link_of[k] for p in f1.paths for k in p}
+                    f2 = self._trace(d, row2, ignored)
+                    self.counts["retraced_dests"] += 1
+                for k, fam in ((1, f1), (2, f2)):
+                    paths = [[link_of[key] for key in p] for p in fam.paths]
+                    ls.seed_kth_paths(root, d, k, paths)
+                dests_rec[d] = (f1, f2)
                 self.counts["seeded_dests"] += 1
+
+    # -- retained record ---------------------------------------------------
+
+    def _advance(self) -> Tuple[Dict[str, tuple], Set[str]]:
+        """Bring the record to this topology generation.  Returns the
+        destinations whose families may be kept, and the endpoints of
+        every link whose (up, metric) changed since they were traced.
+        Within one generation nothing is kept: a destination that is not
+        memoized is new to it."""
+        rec, ls = self.record, self.link_state
+        if rec.links is not None and rec.seq == ls.topology_seq:
+            return {}, set()
+        all_links = ls.all_links()
+        links = dict(
+            zip(map(_LINK_KEY, all_links), map(_LINK_STATE, all_links))
+        )
+        nodes = {
+            n: (ls.is_node_overloaded(n), ls.get_node_metric_increment(n))
+            for n in ls.get_adjacency_databases()
+        }
+        node_ids = self.topo.node_ids
+        reason = rec.reason
+        touched: Set[str] = set()
+        if reason is None:
+            reason = _node_change(rec.nodes, nodes)
+        if reason is None and node_ids is not rec.node_ids and (
+            node_ids != rec.node_ids
+        ):
+            reason = "encoding"
+        if reason is None and links != rec.links:
+            old_links = rec.links
+            for key in {key for key, _ in links.items() ^ old_links.items()}:
+                was, now = old_links.get(key), links.get(key)
+                if was is not None and now is not None and was[5:] != now[5:]:
+                    reason = "adj_label"
+                    break
+                touched.update((key[0], key[2]))
+        prior = rec.dests
+        if reason is not None:
+            prior = {}
+            self.counts["full_retrace"] += 1
+            self.counts[f"full_retrace.{reason}"] += 1
+        rec.seq, rec.links, rec.nodes = ls.topology_seq, links, nodes
+        rec.node_ids, rec.dests, rec.reason = node_ids, {}, None
+        return prior, touched
+
+    @staticmethod
+    def _holds(fam: _Family, touched: Set[str], dist: np.ndarray) -> bool:
+        """True when every input ``fam``'s trace read is unchanged."""
+        return fam.visited.isdisjoint(touched) and np.array_equal(
+            dist[fam.check_ids], fam.check_dist
+        )
+
+    def _root_distances(self) -> np.ndarray:
+        """The root's unmasked distances by node id, exact (float64) from
+        the memoized base SPF: family 1 traced over them is
+        ``get_kth_paths(root, d, 1)`` bit for bit."""
+        ids = self.topo.node_ids
+        dist = np.full(self.topo.padded_nodes, np.inf)
+        for n, res in self.link_state.get_spf_result(self.root).items():
+            dist[ids[n]] = res.metric
+        return dist
+
+    def _trace(self, dest: str, dist: np.ndarray, ignored) -> _Family:
+        paths, visited, reads = self._trace_all(dest, dist, ignored)
+        reads |= visited
+        reads.add(dest)
+        ids = self.topo.node_ids
+        check_ids = np.fromiter((ids[n] for n in reads), np.int64, len(reads))
+        return _Family(
+            tuple(tuple(link.key for link in p) for p in paths),
+            frozenset(visited),
+            check_ids,
+            dist[check_ids],
+        )
 
     # -- device batch ------------------------------------------------------
 
@@ -106,6 +310,8 @@ class Ksp2DeviceEngine:
     #: (prefix churn re-arms a few dests at a time) — same discipline as
     #: node_buckets/cand_buckets in the encoder
     BATCH_BUCKETS = (8, 32, 128, 512, 2048, 8192, 32768)
+    #: buckets of the ignored-id matrix's width (links of a family 1)
+    IGNORE_BUCKETS = (16, 64, 256, 1024, 4096, 16384, 65536)
 
     def _device_resolve(self, ignore_ids: List[List[int]]) -> np.ndarray:
         import jax
@@ -113,16 +319,30 @@ class Ksp2DeviceEngine:
 
         from openr_tpu.ops.csr import bucket_for
         from openr_tpu.ops.jit_guard import call_jit_guarded
-        from openr_tpu.ops.spf import batched_spf_distances_masked
+        from openr_tpu.ops.spf import (
+            batched_spf_distances_masked,
+            link_ignore_masks,
+        )
 
         topo = self.topo
         n = len(ignore_ids)
         padded = bucket_for(n, self.BATCH_BUCKETS)
         self.counts["resolve_rows"] += n
         self.counts["resolve_padded_rows"] += padded
-        # padding rows solve the unmasked topology (cheap no-op work)
-        ignore_ids = ignore_ids + [[]] * (padded - n)
-        masks = link_failure_batch(topo, ignore_ids)
+        width = bucket_for(
+            max(1, max(len(ids) for ids in ignore_ids)), self.IGNORE_BUCKETS
+        )
+        # padding rows (and padding ids) ignore nothing: the unmasked
+        # topology, cheap no-op work
+        ids = np.full((padded, width), -1, np.int32)
+        for row, row_ids in enumerate(ignore_ids):
+            ids[row, : len(row_ids)] = row_ids
+        masks = call_jit_guarded(
+            link_ignore_masks,
+            jnp.asarray(topo.link_edge_pos),
+            jnp.asarray(ids),
+            num_edges=topo.padded_edges,
+        )
         roots = np.full(padded, topo.node_id(self.root), np.int32)
         dist = call_jit_guarded(
             batched_spf_distances_masked,
@@ -130,7 +350,7 @@ class Ksp2DeviceEngine:
             jnp.asarray(topo.dst),
             jnp.asarray(topo.w),
             jnp.asarray(topo.edge_ok),
-            jnp.asarray(masks),
+            masks,
             jnp.asarray(topo.overloaded),
             jnp.asarray(roots),
         )
@@ -139,48 +359,58 @@ class Ksp2DeviceEngine:
         # not one per root
         return np.asarray(jax.device_get(dist))[:n]
 
-    # -- host trace over the device distance field -------------------------
+    # -- host trace over a distance field ----------------------------------
 
     def _path_links(
         self,
         node: str,
         dist: np.ndarray,
         ignored: Set[Link],
+        reads: Set[str],
     ) -> List[Tuple[Link, str]]:
         """Reconstruct NodeSpfResult.path_links for `node` in run_spf's
         append order: predecessors settle in (metric, name) heap order and
         each relaxes its sorted links (run_spf iterates
-        ordered_links_from_node), so the key is (dist[prev], prev, link)."""
+        ordered_links_from_node), so the key is (dist[prev], prev, link).
+        Adds every neighbour whose distance it compared to ``reads``."""
         ls = self.link_state
         ids = self.topo.node_ids
+        num = dist.dtype.type  # float32 device rows, float64 root row
         dv = dist[ids[node]]
-        out: List[Tuple[np.float32, str, Link]] = []
+        out: List[Tuple[np.floating, str, Link]] = []
         for link in ls.ordered_links_from_node(node):
             prev = link.get_other_node_name(node)
             if not link.is_up() or link in ignored:
                 continue
             if ls.is_node_overloaded(prev) and prev != self.root:
                 continue
+            reads.add(prev)
             du = dist[ids[prev]]
             if du >= _BIG:
                 continue
-            if np.float32(du + np.float32(link.get_max_metric())) == dv:
+            if num(du + num(link.get_max_metric())) == dv:
                 out.append((du, prev, link))
         out.sort(key=lambda t: (t[0], t[1], t[2].key))
         return [(link, prev) for _, prev, link in out]
 
     def _trace_all(
-        self, dest: str, dist: np.ndarray, ignored: Set[Link]
-    ) -> List[Path]:
+        self, dest: str, dist: np.ndarray, ignored
+    ) -> Tuple[List[Path], Set[str], Set[str]]:
+        """Every path of the family (LinkState.get_kth_paths' trace loop),
+        the nodes whose path links it consulted, and the neighbours whose
+        distance those compared."""
+        reads: Set[str] = set()
         if dist[self.topo.node_id(dest)] >= _BIG:
-            return []
+            return [], set(), reads
         visited: Set[Link] = set()
         pl_cache: Dict[str, List[Tuple[Link, str]]] = {}
 
         def path_links(v: str) -> List[Tuple[Link, str]]:
             cached = pl_cache.get(v)
             if cached is None:
-                cached = pl_cache[v] = self._path_links(v, dist, ignored)
+                cached = pl_cache[v] = self._path_links(
+                    v, dist, ignored, reads
+                )
             return cached
 
         def trace_one(v: str) -> Optional[Path]:
@@ -202,4 +432,4 @@ class Ksp2DeviceEngine:
         while path:
             paths.append(path)
             path = trace_one(dest)
-        return paths
+        return paths, set(pl_cache), reads

@@ -240,6 +240,18 @@ def _build_descriptions() -> Dict[str, str]:
             "KSP2 destinations re-solved on the device (their k-path "
             "memo was empty)"
         ),
+        "decision.backend.ksp2.reused_dests": (
+            "seeded KSP2 destinations whose both traced path families "
+            "were kept from the previous topology generation"
+        ),
+        "decision.backend.ksp2.retraced_dests": (
+            "seeded KSP2 destinations with at least one path family "
+            "traced anew (an input of its trace moved, or it was new)"
+        ),
+        "decision.backend.ksp2.full_retrace": (
+            "topology generations that dropped every retained KSP2 "
+            "trace and re-traced all destinations"
+        ),
         "decision.backend.ksp2.resolve_padded_rows": (
             "rows of the KSP2 re-solve batches after padding to a batch "
             "bucket"
@@ -253,6 +265,12 @@ def _build_descriptions() -> Dict[str, str]:
         "health.sweeps": "fleet health aggregator sweeps",
         "health.alerts.active": "currently-firing fleet health alerts",
     }
+    from openr_tpu.decision.ksp2 import FULL_RETRACE_REASONS
+
+    for reason, why in FULL_RETRACE_REASONS.items():
+        d[f"decision.backend.ksp2.full_retrace.{reason}"] = (
+            f"KSP2 generations that re-traced every destination: {why}"
+        )
     for phase in _pl.PHASES:
         d[_pl.hist_key(phase)] = (
             f"milliseconds in the {phase} pipeline phase per dispatch"

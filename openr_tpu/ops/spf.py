@@ -222,6 +222,28 @@ def batched_spf(
     return jax.vmap(one)(edge_enabled, overloaded, roots)
 
 
+@functools.partial(jax.jit, static_argnames=("num_edges",))
+def link_ignore_masks(
+    link_edge_pos,  # [L, 2] directed-edge positions of each link
+    ignore_ids,  # [B, K] ignored undirected link ids, -1 pad
+    num_edges: int,
+):
+    """[B, E] edge-enable masks built on the device: both directed edges
+    of every listed link are off — the same mask ``csr.link_failure_batch``
+    builds on the host, from a [B, K] id matrix instead of [B, E] bytes
+    (the KSP2 re-solve batch's input)."""
+    valid = ignore_ids >= 0
+    pos = link_edge_pos[jnp.where(valid, ignore_ids, 0)]  # [B, K, 2]
+    # pads point past the edge axis, where mode="drop" discards them
+    pos = jnp.where(valid[..., None], pos, num_edges)
+    rows = jnp.arange(ignore_ids.shape[0])[:, None, None]
+    return (
+        jnp.ones((ignore_ids.shape[0], num_edges), bool)
+        .at[rows, pos]
+        .set(False, mode="drop")
+    )
+
+
 @jax.jit
 def batched_spf_distances_masked(
     src,  # [E] shared edge list

@@ -70,12 +70,13 @@ phase              meaning
                    and the route conversions; the agent calls are not
                    in it (the agent is another process in a deployment)
 ``ksp2_resolve``   KSP2_ED_ECMP's second-family re-solve on the device
-                   (decision/ksp2.py): the ignore-mask batch build, its
-                   transfer, the batched masked solve and the fetch of
-                   the distance fields
+                   (decision/ksp2.py): the ignored-link id matrix, the
+                   mask built from it on the device, the batched masked
+                   solve and the fetch of the distance fields
 ``ksp2_trace``     KSP2's host path trace: the first family over the
                    base SPF, the second over the fetched distance
-                   fields, and the seeding of LinkState's k-path memo
+                   fields (or the check that keeps a retained family),
+                   and the seeding of LinkState's k-path memo
 ``ksp2_routes``    the KSP2 prefixes' route assembly over the seeded
                    memo: next hops, path metrics and SR-MPLS label
                    stacks (``SpfSolver.create_route_for_prefix``)

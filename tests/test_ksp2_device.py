@@ -91,7 +91,7 @@ def test_engine_seeded_paths_match_scalar_kth_paths():
         seeded = ls_dev.get_kth_paths(root, d, 2)
         scalar = ls_ref.get_kth_paths(root, d, 2)
         assert seeded == scalar, d
-    # k=1 untouched by seeding: also identical
+    # k=1 is seeded from the root's base SPF: also identical
     for d in dests:
         assert ls_dev.get_kth_paths(root, d, 1) == ls_ref.get_kth_paths(
             root, d, 1
@@ -261,3 +261,268 @@ def test_tpu_backend_ksp2_with_drains():
     )
     assert _db_view(tpu) == _db_view(scalar)
     assert backend.num_scalar_builds == 0
+
+
+# ---------------------------------------------------------------------------
+# traced k-paths kept across link events (Ksp2Record)
+# ---------------------------------------------------------------------------
+
+
+def _f4_edges():
+    from cellbench.topologies import f4fabric
+
+    return f4fabric.edges(
+        {"pods": 2, "rsws_per_pod": 3, "planes": 2, "ssws_per_plane": 2}
+    )
+
+
+def _tied_random_edges():
+    # metrics 1-2 only: many equal-cost paths, so the trace's tie order
+    # decides which links each family takes
+    return [
+        (a, b, 1 + m % 2) for a, b, m in random_connected_edges(18, 22, seed=7)
+    ]
+
+
+#: name -> (edges, root); the fabrics are seen from a fabric switch, where
+#: every rack has a second path family
+TOPOLOGIES = {
+    "fabric": (
+        lambda: fabric_edges(
+            num_pods=3, rsws_per_pod=3, fsws_per_pod=2, num_ssws=4
+        ),
+        "fsw0_0",
+    ),
+    "f4": (_f4_edges, "fsw0_0"),
+    "random_ties": (_tied_random_edges, "node0"),
+}
+
+
+class _Network:
+    """A LinkState fed link events as each endpoint's LinkMonitor would
+    publish them: a link down withdraws both adjacencies."""
+
+    def __init__(self, edges, root):
+        self.root = root
+        self.base = {(a, b): m for a, b, m in edges}
+        self.metric = dict(self.base)
+        self.down = set()
+        self.overloaded = []
+        self.nodes = sorted({n for e in edges for n in e[:2]})
+        self.labels = {n: 100 + i for i, n in enumerate(self.nodes)}
+        self.ls = self.fresh()
+
+    def edges(self):
+        return [(a, b, m) for (a, b), m in self.metric.items()
+                if (a, b) not in self.down]
+
+    def dbs(self):
+        from openr_tpu.types import AdjacencyDatabase
+
+        dbs = build_adj_dbs(self.edges(), node_labels=self.labels,
+                            overloaded=self.overloaded)
+        for n in self.nodes:
+            dbs.setdefault(n, AdjacencyDatabase(
+                this_node_name=n, adjacencies=[], area="0",
+                node_label=self.labels[n],
+                is_overloaded=n in self.overloaded))
+        return dbs
+
+    def fresh(self) -> LinkState:
+        ls = LinkState("0", self.root)
+        for db in self.dbs().values():
+            ls.update_adjacency_database(db)
+        return ls
+
+    def publish(self, *nodes):
+        dbs = self.dbs()
+        for n in nodes:
+            self.ls.update_adjacency_database(dbs[n])
+
+    def apply(self, kind, link, metric=None):
+        if kind == "down":
+            self.down.add(link)
+        elif kind == "up":
+            self.down.discard(link)
+        elif kind == "metric":
+            self.metric[link] = metric
+        else:  # restore
+            self.metric[link] = self.base[link]
+        self.publish(*link)
+
+
+def _ksp2_prefixes(nodes, root):
+    ps = PrefixState()
+    for i, n in enumerate(x for x in nodes if x != root):
+        ps.update_prefix(n, "0", PrefixEntry(
+            f"10.{i // 256}.{i % 256}.0/24", forwarding_type=SR_MPLS,
+            forwarding_algorithm=KSP2))
+    return ps
+
+
+def _record_backend(root):
+    from openr_tpu.config import ResilienceConfig
+
+    # no governor: a shadow swap would hide a wrong device build behind
+    # the oracle's routes
+    return TpuBackend(SpfSolver(root),
+                      resilience=ResilienceConfig(enabled=False))
+
+
+def _assert_exact(net, backend, ps):
+    """The memo the device path seeded equals a fresh LinkState's host
+    k-paths, and the routes equal a scalar build's."""
+    tpu = backend.build_route_db({"0": net.ls}, ps)
+    fresh = net.fresh()
+    reachable = fresh.get_spf_result(net.root)
+    for d in net.nodes:
+        if d == net.root:
+            continue
+        # every reachable destination came from the device path's seed
+        assert net.ls.has_kth_paths(net.root, d, 2) == (d in reachable), d
+        for k in (1, 2):
+            assert net.ls.get_kth_paths(net.root, d, k) == (
+                fresh.get_kth_paths(net.root, d, k)), (d, k)
+    scalar = ScalarBackend(SpfSolver(net.root)).build_route_db(
+        {"0": net.fresh()}, ps)
+    assert _db_view(tpu) == _db_view(scalar)
+
+
+@pytest.mark.parametrize("seed", [11, 12])
+@pytest.mark.parametrize("topology", sorted(TOPOLOGIES))
+def test_retained_ksp2_paths_match_fresh_kth_paths_across_link_events(
+    topology, seed
+):
+    import random
+
+    make_edges, root = TOPOLOGIES[topology]
+    net = _Network(make_edges(), root)
+    ps = _ksp2_prefixes(net.nodes, root)
+    backend = _record_backend(root)
+    _assert_exact(net, backend, ps)
+    rng = random.Random(seed)
+    links = sorted(net.base)
+    for _cycle in range(8):
+        gone, bent = rng.sample(links, 2)
+        for kind, link in (("down", gone), ("metric", bent),
+                           ("restore", bent), ("up", gone)):
+            net.apply(kind, link, rng.randint(2, 4))
+            _assert_exact(net, backend, ps)
+    counts = backend.ksp2_counts
+    assert backend.num_scalar_builds == 0
+    assert counts["resolve_rows"] == counts["seeded_dests"]
+    assert counts["reused_dests"] + counts["retraced_dests"] == (
+        counts["seeded_dests"])
+    assert counts["full_retrace"] == counts["full_retrace.cold"] == 1
+    assert counts["reused_dests"] > 0
+
+
+@pytest.mark.parametrize("topology", ["fabric", "f4"])
+def test_event_on_a_visited_node_retraces_that_destination(topology):
+    make_edges, root = TOPOLOGIES[topology]
+    net = _Network(make_edges(), root)
+    ps = _ksp2_prefixes(net.nodes, root)
+    backend = _record_backend(root)
+    _assert_exact(net, backend, ps)
+    rec = backend._ksp2_records["0"]
+    dest = max(d for d in rec.dests if d.startswith("rsw"))
+    fam1, fam2 = rec.dests[dest]
+    # a link incident to a visited node, on neither family's paths
+    on_paths = {k for fam in (fam1, fam2) for p in fam.paths for k in p}
+    link = next(
+        (a, b) for (a, b) in sorted(net.base)
+        if (a in fam2.visited or b in fam2.visited)
+        and not any({a, b} == {k[0], k[2]} for k in on_paths)
+    )
+    before = dict(rec.dests)
+    net.apply("metric", link, 3)
+    _assert_exact(net, backend, ps)
+    assert rec.dests[dest][1] is not fam2
+    # ... while the detector kept both families of some other destination
+    kept = [d for d, fams in rec.dests.items()
+            if all(a is b for a, b in zip(fams, before[d]))]
+    assert kept and dest not in kept
+    assert backend.ksp2_counts["reused_dests"] == len(kept)
+
+
+def _one_build_counts(backend, net, ps):
+    before = dict(backend.ksp2_counts)
+    _assert_exact(net, backend, ps)
+    return {k: v - before.get(k, 0) for k, v in backend.ksp2_counts.items()}
+
+
+def test_overload_flip_and_node_leaving_retrace_every_destination():
+    make_edges, root = TOPOLOGIES["fabric"]
+    net = _Network(make_edges(), root)
+    ps = _ksp2_prefixes(net.nodes, root)
+    backend = _record_backend(root)
+    _assert_exact(net, backend, ps)
+    rec = backend._ksp2_records["0"]
+    dests = len(rec.dests)
+
+    net.overloaded = ["fsw1_1"]
+    net.publish("fsw1_1")
+    got = _one_build_counts(backend, net, ps)
+    assert got["full_retrace.overload"] == 1
+    assert got["retraced_dests"] == dests and got.get("reused_dests", 0) == 0
+
+    net.overloaded = []
+    net.publish("fsw1_1")
+    _assert_exact(net, backend, ps)
+    gone = "rsw2_0"
+    net.ls.delete_adjacency_database(gone)
+    net.nodes.remove(gone)
+    net.base = {e: m for e, m in net.base.items() if gone not in e}
+    net.metric = {e: m for e, m in net.metric.items() if gone not in e}
+    net.publish("fsw2_0", "fsw2_1")
+    got = _one_build_counts(backend, net, ps)
+    assert got["full_retrace.node_set"] == 1
+    assert got.get("reused_dests", 0) == 0
+    assert got["retraced_dests"] == len(rec.dests) == dests - 1
+
+
+def test_suspect_purge_drops_the_record_and_the_next_build_retraces_all():
+    make_edges, root = TOPOLOGIES["fabric"]
+    net = _Network(make_edges(), root)
+    ps = _ksp2_prefixes(net.nodes, root)
+    backend = _record_backend(root)
+    _assert_exact(net, backend, ps)
+    rec = backend._ksp2_records["0"]
+    dests = len(rec.dests)
+    backend.inject_silent_corruption(True)
+    assert rec.dests == {} and rec.reason == "purge"
+    backend.inject_silent_corruption(False)
+    # no topology change: the purge alone re-traces every destination
+    got = _one_build_counts(backend, net, ps)
+    assert got["full_retrace.purge"] == 1
+    assert got["retraced_dests"] == dests and got.get("reused_dests", 0) == 0
+    # a size purge trusts the device: the record stays
+    backend._purge_warm("table_too_large", suspect=False)
+    assert len(rec.dests) == dests
+
+
+def test_device_built_masks_equal_link_failure_batch():
+    import jax.numpy as jnp
+    import numpy as np
+
+    from openr_tpu.ops.csr import encode_link_state, link_failure_batch
+    from openr_tpu.ops.spf import link_ignore_masks
+
+    ls = make_ls(_f4_edges())
+    topo = encode_link_state(ls)
+    L = len(topo.links)
+    rows = [[0, 3, L - 1], [], [5], list(range(0, L, 2))]
+    width = 16
+    assert max(map(len, rows)) <= width
+    # padding rows (all -1) ignore nothing, as link_failure_batch's []
+    rows += [[]] * 4
+    ids = np.full((len(rows), width), -1, np.int32)
+    for r, row in enumerate(rows):
+        ids[r, : len(row)] = row
+    got = np.asarray(link_ignore_masks(
+        jnp.asarray(topo.link_edge_pos), jnp.asarray(ids),
+        num_edges=topo.padded_edges))
+    want = link_failure_batch(topo, rows)
+    assert got.dtype == want.dtype and got.shape == want.shape
+    assert (got == want).all()
+    assert not got[0].all() and got[1].all() and got[-1].all()
