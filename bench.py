@@ -2878,8 +2878,7 @@ def rolling_sweep_world(
             node.counters.set("node.start_ms", start_ms)
         obs = net.nodes[observer]
         be = obs.decision.backend
-        sh0 = dict(be._warm_class_builds)
-        sf0 = dict(be._warm_class_fallbacks)
+        w0 = be.warm_status()["by_class"]
         slot0 = be.num_encode_slot_patches
         purge0 = be.num_warm_purges
         t_mark_ms = clock.now_ms()
@@ -2956,12 +2955,17 @@ def rolling_sweep_world(
         assert conv is not None and conv.count > 0
         pct = conv.percentiles()
 
-        s_hits = be._warm_class_builds["structural"] - sh0["structural"]
+        w1 = be.warm_status()
+        s_hits = (
+            w1["by_class"]["structural"]["hits"] - w0["structural"]["hits"]
+        )
         s_fb = (
-            be._warm_class_fallbacks["structural"] - sf0["structural"]
+            w1["by_class"]["structural"]["fallbacks"]
+            - w0["structural"]["fallbacks"]
         )
         p_hits = (
-            be._warm_class_builds["perturbation"] - sh0["perturbation"]
+            w1["by_class"]["perturbation"]["hits"]
+            - w0["perturbation"]["hits"]
         )
 
         health = obs.health
@@ -3001,7 +3005,7 @@ def rolling_sweep_world(
                 ),
                 "perturbation_hits": p_hits,
                 "slot_patches": be.num_encode_slot_patches - slot0,
-                "slot_declines": dict(be._slot_decline_reasons),
+                "slot_declines": w1["slot_declines"],
                 "purges": be.num_warm_purges - purge0,
             },
             "convergence": {

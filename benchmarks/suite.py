@@ -142,8 +142,8 @@ def bench_decision_initial(results: List[Dict], full: bool) -> None:
                 # cold = no memoized SPF and no cached topology encoding:
                 # that's what "initial update" measures in the reference
                 ls.clear_spf_memoization()
-                if hasattr(b, "_enc_cache"):
-                    b._enc_cache = {}
+                if hasattr(b, "gen"):
+                    b.gen.enc_cache = {}
                 b.build_route_db({"0": ls}, ps)
 
             timings[name] = _best_of(cold_build, repeats=repeats)

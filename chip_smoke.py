@@ -306,7 +306,7 @@ def phase_a(side: int = 64, ppn: int = 100, n_metric_changes: int = 3,
             wall = await settle(before + 1, f"metric change {k}")
             fib, rep, cmp = compare_fib(fib, rep, f"metric change {k}")
             warm.append({"link": e[:2], "metric": e[2], "wall_s": wall,
-                         "encode": backend._last_encode_kind, **cmp})
+                         "encode": backend.gen.last_encode_kind, **cmp})
         record["warm"] = warm
 
         # -- structural path: one node leaves ------------------------------
@@ -321,7 +321,7 @@ def phase_a(side: int = 64, ppn: int = 100, n_metric_changes: int = 3,
         fib, rep, cmp = compare_fib(fib, rep, "node leave")
         record["structural"] = {"node": gone, "neighbors": len(nbrs),
                                 "wall_s": wall,
-                                "encode": backend._last_encode_kind, **cmp}
+                                "encode": backend.gen.last_encode_kind, **cmp}
 
         # -- ctrl handler: get_route_db ------------------------------------
         t0 = time.perf_counter()

@@ -31,6 +31,7 @@ from typing import Dict, List, Optional, Set, Tuple
 import numpy as np
 
 from openr_tpu.decision.link_state import INF, LinkState
+from openr_tpu.decision.generation import DeviceGeneration
 from openr_tpu.decision.prefix_state import PrefixState
 from openr_tpu.decision.rib import DecisionRouteDb, RibUnicastEntry
 from openr_tpu.decision.spf_solver import (
@@ -117,6 +118,22 @@ def _patch_route_db(
         else:
             db.unicast_routes[prefix] = entry
     return db
+
+
+def _decode_inputs_changed(old_enc, enc) -> np.ndarray:
+    """[A, V] nodes whose decoded routes can move while their selection
+    outputs stay byte-identical: overload or soft-drain state moved
+    (decode wraps the winning entry via LinkState drain lookups), or
+    slot-membership churn renamed the slot (a replacement node with the
+    same links keeps dist and lanes, but decode embeds its name and
+    link objects)."""
+    changed = (old_enc.overloaded != enc.overloaded) | (
+        old_enc.soft != enc.soft
+    )
+    for ai, t in enumerate(enc.topos):
+        if t.slot_changed is not None:
+            changed[ai] |= t.slot_changed
+    return changed
 
 
 class DecisionBackend:
@@ -256,15 +273,8 @@ class TpuBackend(DecisionBackend):
         parallel=None,
         probe=None,
         warm_rebuild: bool = True,
-        plan_cache_entries: int = 0,
     ) -> None:
         self.solver = solver  # scalar fallback + MPLS/static
-        if plan_cache_entries:
-            # bound the content-hash RepairPlan memo (ops.repair) the
-            # what-if/sweep planners ride; 0 keeps the library default
-            from openr_tpu.ops.repair import set_plan_cache_cap
-
-            set_plan_cache_cap(plan_cache_entries)
         # AOT-equivalence with the reference's compiled binary: persist
         # XLA executables so only the FIRST boot on a machine pays kernel
         # compilation
@@ -332,21 +342,8 @@ class TpuBackend(DecisionBackend):
                 else disabled_probe()
             )
         self.probe = probe
-        #: per-device replicas of the device-resident SPF tables, keyed
-        #: by device index and invalidated by table identity
-        self._spf_replicas: dict = {}
-        #: pool health generation the replica cache was built under —
-        #: a quarantine/restore re-packs shard ownership, and replicas
-        #: pinned to unhealthy chips are dropped at the next dispatch
-        self._replica_health_seq = -1
-        #: attribution of the LAST device build's freshly-computed rows:
-        #: either a contiguous shard plan [(device, row_lo, row_hi)]
-        #: (full builds) or an explicit row->device map (incremental
-        #: gathers); the governor reads it to pin a shadow-verification
-        #: mismatch on the one chip that produced the wrong rows
-        self._attr_plan = None
-        self._attr_rows = None
-        self._attr_table = None
+        #: everything cached about the live device generation
+        self.gen = DeviceGeneration()
         #: health authority (openr_tpu/resilience/governor.py): shadow
         #: verification + circuit breaker + probed recovery.  `resilience`
         #: is a config.ResilienceConfig (None = defaults; enabled=False
@@ -375,45 +372,18 @@ class TpuBackend(DecisionBackend):
                 tracer=tracer,
                 **gov_kwargs,
             )
-        #: EncodedMultiArea cache keyed by ((area, topology_seq), ...):
-        #: most rebuilds are prefix churn on an unchanged graph, and
-        #: re-encoding a 4096-node LSDB costs tens of ms of the debounce
-        #: budget (SURVEY §7 hard-part 4)
-        self._enc_cache: dict = {}
-        #: Ksp2DeviceEngine per (area, topology_seq) — the traced-path memo
-        #: itself lives in the LinkState; this only avoids rebuilding the
-        #: link-id table every rebuild
-        self._ksp2_engines: dict = {}
-        #: Ksp2Record per area: the traced k-paths kept across topology
-        #: generations (decision/ksp2.py), dropped on device suspicion
-        self._ksp2_records: dict = {}
         #: KSP2 seeding work across every engine this backend made
         #: (``decision.backend.ksp2.*``, see Ksp2DeviceEngine.counts)
         self.ksp2_counts: Counter = Counter()
         self.num_encode_hits = 0
         self.num_encodes = 0
-        #: device-resident per-area SPF tables, valid while (_spf_enc is
-        #: the live encoding object, _spf_degree == D) — identity is held
-        #: by reference, never by id(), to survive GC id reuse
-        self._spf_tables = None
-        self._spf_enc = None
-        self._spf_degree = None
-        #: warm-start generation-delta rebuild (the ISSUE-9 tentpole):
-        #: the previous generation's SPF tables stay device-resident
-        #: (plus small host mirrors for delta planning), and a
-        #: warm-eligible topology tick re-relaxes only the perturbed
-        #: frontier instead of re-running the cold hop-diameter solve.
-        #: The context is PURGED — and the next device build forced
-        #: through shadow verification — on anything that makes it
-        #: suspect: corruption injection, a quarantine re-pack, the
-        #: full-replace swap, or a structural/shape change.
+        #: warm-start generation-delta rebuild: a warm-eligible topology
+        #: tick re-relaxes only the perturbed frontier from the previous
+        #: generation's resident tables.  The context is PURGED, and the
+        #: next device build forced through shadow verification, on
+        #: anything that makes it suspect: corruption injection, a
+        #: quarantine re-pack, the full-replace swap.
         self._warm_enabled = bool(warm_rebuild)
-        self._warm_ctx = None  # dict(enc, dist, nh, degree, tables)
-        self._warm_changed_nodes = None  # [A, V] bool vs previous gen
-        self._warm_base_enc = None  # ctx enc the last warm solve diffed
-        self._warm_solved = False  # this build's tables came in warm
-        self._warm_rounds = None  # (rounds_d, rounds_l) device scalars
-        self._last_changed_prefixes: Optional[Set[str]] = None
         self.num_warm_builds = 0
         self.num_warm_subgraph_builds = 0
         self.num_warm_selective_builds = 0
@@ -445,53 +415,22 @@ class TpuBackend(DecisionBackend):
         #: applied in place vs declined-to-cold (with the reason)
         self.num_encode_slot_patches = 0
         self._slot_decline_reasons: Dict[str, int] = {}
-        #: encode kind of the live encoding ("cold"/"patch"/"slot")
-        self._last_encode_kind = "cold"
-        #: KSP2 prefixes seen by the most recent decodes: their routes
-        #: depend on the WHOLE topology (k-shortest re-solves), so the
-        #: warm-selective patch path declines while any are present
-        self._ksp2_present = False
         if self.governor is not None:
             # any quarantine transition (whole-backend or per-chip)
             # re-packs shard ownership and makes device residency
-            # suspect — purge the warm context so the next generation
-            # rebuilds cold and scalar-verified
+            # suspect: purge so the next generation rebuilds cold and
+            # scalar-verified
             self.governor.add_quarantine_listener(
-                lambda info: self._purge_warm(
-                    f"quarantine:{info.get('reason', '')}"
+                lambda info: self._count_purge(
+                    f"quarantine:{info.get('reason', '')}", self.gen.purge()
                 )
             )
         #: incremental candidate table (persistent across rebuilds);
-        #: _table_synced guards against missed deltas when a build falls
-        #: back to the scalar path (the table skips that tick's churn)
+        #: gen.table_synced guards against missed deltas when a build
+        #: falls back to the scalar path (the table skips that churn)
         from openr_tpu.decision.cand_table import CandidateTable
 
         self._cand_table = CandidateTable(cand_buckets=self.cand_buckets)
-        self._table_synced = False
-        #: previous device-built RouteDb + the enc it was built against
-        self._last_db: Optional[DecisionRouteDb] = None
-        self._last_enc = None
-        #: one-shot: set when a quarantine swap makes the whole previous
-        #: RouteDb suspect (see DecisionBackend.take_full_replace)
-        self._full_replace = False
-        #: on-device generation-delta context for COLD/full rebuilds
-        #: (the warm-start take_last_changed_prefixes pattern extended
-        #: to the full-build path): the previous full build's selection
-        #: outputs stay device-resident per shard, the next full build
-        #: runs the fused select+diff kernel, and only changed rows
-        #: cross the host boundary.  Purged with the warm context on any
-        #: suspicion event, and dropped whenever a build is not a
-        #: full-table build (incremental/warm-selective patches make the
-        #: resident outputs stale for their rows).
-        self._prev_sel = None
-        #: probe chip of the most recent full-dispatch plan (a failed
-        #: probe shard must not mid-stream re-pack — the whole build
-        #: falls back so the governor scores the probe)
-        self._plan_probe = None
-        #: per-shard device outputs of the stream in progress (set by
-        #: `_stream_row_shards` on clean completion, consumed by
-        #: `_retain_prev_sel`)
-        self._stream_outs = None
         #: test seams for the streamed dispatcher: `_stream_pick`
         #: overrides completion-order selection (fn(pending) -> index)
         #: so reassembly is provably order-independent;  `_stream_fault`
@@ -517,7 +456,7 @@ class TpuBackend(DecisionBackend):
     ):
         gov = self.governor
         probe = False
-        self._last_changed_prefixes = None
+        self.gen.last_changed_prefixes = None
         if gov is not None:
             from openr_tpu.resilience.governor import (
                 ADMIT_PROBE,
@@ -614,67 +553,36 @@ class TpuBackend(DecisionBackend):
                 # caller must diff this build against its WHOLE previous
                 # RouteDb (corrupt entries from unsampled builds since
                 # the last verified one must be purged, not just the
-                # changed prefixes)
-                self._last_db = None
-                self._table_synced = False
-                self._full_replace = True
-                # the swap proves the device (or a chip) lied: nothing
-                # device-resident is trustworthy as a warm base, and the
-                # patched-changed-set guarantee no longer holds either
-                self._last_changed_prefixes = None
-                self._purge_warm("full_replace")
+                # changed prefixes), and nothing device-resident is
+                # trustworthy as a warm base
+                self._count_purge("full_replace", self.gen.swap_replaced())
                 return db
-        if cache_result:
-            self._last_db = db
-        else:
-            self._last_db = None
+        self.gen.last_db = db if cache_result else None
         return db
 
     def take_full_replace(self) -> bool:
-        fr, self._full_replace = self._full_replace, False
+        fr, self.gen.full_replace = self.gen.full_replace, False
         return fr
 
     def take_last_changed_prefixes(self) -> Optional[Set[str]]:
-        out, self._last_changed_prefixes = self._last_changed_prefixes, None
+        out = self.gen.last_changed_prefixes
+        self.gen.last_changed_prefixes = None
         return out
 
     # -- warm-start generation-delta context -------------------------------
 
-    def _purge_warm(self, reason: str, suspect: bool = True) -> None:
-        """Drop the warm-rebuild context (previous generation's tables +
-        host mirrors) and force the next device build through shadow
-        verification.  Triggers: corruption injection (``tpu_corrupt``,
-        whole-backend or chip-scoped), any quarantine re-pack, the
-        full-replace swap, and structural/shape deltas.  ``suspect``
-        (the default) additionally drops the device-resident SPF table
-        cache and its per-chip replicas, so the next device build truly
-        solves COLD — we never reuse device state a corruption event
-        may have touched.  Size/housekeeping purges pass suspect=False
-        and keep the (trusted) tables.  Idempotent — only an actual
-        drop counts as a purge."""
+    def _count_purge(self, reason: str, dropped: bool) -> None:
+        """Telemetry of one ``gen.purge``: the reason is always counted;
+        a purge that ``dropped`` a warm context counts as a purge and
+        forces the next device build through shadow verification."""
         key = reason.split(":", 1)[0]
         self._warm_purge_reasons[key] = (
             self._warm_purge_reasons.get(key, 0) + 1
         )
-        if suspect:
-            self._spf_tables = None
-            self._spf_enc = None
-            self._spf_degree = None
-            self._spf_replicas = {}
-            # the full-build delta context is device residency too: a
-            # suspect device must not vouch for "row unchanged"
-            self._prev_sel = None
-            # nor for "KSP2 distance row unchanged"
-            for rec in self._ksp2_records.values():
-                rec.drop("purge")
-        if self._warm_ctx is None and self._warm_changed_nodes is None:
-            return
-        self._warm_ctx = None
-        self._warm_changed_nodes = None
-        self._warm_base_enc = None
-        self.num_warm_purges += 1
-        if self.governor is not None:
-            self.governor.request_shadow_check(reason)
+        if dropped:
+            self.num_warm_purges += 1
+            if self.governor is not None:
+                self.governor.request_shadow_check(reason)
 
     def _warm_fallback(
         self, reason: str, delta_class: Optional[str] = None
@@ -731,11 +639,11 @@ class TpuBackend(DecisionBackend):
         (static overlay, stale incremental bases) — the governor treats
         those as unattributable and falls back to the whole-backend
         quarantine."""
-        table = self._attr_table
+        table = self.gen.attr_table
         if table is None:
             return None
-        if self._attr_rows is not None:
-            rows = self._attr_rows
+        if self.gen.attr_rows is not None:
+            rows = self.gen.attr_rows
             devs = sorted(set(rows.values()))
 
             def dev_of(prefix, _rows=rows, _table=table):
@@ -743,7 +651,7 @@ class TpuBackend(DecisionBackend):
                 return None if r is None else _rows.get(r)
 
             return devs, dev_of
-        plan = self._attr_plan
+        plan = self.gen.attr_plan
         devs = [
             d
             for d, lo, hi in plan
@@ -795,10 +703,11 @@ class TpuBackend(DecisionBackend):
             # a lying accelerator means nothing device-resident can seed
             # a warm rebuild: the next generation solves cold, and the
             # governor is asked to shadow-verify it
-            self._purge_warm(
+            self._count_purge(
                 "tpu_corrupt"
                 if device_index is None
-                else f"tpu_corrupt:dev{int(device_index)}"
+                else f"tpu_corrupt:dev{int(device_index)}",
+                self.gen.purge(),
             )
 
     def _sdc_active_for(self, device_index: int) -> bool:
@@ -834,7 +743,7 @@ class TpuBackend(DecisionBackend):
             # fleet's churn profile is actually warm-eligible
             "decision.backend.warm_enabled": 1.0 if self._warm_enabled else 0.0,
             "decision.backend.warm_context_ready": (
-                1.0 if self._warm_ctx is not None else 0.0
+                1.0 if self.gen.warm_ctx is not None else 0.0
             ),
             "decision.backend.warm_builds": float(self.num_warm_builds),
             "decision.backend.warm_subgraph_builds": float(
@@ -860,37 +769,6 @@ class TpuBackend(DecisionBackend):
             "decision.backend.warm_last_reset_nodes": float(
                 self.warm_last_reset_nodes
             ),
-            # ISSUE-12 split: the structural (membership-churn) ratio is
-            # what a rolling fleet upgrade lives on; perturbation ticks
-            # must not be allowed to mask a structural cold wall
-            "decision.backend.warm_builds.perturbation": float(
-                self._warm_class_builds["perturbation"]
-            ),
-            "decision.backend.warm_builds.structural": float(
-                self._warm_class_builds["structural"]
-            ),
-            "decision.backend.warm_cold_fallbacks.perturbation": float(
-                self._warm_class_fallbacks["perturbation"]
-            ),
-            "decision.backend.warm_cold_fallbacks.structural": float(
-                self._warm_class_fallbacks["structural"]
-            ),
-            "decision.backend.warm_hit_ratio.perturbation": (
-                self._warm_class_builds["perturbation"]
-                / max(
-                    1,
-                    self._warm_class_builds["perturbation"]
-                    + self._warm_class_fallbacks["perturbation"],
-                )
-            ),
-            "decision.backend.warm_hit_ratio.structural": (
-                self._warm_class_builds["structural"]
-                / max(
-                    1,
-                    self._warm_class_builds["structural"]
-                    + self._warm_class_fallbacks["structural"],
-                )
-            ),
             "decision.backend.warm_encode_slot_patches": float(
                 self.num_encode_slot_patches
             ),
@@ -910,6 +788,16 @@ class TpuBackend(DecisionBackend):
                 self.num_delta_rows_skipped
             ),
         }
+        # split by delta class: the structural (membership-churn) ratio
+        # is what a rolling fleet upgrade lives on; perturbation ticks
+        # must not be allowed to mask a structural cold wall
+        for cls, hits in self._warm_class_builds.items():
+            falls = self._warm_class_fallbacks[cls]
+            out[f"decision.backend.warm_builds.{cls}"] = float(hits)
+            out[f"decision.backend.warm_cold_fallbacks.{cls}"] = float(falls)
+            out[f"decision.backend.warm_hit_ratio.{cls}"] = hits / max(
+                1, hits + falls
+            )
         from openr_tpu.decision.ksp2 import FULL_RETRACE_REASONS
 
         for k in (
@@ -948,6 +836,35 @@ class TpuBackend(DecisionBackend):
             out.update(self._pool.counter_snapshot("decision.backend.pool"))
         return out
 
+    def warm_status(self) -> Dict[str, object]:
+        """Warm-rebuild health for ``get_resilience_status``, by delta
+        class: in a rolling upgrade a collapse of the STRUCTURAL ratio
+        means publication→FIB is back on the cold wall."""
+        builds = self._warm_class_builds
+        fallbacks = self._warm_class_fallbacks
+        return {
+            "enabled": self._warm_enabled,
+            "context_ready": self.gen.warm_ctx is not None,
+            "by_class": {
+                cls: {
+                    "hits": builds[cls],
+                    "fallbacks": fallbacks[cls],
+                    "hit_ratio": round(
+                        builds[cls] / max(1, builds[cls] + fallbacks[cls]), 3
+                    ),
+                    "fallback_reasons": dict(
+                        sorted(self._warm_class_fallback_reasons[cls].items())
+                    ),
+                }
+                for cls in sorted(builds)
+            },
+            "encode_patches": self.num_encode_patches,
+            "encode_slot_patches": self.num_encode_slot_patches,
+            "slot_declines": dict(sorted(self._slot_decline_reasons.items())),
+            "purges": self.num_warm_purges,
+            "purge_reasons": dict(sorted(self._warm_purge_reasons.items())),
+        }
+
     def _device_worth_it(self, area_link_states, prefix_state) -> bool:
         """Auto cutover: device iff the estimated scalar build cost
         exceeds the measured device dispatch overhead.  Work items =
@@ -973,10 +890,7 @@ class TpuBackend(DecisionBackend):
             self.num_small_scalar_builds += 1
         else:
             self.num_scalar_builds += 1
-        self._last_db = None
-        self._table_synced = False
-        self._attr_table = None  # nothing device-computed to attribute
-        self._prev_sel = None  # resident outputs no longer match _last_db
+        self.gen.invalidate_base()
         return self.solver.build_route_db(area_link_states, prefix_state)
 
     # -- encoding (cached across prefix-churn rebuilds) --------------------
@@ -988,7 +902,7 @@ class TpuBackend(DecisionBackend):
             (a, area_link_states[a].topology_seq)
             for a in sorted(area_link_states)
         )
-        cached = self._enc_cache.get(cache_key)
+        cached = self.gen.enc_cache.get(cache_key)
         # pin the LinkState objects themselves: identity must be compared
         # via held references (a bare id() could be reused by a
         # replacement object after GC and serve stale arrays)
@@ -999,8 +913,8 @@ class TpuBackend(DecisionBackend):
             self.num_encode_hits += 1
             return cached[1]
         enc = None
-        self._last_encode_kind = "cold"
-        if self._warm_enabled and self._enc_cache:
+        self.gen.last_encode_kind = "cold"
+        if self._warm_enabled and self.gen.enc_cache:
             # perturbation ticks (the overwhelming topology-churn class)
             # refresh only the weight/validity/drain columns; membership
             # churn (node join/leave, link add/remove — a rolling
@@ -1010,12 +924,12 @@ class TpuBackend(DecisionBackend):
             # most of the warm rebuild's host budget at 4096 nodes.
             from openr_tpu.ops.csr import patch_encoded_multi_area_slots
 
-            (prev_ls, prev_enc) = next(iter(self._enc_cache.values()))
+            (prev_ls, prev_enc) = next(iter(self.gen.enc_cache.values()))
             enc, kind, reason = patch_encoded_multi_area_slots(
                 prev_enc, area_link_states, me
             )
             if enc is not None:
-                self._last_encode_kind = kind
+                self.gen.last_encode_kind = kind
                 if kind == "slot":
                     self.num_encode_slot_patches += 1
                 else:
@@ -1028,13 +942,13 @@ class TpuBackend(DecisionBackend):
             enc = encode_multi_area(
                 area_link_states, me, node_buckets=self.node_buckets
             )
-        self._enc_cache = {
+        self.gen.enc_cache = {
             cache_key: (
                 [area_link_states[a] for a in sorted(area_link_states)],
                 enc,
             )
         }
-        self._ksp2_engines = {}
+        self.gen.ksp2_engines = {}
         self.num_encodes += 1
         return enc
 
@@ -1042,13 +956,13 @@ class TpuBackend(DecisionBackend):
         from openr_tpu.decision.ksp2 import Ksp2DeviceEngine, Ksp2Record
 
         root = self.solver.my_node_name
-        rec = self._ksp2_records.get(area)
+        rec = self.gen.ksp2_records.get(area)
         if rec is None or rec.link_state is not link_state or rec.root != root:
-            rec = self._ksp2_records[area] = Ksp2Record(
+            rec = self.gen.ksp2_records[area] = Ksp2Record(
                 link_state, root, "cold" if rec is None else "link_state"
             )
         key = (area, link_state.topology_seq)
-        eng = self._ksp2_engines.get(key)
+        eng = self.gen.ksp2_engines.get(key)
         if (
             eng is None
             or eng.link_state is not link_state
@@ -1063,7 +977,7 @@ class TpuBackend(DecisionBackend):
                 counts=self.ksp2_counts,
                 record=rec,
             )
-            self._ksp2_engines[key] = eng
+            self.gen.ksp2_engines[key] = eng
         return eng
 
     def _ksp2_routes(
@@ -1079,7 +993,7 @@ class TpuBackend(DecisionBackend):
         prefixes, dests = ksp2
         if not prefixes:
             return {}
-        self._ksp2_present = True
+        self.gen.ksp2_present = True
         for a, nodes in sorted(dests.items()):
             ai = enc.area_index(a)
             self._ksp2_engine(a, area_link_states[a], enc.topos[ai]).seed(
@@ -1129,21 +1043,21 @@ class TpuBackend(DecisionBackend):
         from openr_tpu.ops.route_select import multi_area_spf_tables
 
         if (
-            self._spf_tables is not None
-            and self._spf_enc is enc
-            and self._spf_degree == max_degree
+            self.gen.spf_tables is not None
+            and self.gen.spf_enc is enc
+            and self.gen.spf_degree == max_degree
         ):
-            return self._spf_tables
+            return self.gen.spf_tables
         from openr_tpu.tracing import pipeline
 
-        self._warm_solved = False
-        self._warm_changed_nodes = None
-        self._warm_rounds = None
+        self.gen.warm_solved = False
+        self.gen.warm_changed_nodes = None
+        self.gen.warm_rounds = None
         dist = nh = None
         if (
             self._warm_enabled
             and delta_class is not None
-            and self._warm_ctx is not None
+            and self.gen.warm_ctx is not None
         ):
             dist, nh = self._warm_spf(enc, max_degree, delta_class)
         elif self._warm_enabled and delta_class is not None:
@@ -1151,7 +1065,7 @@ class TpuBackend(DecisionBackend):
             # quarantine re-pack, full replace): this build solves cold
             # and re-establishes the context
             self._warm_fallback("no_context", delta_class)
-        elif self._warm_enabled and self._warm_ctx is not None:
+        elif self._warm_enabled and self.gen.warm_ctx is not None:
             # a topology tick the hint classified cold (static/policy
             # coincidence, first build): count it so the warm-hit ratio
             # reflects reality
@@ -1200,12 +1114,12 @@ class TpuBackend(DecisionBackend):
         with self.probe.phase(pipeline.TRANSFER):
             soft = jnp.asarray(enc.soft)
             ovl = jnp.asarray(enc.overloaded)
-        self._spf_tables = (dist, nh, ovl, soft)
-        self._spf_enc = enc
-        self._spf_degree = max_degree
+        self.gen.spf_tables = (dist, nh, ovl, soft)
+        self.gen.spf_enc = enc
+        self.gen.spf_degree = max_degree
         if self._warm_enabled:
             self._refresh_warm_ctx(enc, max_degree)
-        return self._spf_tables
+        return self.gen.spf_tables
 
     #: warm-context host mirrors beyond this size are not worth the
     #: per-generation fetch (the warm win targets the debounce budget)
@@ -1222,7 +1136,7 @@ class TpuBackend(DecisionBackend):
         from openr_tpu.ops.route_select import warm_multi_area_spf_tables
         from openr_tpu.tracing import pipeline
 
-        ctx = self._warm_ctx
+        ctx = self.gen.warm_ctx
         with self.probe.phase(pipeline.WARM_PLAN):
             if ctx["degree"] != max_degree:
                 self._warm_fallback("degree_bucket", delta_class)
@@ -1328,9 +1242,9 @@ class TpuBackend(DecisionBackend):
                 dist, nh, rounds_d, rounds_l = call_jit_guarded(
                     warm_multi_area_spf_tables, *args, max_degree=max_degree
                 )
-        self._warm_solved = True
-        self._warm_base_enc = old_enc
-        self._warm_rounds = (rounds_d, rounds_l)
+        self.gen.warm_solved = True
+        self.gen.warm_base_enc = old_enc
+        self.gen.warm_rounds = (rounds_d, rounds_l)
         self._warm_hit(delta_class)
         return dist, nh
 
@@ -1390,18 +1304,20 @@ class TpuBackend(DecisionBackend):
 
         from openr_tpu.tracing import pipeline
 
-        dist_d, nh_d = self._spf_tables[0], self._spf_tables[1]
+        dist_d, nh_d = self.gen.spf_tables[0], self.gen.spf_tables[1]
         table_bytes = int(
             np.prod(dist_d.shape) * 4 + np.prod(nh_d.shape)
         )
         if table_bytes > self.WARM_MAX_TABLE_BYTES:
             # housekeeping, not suspicion: the tables stay trusted and
             # cached; only warm seeding is declined at this size
-            self._purge_warm("table_too_large", suspect=False)
+            self._count_purge(
+                "table_too_large", self.gen.purge(suspect=False)
+            )
             return
         dist_h = nh_h = None
-        prev = self._warm_ctx
-        if self._warm_solved:
+        prev = self.gen.warm_ctx
+        if self.gen.warm_solved:
             with self.probe.phase(pipeline.WARM_PLAN):
                 dist_h, nh_h = jax.device_get((dist_d, nh_d))
                 dist_h = np.asarray(dist_h)
@@ -1414,29 +1330,21 @@ class TpuBackend(DecisionBackend):
                 ):
                     # per-node change mask vs the previous generation —
                     # selection outputs can only move for prefixes whose
-                    # candidate rows read a changed (dist, lane, drain)
-                    # cell
+                    # candidate rows read a changed (dist, lane, drain,
+                    # name) cell
                     changed = (prev["dist"] != dist_h) | (
                         prev["nh"] != nh_h
                     ).any(axis=2)
-                    changed |= prev["enc"].overloaded != enc.overloaded
-                    changed |= prev["enc"].soft != enc.soft
-                    # slot-membership churn: a renamed slot can keep
-                    # identical dist/lanes (replacement node, same
-                    # links) yet its NAME — which decode embeds in
-                    # routes — changed; force its rows to re-select
-                    for ai, t in enumerate(enc.topos):
-                        if t.slot_changed is not None:
-                            changed[ai] |= t.slot_changed
-                    self._warm_changed_nodes = changed
-                if self._warm_rounds is not None:
-                    rd, rl = jax.device_get(self._warm_rounds)
+                    changed |= _decode_inputs_changed(prev["enc"], enc)
+                    self.gen.warm_changed_nodes = changed
+                if self.gen.warm_rounds is not None:
+                    rd, rl = jax.device_get(self.gen.warm_rounds)
                     self.warm_last_rounds = (
                         int(np.max(rd)),
                         int(np.max(rl)),
                     )
-                    self._warm_rounds = None
-        self._warm_ctx = {
+                    self.gen.warm_rounds = None
+        self.gen.warm_ctx = {
             "enc": enc,
             "dist": dist_h,
             "nh": nh_h,
@@ -1469,7 +1377,7 @@ class TpuBackend(DecisionBackend):
         keeps a shard (the probe must actually exercise the chip).
         Single-chip pools plan ONE shard on the lead chip, so every
         full build flows through the same streamed dispatcher."""
-        self._plan_probe = None
+        self.gen.plan_probe = None
         if not self._use_pool():
             lead = self.pool.lead_index()
             return [(lead if lead is not None else 0, 0, n_rows)]
@@ -1489,7 +1397,7 @@ class TpuBackend(DecisionBackend):
         plan[-1] = (dev, lo, n_rows)
         if self.governor is not None:
             self.governor.confirm_plan([d for d, _lo, _hi in plan])
-        self._plan_probe = probe
+        self.gen.plan_probe = probe
         return plan
 
     def _replicated_tables(self, dev_index: int, tables: tuple) -> tuple:
@@ -1502,14 +1410,14 @@ class TpuBackend(DecisionBackend):
         import jax
 
         pool = self.pool
-        if self._replica_health_seq != pool.health_seq:
-            self._spf_replicas = {
+        if self.gen.replica_health_seq != pool.health_seq:
+            self.gen.spf_replicas = {
                 k: v
-                for k, v in self._spf_replicas.items()
+                for k, v in self.gen.spf_replicas.items()
                 if pool.is_healthy(k)
             }
-            self._replica_health_seq = pool.health_seq
-        cached = self._spf_replicas.get(dev_index)
+            self.gen.replica_health_seq = pool.health_seq
+        cached = self.gen.spf_replicas.get(dev_index)
         if cached is not None and cached[0] is tables:
             return cached[1]
         from openr_tpu.tracing import pipeline
@@ -1517,7 +1425,7 @@ class TpuBackend(DecisionBackend):
         dev = self.pool.device(dev_index)
         with self.probe.phase(pipeline.TRANSFER, device=dev_index):
             rep = tuple(jax.device_put(t, dev) for t in tables)
-        self._spf_replicas[dev_index] = (tables, rep)
+        self.gen.spf_replicas[dev_index] = (tables, rep)
         return rep
 
     def _stream_row_shards(self, dv, tables, per_area, plan, delta_ctx):
@@ -1682,7 +1590,7 @@ class TpuBackend(DecisionBackend):
                 if (
                     not allow_repack
                     or gov is None
-                    or dev_index == self._plan_probe
+                    or dev_index == self.gen.plan_probe
                 ):
                     raise
                 gov.record_stream_failure(dev_index, e)
@@ -1806,16 +1714,15 @@ class TpuBackend(DecisionBackend):
                 # quarantine already purged residency as suspect)
                 clean_outs[key] = outs
             yield drained
-        if self.num_stream_repacks == repacks_before:
-            self._stream_outs = clean_outs
-        else:
-            self._stream_outs = None
+        clean = self.num_stream_repacks == repacks_before
+        self.gen.stream_outs = clean_outs if clean else None
 
     # -- device build ------------------------------------------------------
 
     def _select_rows_gathered(
         self,
         rows,
+        deleted,
         tables,
         dv,
         per_area,
@@ -1827,9 +1734,13 @@ class TpuBackend(DecisionBackend):
         """Gather the given candidate-table rows into a padded [K, C]
         batch, run the selection kernel as ONE committed dispatch (the
         pool's lead healthy chip, or the armed probe chip), decode, and
-        return ``(results, inc_dev)``.  Shared by the prefix-churn
+        return ``(results, attr)``: ``results`` also withdraws the
+        ``deleted`` prefixes, and ``attr`` maps each row to the chip that
+        computed it (None off the pool).  Shared by the prefix-churn
         incremental path and the warm-selective generation-delta path —
-        both re-select only the rows that can have moved."""
+        both re-select only the rows that can have moved.  With no rows
+        nothing is dispatched, so no probe is armed that a build would
+        never exercise."""
         import jax
         import jax.numpy as jnp
 
@@ -1839,6 +1750,11 @@ class TpuBackend(DecisionBackend):
         from openr_tpu.ops.route_select import multi_area_select_from_tables
         from openr_tpu.tracing import pipeline
 
+        results: Dict[str, Optional[RibUnicastEntry]] = {
+            p: None for p in deleted
+        }
+        if not rows:
+            return results, None
         dist, nh, ovl, soft = tables
         inc_dev = None
         # selective gathers ride ONE chip: the pool's lead healthy
@@ -1904,23 +1820,26 @@ class TpuBackend(DecisionBackend):
             shortest = self._corrupt_metrics(shortest)
         ksp2 = ([], {})
         with self.probe.phase(pipeline.DECODE):
-            results = self._decode_rows(
-                [(i, table.row_prefix[r]) for i, r in enumerate(rows)],
-                use,
-                shortest,
-                lanes,
-                valid,
-                dv,
-                np.asarray(ridx),
-                enc,
-                area_link_states,
-                prefix_state,
-                ksp2,
+            results.update(
+                self._decode_rows(
+                    [(i, table.row_prefix[r]) for i, r in enumerate(rows)],
+                    use,
+                    shortest,
+                    lanes,
+                    valid,
+                    dv,
+                    np.asarray(ridx),
+                    enc,
+                    area_link_states,
+                    prefix_state,
+                    ksp2,
+                )
             )
         results.update(
             self._ksp2_routes(ksp2, enc, area_link_states, prefix_state)
         )
-        return results, inc_dev
+        attr = None if inc_dev is None else {int(r): inc_dev for r in rows}
+        return results, attr
 
     def _delta_ctx_for(
         self, plan, D: int, enc, dv, changed_prefixes, exact_churn: bool
@@ -1938,13 +1857,13 @@ class TpuBackend(DecisionBackend):
         (their routes read the WHOLE topology) and no MPLS label pass.
         Probe builds decline: a probing chip must be exercised and
         attributable end to end, not vouch for 'unchanged'."""
-        prev = self._prev_sel
+        prev = self.gen.prev_sel
         if (
             prev is None
-            or self._last_db is None
+            or self.gen.last_db is None
             or not exact_churn
-            or self._plan_probe is not None
-            or self._ksp2_present
+            or self.gen.plan_probe is not None
+            or self.gen.ksp2_present
             or self.solver.enable_node_segment_label
         ):
             return None
@@ -1963,22 +1882,10 @@ class TpuBackend(DecisionBackend):
             snap.get(k) is not v for k, v in statics.items()
         ):
             return None
-        # drain-state deltas: decode wraps the winning entry via
-        # LinkState drain lookups, so rows touching a node whose
-        # overload/soft-drain state moved must re-decode even when
-        # their selection outputs are identical (the kernel folds this
-        # mask into its changed-row computation)
-        node_changed = (prev_enc.overloaded != enc.overloaded) | (
-            prev_enc.soft != enc.soft
-        )
-        # slot-membership churn since the delta base: renamed slots can
-        # keep byte-identical selection outputs while their decoded
-        # route contents (names, link objects) moved — their rows must
-        # re-decode (tombstone/revive flips change dist and are caught
-        # by the kernel's output diff regardless)
-        for ai, t in enumerate(enc.topos):
-            if t.slot_changed is not None:
-                node_changed[ai] |= t.slot_changed
+        # rows touching these nodes re-decode even when their selection
+        # outputs are identical (the kernel folds this mask into its
+        # changed-row computation)
+        node_changed = _decode_inputs_changed(prev_enc, enc)
         force = None
         if changed_prefixes:
             rows = self._cand_table.rows_for(changed_prefixes)
@@ -1995,12 +1902,12 @@ class TpuBackend(DecisionBackend):
         next full build's delta base.  Returns True when the stream was
         clean (no mid-stream re-pack) — also the caller's signal that
         the shard plan attribution is trustworthy."""
-        outs = self._stream_outs
-        self._stream_outs = None
+        outs = self.gen.stream_outs
+        self.gen.stream_outs = None
         if outs is None or len(outs) != len(plan):
-            self._prev_sel = None
+            self.gen.prev_sel = None
             return False
-        self._prev_sel = {
+        self.gen.prev_sel = {
             "plan": tuple(plan),
             "degree": D,
             "shape": dv.cand_ok.shape,
@@ -2016,7 +1923,7 @@ class TpuBackend(DecisionBackend):
         cell — own-area id or cross-area resolution — changed distance,
         lanes, or drain state.  Every other row provably reproduces its
         previous selection output, so the patch path skips it."""
-        ch = self._warm_changed_nodes  # [A, V] bool
+        ch = self.gen.warm_changed_nodes  # [A, V] bool
         row_hit = (ch[dv.cand_area, dv.cand_node] & dv.cand_ok).any(axis=1)
         cnia = dv.cand_node_in_area  # [P, C, A]
         ok3 = (cnia >= 0) & dv.cand_ok[:, :, None]
@@ -2024,6 +1931,38 @@ class TpuBackend(DecisionBackend):
         hit3 = ok3 & ch[a_idx, np.maximum(cnia, 0)]
         row_hit |= hit3.any(axis=(1, 2))
         return np.nonzero(row_hit)[0]
+
+    def _patch_tail(
+        self, base, results, table, attr, report=False, retain=None
+    ):
+        """The ending of every build that patches ``base``, the previous
+        RouteDb, with ``results`` (prefix -> entry; None withdraws it).
+        ``attr`` maps each decoded row to its chip (None: nothing
+        attributable).  ``report`` publishes the changed set: every other
+        prefix keeps its previous entry object.  ``retain`` (a delta
+        build's ``(plan, D, enc, dv)``) keeps this build's resident
+        outputs as the next delta base; any other patch leaves them
+        stale for its rows, so it drops them."""
+        from openr_tpu.tracing import pipeline
+
+        self.num_device_builds += 1
+        clean = True
+        if retain is None:
+            self.gen.prev_sel = None
+        else:
+            clean = self._retain_prev_sel(*retain)
+        if attr and clean:
+            self.gen.attribute(table, rows=attr)
+        else:
+            self.gen.attribute(None)
+        if report:
+            self.gen.last_changed_prefixes = {
+                p for p in results if p is not None
+            }
+        with self.probe.phase(pipeline.ROUTE_PATCH):
+            return _patch_route_db(
+                base, results, self.solver.get_static_routes()
+            )
 
     def _build_device(
         self,
@@ -2041,14 +1980,12 @@ class TpuBackend(DecisionBackend):
             # this tick's delta is consumed without being applied to the
             # candidate table — mark it stale or a later apply_dirty would
             # run selection over rows missing this churn
-            self._last_db = None
-            self._table_synced = False
-            self._attr_table = None
+            self.gen.invalidate_base()
             return None
-        prev_enc = self._last_enc
+        prev_enc = self.gen.last_enc
         with self.probe.phase(pipeline.ENCODE):
             enc = self._encoded(area_link_states, me)
-        self._last_enc = enc
+        self.gen.last_enc = enc
 
         # table sync is driven ONLY by prefix churn; the build mode (patch
         # vs full selection) additionally requires an unchanged topology
@@ -2059,7 +1996,7 @@ class TpuBackend(DecisionBackend):
             # path (a full_sync may reassign rows and admits churn the
             # device's changed-row compare cannot see)
             exact_churn = (
-                changed_prefixes is not None and self._table_synced
+                changed_prefixes is not None and self.gen.table_synced
             )
             try:
                 if exact_churn:
@@ -2069,13 +2006,13 @@ class TpuBackend(DecisionBackend):
             except CapacityError:
                 self.num_fallback_cand_overflow += 1
                 raise
-            self._table_synced = True
+            self.gen.table_synced = True
             dv = table.derived(enc)
 
         incremental = (
             changed_prefixes is not None
             and not force_full
-            and self._last_db is not None
+            and self.gen.last_db is not None
             and prev_enc is enc
             and len(changed_prefixes) <= ROWSEL_BUCKETS[-1]
         )
@@ -2086,56 +2023,32 @@ class TpuBackend(DecisionBackend):
             == RouteComputationRules.PER_AREA_SHORTEST_DISTANCE
         )
         # patch-path eligibility must be judged against the PRE-build
-        # RouteDb base (warm-selective needs _last_db built on prev_enc)
-        patch_base = self._last_db
+        # RouteDb base (warm-selective needs gen.last_db built on prev_enc)
+        patch_base = self.gen.last_db
         dist, nh, ovl, soft = self._spf(enc, D, delta_class=delta_class)
 
         if incremental:
             rows = table.rows_for(changed_prefixes)
-            deleted = [
-                p for p in changed_prefixes if p not in table.pid
-            ]
+            deleted = table.missing(changed_prefixes)
             if not rows and not deleted:
                 self.num_incremental_builds += 1
                 # nothing freshly computed this tick: a sampled shadow
                 # check on this db must not attribute stale rows
-                self._attr_table = None
-                return self._last_db
-            results: Dict[str, Optional[RibUnicastEntry]] = {
-                p: None for p in deleted
-            }
-            inc_dev = None
-            if rows:
-                # deleted-only ticks dispatch nothing, so they must not
-                # arm a probe a build would never exercise — the helper
-                # (which arms at most one) only runs when rows exist
-                gathered_results, inc_dev = self._select_rows_gathered(
-                    rows,
-                    (dist, nh, ovl, soft),
-                    dv,
-                    per_area,
-                    table,
-                    enc,
-                    area_link_states,
-                    prefix_state,
-                )
-                results.update(gathered_results)
+                self.gen.attribute(None)
+                return patch_base
+            results, attr = self._select_rows_gathered(
+                rows,
+                deleted,
+                (dist, nh, ovl, soft),
+                dv,
+                per_area,
+                table,
+                enc,
+                area_link_states,
+                prefix_state,
+            )
             self.num_incremental_builds += 1
-            self.num_device_builds += 1
-            if inc_dev is not None and rows:
-                self._attr_rows = {int(r): inc_dev for r in rows}
-                self._attr_plan = None
-                self._attr_table = table
-            else:
-                self._attr_table = None
-            # a patched build leaves the resident full-table outputs
-            # stale for its rows — they can no longer vouch for the
-            # next full build's delta
-            self._prev_sel = None
-            with self.probe.phase(pipeline.ROUTE_PATCH):
-                return _patch_route_db(
-                    self._last_db, results, self.solver.get_static_routes()
-                )
+            return self._patch_tail(patch_base, results, table, attr)
 
         # ---- warm-selective rebuild (generation-delta topology tick) -----
         # the warm solve already re-relaxed only the perturbed frontier;
@@ -2144,12 +2057,12 @@ class TpuBackend(DecisionBackend):
         # RouteDb — selection, decode and the publication diff all stay
         # O(perturbation), not O(total prefixes)
         if (
-            self._warm_solved
-            and self._warm_changed_nodes is not None
+            self.gen.warm_solved
+            and self.gen.warm_changed_nodes is not None
             and patch_base is not None
-            and prev_enc is self._warm_base_enc
-            and self._table_synced
-            and not self._ksp2_present
+            and prev_enc is self.gen.warm_base_enc
+            and self.gen.table_synced
+            and not self.gen.ksp2_present
             and not self.solver.enable_node_segment_label
         ):
             with self.probe.phase(pipeline.WARM_PLAN):
@@ -2159,49 +2072,24 @@ class TpuBackend(DecisionBackend):
                     if changed_prefixes
                     else []
                 )
-                deleted = [
-                    p
-                    for p in (changed_prefixes or ())
-                    if p not in table.pid
-                ]
+                deleted = table.missing(changed_prefixes or ())
                 sel_rows = sorted(set(affected.tolist()) | set(churn_rows))
             if len(sel_rows) <= ROWSEL_BUCKETS[-1]:
-                results = {p: None for p in deleted}
-                inc_dev = None
-                if sel_rows:
-                    gathered_results, inc_dev = self._select_rows_gathered(
-                        sel_rows,
-                        (dist, nh, ovl, soft),
-                        dv,
-                        per_area,
-                        table,
-                        enc,
-                        area_link_states,
-                        prefix_state,
-                    )
-                    results.update(gathered_results)
+                results, attr = self._select_rows_gathered(
+                    sel_rows,
+                    deleted,
+                    (dist, nh, ovl, soft),
+                    dv,
+                    per_area,
+                    table,
+                    enc,
+                    area_link_states,
+                    prefix_state,
+                )
                 self.num_warm_selective_builds += 1
-                self.num_device_builds += 1
-                if inc_dev is not None and sel_rows:
-                    self._attr_rows = {int(r): inc_dev for r in sel_rows}
-                    self._attr_plan = None
-                    self._attr_table = table
-                else:
-                    self._attr_table = None
-                changed_out = {
-                    table.row_prefix[r]
-                    for r in sel_rows
-                    if table.row_prefix[r] is not None
-                }
-                changed_out.update(deleted)
-                self._last_changed_prefixes = changed_out
-                self._prev_sel = None  # patched build: outputs stale
-                with self.probe.phase(pipeline.ROUTE_PATCH):
-                    return _patch_route_db(
-                        patch_base,
-                        results,
-                        self.solver.get_static_routes(),
-                    )
+                return self._patch_tail(
+                    patch_base, results, table, attr, report=True
+                )
 
         # ---- full build (streamed pipeline, ISSUE 11) --------------------
         # the selection batch shards row-contiguously across the pool's
@@ -2217,13 +2105,8 @@ class TpuBackend(DecisionBackend):
             plan, D, enc, dv, changed_prefixes, exact_churn
         )
         if delta_ctx is not None:
-            deleted = [
-                p
-                for p in (changed_prefixes or ())
-                if p not in table.pid
-            ]
+            deleted = table.missing(changed_prefixes or ())
             results = {p: None for p in deleted}
-            decoded_rows: List[int] = []
             shard_devs: Dict[int, int] = {}
             for shard in self._stream_row_shards(
                 dv, (dist, nh, ovl, soft), per_area, plan, delta_ctx
@@ -2261,32 +2144,20 @@ class TpuBackend(DecisionBackend):
                 )
                 for r in global_rows:
                     shard_devs[int(r)] = shard["dev"]
-                decoded_rows.extend(int(r) for r in global_rows)
-            self.num_device_builds += 1
             self.num_delta_builds += 1
-            clean = self._retain_prev_sel(plan, D, enc, dv)
-            if self._use_pool() and decoded_rows and clean:
-                self._attr_rows = shard_devs
-                self._attr_plan = None
-                self._attr_table = table
-            else:
-                self._attr_table = None
-            changed_out = {
-                table.row_prefix[r]
-                for r in decoded_rows
-                if table.row_prefix[r] is not None
-            }
-            changed_out.update(deleted)
-            self._last_changed_prefixes = changed_out
-            with self.probe.phase(pipeline.ROUTE_PATCH):
-                return _patch_route_db(
-                    patch_base, results, self.solver.get_static_routes()
-                )
+            return self._patch_tail(
+                patch_base,
+                results,
+                table,
+                shard_devs if self._use_pool() else None,
+                report=True,
+                retain=(plan, D, enc, dv),
+            )
 
-        # a full decode re-derives KSP2 presence from scratch (the
-        # warm-selective patch path declines while any KSP2 prefix is
-        # live, and _decode_rows re-raises the flag on discovery)
-        self._ksp2_present = False
+        # a full decode re-derives KSP2 presence from scratch (both
+        # row-patch paths decline while any KSP2 prefix is live, and
+        # _ksp2_routes raises the flag again on discovery)
+        self.gen.ksp2_present = False
         results = {}
         for shard in self._stream_row_shards(
             dv, (dist, nh, ovl, soft), per_area, plan, None
@@ -2325,14 +2196,12 @@ class TpuBackend(DecisionBackend):
         self.num_device_builds += 1
         clean = self._retain_prev_sel(plan, D, enc, dv)
         if self._use_pool() and clean:
-            self._attr_plan = plan
-            self._attr_rows = None
-            self._attr_table = table
+            self.gen.attribute(table, plan=plan)
         else:
             # single-chip pool, or a mid-stream re-pack moved rows off
             # the planned chips: don't attribute what the plan no
             # longer describes
-            self._attr_table = None
+            self.gen.attribute(None)
 
         with self.probe.phase(pipeline.DECODE):
             route_db = DecisionRouteDb()

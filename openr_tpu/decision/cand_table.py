@@ -357,6 +357,10 @@ class CandidateTable:
     def rows_for(self, prefixes: Iterable[str]) -> List[int]:
         return [self.pid[p] for p in prefixes if p in self.pid]
 
+    def missing(self, prefixes: Iterable[str]) -> List[str]:
+        """The given prefixes the table holds no row for (withdrawn)."""
+        return [p for p in prefixes if p not in self.pid]
+
     @property
     def num_prefixes(self) -> int:
         return len(self.pid)

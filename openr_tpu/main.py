@@ -46,6 +46,7 @@ from openr_tpu.messaging.queue import ReplicateQueue
 from openr_tpu.monitor.monitor import Monitor
 from openr_tpu.neighbor_monitor import NeighborMonitor
 from openr_tpu.ops import jit_guard
+from openr_tpu.ops.repair import set_plan_cache_cap
 from openr_tpu.plugin import PluginArgs, PluginManager
 from openr_tpu.policy import PolicyManager
 from openr_tpu.prefix_manager.prefix_manager import PrefixManager
@@ -337,13 +338,14 @@ class OpenrNode:
                 tracer=self.tracer,
                 resilience=config.resilience_config,
                 parallel=config.parallel_config,
-                plan_cache_entries=(
-                    config.tpu_compute_config.plan_cache_entries
-                ),
             )
             if use_tpu
             else ScalarBackend(solver)
         )
+        if use_tpu and config.tpu_compute_config.plan_cache_entries:
+            # bound the content-hash RepairPlan memo (ops.repair) the
+            # what-if/sweep planners ride; 0 keeps the library default
+            set_plan_cache_cap(config.tpu_compute_config.plan_cache_entries)
         self.decision = Decision(
             node_name=self.name,
             clock=clock,

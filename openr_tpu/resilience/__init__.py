@@ -46,45 +46,8 @@ def node_resilience_status(node) -> Dict[str, object]:
     kv = getattr(node, "kv_transport", None)
     if kv is not None and hasattr(kv, "breaker_status"):
         out["kv_transport"] = kv.breaker_status()
-    if hasattr(backend, "_warm_class_builds"):
-        # warm-rebuild health split by delta class (ISSUE 12): during a
-        # rolling fleet upgrade the STRUCTURAL ratio is the first thing
-        # an operator reads — a collapse there means publication→FIB
-        # is back on the cold wall while the fleet churns
-        builds = backend._warm_class_builds
-        fallbacks = backend._warm_class_fallbacks
-        out["warm"] = {
-            "enabled": bool(backend._warm_enabled),
-            "context_ready": backend._warm_ctx is not None,
-            "by_class": {
-                cls: {
-                    "hits": builds[cls],
-                    "fallbacks": fallbacks[cls],
-                    "hit_ratio": round(
-                        builds[cls]
-                        / max(1, builds[cls] + fallbacks[cls]),
-                        3,
-                    ),
-                    "fallback_reasons": dict(
-                        sorted(
-                            backend._warm_class_fallback_reasons[
-                                cls
-                            ].items()
-                        )
-                    ),
-                }
-                for cls in sorted(builds)
-            },
-            "encode_patches": backend.num_encode_patches,
-            "encode_slot_patches": backend.num_encode_slot_patches,
-            "slot_declines": dict(
-                sorted(backend._slot_decline_reasons.items())
-            ),
-            "purges": backend.num_warm_purges,
-            "purge_reasons": dict(
-                sorted(backend._warm_purge_reasons.items())
-            ),
-        }
+    if hasattr(backend, "warm_status"):
+        out["warm"] = backend.warm_status()
     return out
 
 

@@ -424,7 +424,7 @@ def test_event_on_a_visited_node_retraces_that_destination(topology):
     ps = _ksp2_prefixes(net.nodes, root)
     backend = _record_backend(root)
     _assert_exact(net, backend, ps)
-    rec = backend._ksp2_records["0"]
+    rec = backend.gen.ksp2_records["0"]
     dest = max(d for d in rec.dests if d.startswith("rsw"))
     fam1, fam2 = rec.dests[dest]
     # a link incident to a visited node, on neither family's paths
@@ -457,7 +457,7 @@ def test_overload_flip_and_node_leaving_retrace_every_destination():
     ps = _ksp2_prefixes(net.nodes, root)
     backend = _record_backend(root)
     _assert_exact(net, backend, ps)
-    rec = backend._ksp2_records["0"]
+    rec = backend.gen.ksp2_records["0"]
     dests = len(rec.dests)
 
     net.overloaded = ["fsw1_1"]
@@ -487,7 +487,7 @@ def test_suspect_purge_drops_the_record_and_the_next_build_retraces_all():
     ps = _ksp2_prefixes(net.nodes, root)
     backend = _record_backend(root)
     _assert_exact(net, backend, ps)
-    rec = backend._ksp2_records["0"]
+    rec = backend.gen.ksp2_records["0"]
     dests = len(rec.dests)
     backend.inject_silent_corruption(True)
     assert rec.dests == {} and rec.reason == "purge"
@@ -497,7 +497,7 @@ def test_suspect_purge_drops_the_record_and_the_next_build_retraces_all():
     assert got["full_retrace.purge"] == 1
     assert got["retraced_dests"] == dests and got.get("reused_dests", 0) == 0
     # a size purge trusts the device: the record stays
-    backend._purge_warm("table_too_large", suspect=False)
+    backend.gen.purge(suspect=False)
     assert len(rec.dests) == dests
 
 

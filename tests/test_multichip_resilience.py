@@ -127,8 +127,8 @@ def test_full_build_shards_across_the_pool_with_parity():
     als, ps = make_world()
     backend = make_backend(SimClock())
     db = backend.build_route_db(als, ps)
-    assert backend._attr_plan is not None
-    devs = [d for d, _lo, _hi in backend._attr_plan]
+    assert backend.gen.attr_plan is not None
+    devs = [d for d, _lo, _hi in backend.gen.attr_plan]
     assert len(devs) > 1, "tiny world must still shard (min_shard_rows=0)"
     assert norm_db(db) == norm_db(SpfSolver("node0").build_route_db(als, ps))
 
@@ -162,7 +162,7 @@ def test_mid_stream_chip_failure_quarantines_and_recovery_is_probed():
     backend._stream_fault = None
     # the next build excludes the chip and stays correct
     db2 = backend.build_route_db(als, ps)
-    assert 2 not in {d for d, _lo, _hi in (backend._attr_plan or ())}
+    assert 2 not in {d for d, _lo, _hi in (backend.gen.attr_plan or ())}
     assert norm_db(db2) == norm_db(
         SpfSolver("node0").build_route_db(als, ps)
     )
@@ -196,7 +196,7 @@ def test_one_corrupt_chip_is_quarantined_individually():
     assert backend.take_full_replace()
     # survivors keep serving: the next build re-packs without chip 3
     db2 = backend.build_route_db(als, ps, force_full=True)
-    assert 3 not in [d for d, _lo, _hi in backend._attr_plan]
+    assert 3 not in [d for d, _lo, _hi in backend.gen.attr_plan]
     assert norm_db(db2) == oracle
 
 
@@ -231,11 +231,11 @@ def test_device_scoped_corrupt_purges_warm_context_and_repacks():
         als, ps, changed_prefixes=set(), force_full=True, warm_delta=True
     )
     assert backend.num_warm_builds == 1
-    assert backend._warm_ctx is not None
+    assert backend.gen.warm_ctx is not None
     # chip-scoped corruption: warm context purged IMMEDIATELY, and the
     # purge arms a forced shadow check for the next device build
     backend.inject_silent_corruption(True, device_index=3)
-    assert backend._warm_ctx is None
+    assert backend.gen.warm_ctx is None
     assert backend.num_warm_purges == 1
     db = backend.build_route_db(als, ps, force_full=True)
     assert gov.num_shadow_mismatches == 1
@@ -252,7 +252,7 @@ def test_device_scoped_corrupt_purges_warm_context_and_repacks():
     assert backend.num_warm_builds == 1
     assert backend._warm_fallback_reasons.get("no_context", 0) >= 1
     # the re-pack dropped the quarantined chip's table replica
-    assert 3 not in backend._spf_replicas
+    assert 3 not in backend.gen.spf_replicas
     perturb(4)
     db = backend.build_route_db(
         als, ps, changed_prefixes=set(), force_full=True, warm_delta=True
@@ -332,7 +332,7 @@ def test_chip_tpu_fail_is_injected_no_probes_until_requested():
     # injected chip outage: NO probe shards, however long the clock runs
     clock._now += 500.0
     backend.build_route_db(als, ps, force_full=True)
-    assert 5 not in [d for d, _lo, _hi in backend._attr_plan]
+    assert 5 not in [d for d, _lo, _hi in backend.gen.attr_plan]
     assert not backend.pool.is_healthy(5)
     # the heal is probed, never trusted blindly
     gov.request_probe_device(5, reason="chaos_heal")

@@ -149,7 +149,7 @@ def test_streamed_build_attributes_drain_to_completing_chip():
         s for s in tracer.get_spans() if s.name == "pipeline.stream_drain"
     ]
     assert drains, "streamed build recorded no stream_drain spans"
-    plan_devs = {d for d, _lo, _hi in backend._attr_plan}
+    plan_devs = {d for d, _lo, _hi in backend.gen.attr_plan}
     assert {s.attrs["device"] for s in drains} == plan_devs
     # one drain window per shard; busy ledger covers every planned chip
     assert len(drains) == len(plan_devs)
@@ -252,7 +252,7 @@ def test_sharded_full_build_attributes_every_phase_and_chip():
         assert h is not None and h.count >= 1, phase
     # device_compute samples are chip-attributed spans; the shard plan's
     # chips and the span-attributed chips agree
-    plan_devs = {d for d, _lo, _hi in backend._attr_plan}
+    plan_devs = {d for d, _lo, _hi in backend.gen.attr_plan}
     span_devs = {
         s.attrs["device"]
         for s in tracer.get_spans()

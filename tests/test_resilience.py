@@ -680,7 +680,7 @@ async def _warm_purge_run():
     assert gov.num_shadow_mismatches == 0
     await clock.run_for(3.0)  # corruption live at t=+8
     # the injection purged the warm context immediately
-    assert backend._warm_ctx is None
+    assert backend.gen.warm_ctx is None
     assert backend._warm_purge_reasons.get("tpu_corrupt", 0) >= 1
     purges_at_fault = backend.num_warm_purges
     # drive a rebuild during the corrupt window: the purge armed a
@@ -701,7 +701,7 @@ async def _warm_purge_run():
     # the first post-purge device build re-solved cold and
     # re-established the context; by now warm rebuilds have resumed
     assert backend.num_warm_builds > warm_before_fault
-    assert backend._warm_ctx is not None
+    assert backend.gen.warm_ctx is not None
     await clock.run_for(6.0)
     checker.check_all()
     stats = (

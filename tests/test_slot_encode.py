@@ -421,7 +421,7 @@ def test_purge_on_suspicion_still_forces_cold_after_structural():
     )
     assert warm._warm_class_builds["structural"] == 1
     warm.inject_silent_corruption(True)
-    assert warm._warm_ctx is None
+    assert warm.gen.warm_ctx is None
     warm.inject_silent_corruption(False)
     ls.update_adjacency_database(adj["node15"])
     for n in ("node11", "node14"):

@@ -250,7 +250,7 @@ def test_out_of_order_completion_reassembles_row_order():
         b._stream_pick = pick
         db = b.build_route_db(als, ps, force_full=True)
         assert_db_equal(db, oracle)
-        assert len({d for d, _lo, _hi in b._attr_plan}) > 1
+        assert len({d for d, _lo, _hi in b.gen.attr_plan}) > 1
 
 
 def test_mid_stream_chip_failure_repacks_onto_survivors():
@@ -274,11 +274,11 @@ def test_mid_stream_chip_failure_repacks_onto_survivors():
     assert_db_equal(db, SpfSolver("node0").build_route_db(als, ps))
     assert not b.pool.is_healthy(3)
     # re-packed build is unattributable by design (rows moved off plan)
-    assert b._attr_table is None
+    assert b.gen.attr_table is None
     b._stream_fault = None
     db2 = b.build_route_db(als, ps, force_full=True)
     assert_db_equal(db2, SpfSolver("node0").build_route_db(als, ps))
-    assert 3 not in {d for d, _lo, _hi in (b._attr_plan or ())}
+    assert 3 not in {d for d, _lo, _hi in (b.gen.attr_plan or ())}
 
 
 def test_mid_stream_failure_without_governor_falls_back_scalar():
@@ -390,9 +390,9 @@ def test_delta_declines_after_purge_and_on_static_change():
     _adj, als, ps = make_world()
     b = make_backend(8)
     b.build_route_db(als, ps, changed_prefixes=set(), force_full=True)
-    assert b._prev_sel is not None
+    assert b.gen.prev_sel is not None
     b.inject_silent_corruption(True)
-    assert b._prev_sel is None
+    assert b.gen.prev_sel is None
     b.inject_silent_corruption(False)
     b.build_route_db(als, ps, changed_prefixes=set(), force_full=True)
     assert b.num_delta_builds == 0

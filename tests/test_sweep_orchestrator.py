@@ -725,13 +725,18 @@ def test_plan_cache_cap_holds_under_world_churn(tmp_path):
 
 
 def test_plan_cache_cap_is_config_wired():
-    from openr_tpu.decision.backend import TpuBackend
-    from openr_tpu.decision.spf_solver import SpfSolver
+    from openr_tpu.config import OpenrConfig
+    from openr_tpu.emulation.network import EmulatedNetwork
     from openr_tpu.ops import repair
 
     before = repair.plan_cache_gauges()["plan_cache.cap"]
     try:
-        TpuBackend(SpfSolver("node0"), plan_cache_entries=5)
+        cfg = OpenrConfig(node_name="node0")
+        cfg.tpu_compute_config.plan_cache_entries = 5
+        # the node that owns the config bounds the planners' memo
+        EmulatedNetwork(SimClock(), use_tpu_backend=True).add_node(
+            "node0", cfg
+        )
         assert repair.plan_cache_gauges()["plan_cache.cap"] == 5.0
     finally:
         repair.set_plan_cache_cap(int(before))

@@ -344,17 +344,22 @@ def test_corruption_injection_purges_warm_context():
     als = {"0": ls}
     warm = make_backend(warm=True)
     warm.build_route_db(als, ps, force_full=True)
-    assert warm._warm_ctx is not None
+    assert warm.gen.warm_ctx is not None
     warm.inject_silent_corruption(True)
-    assert warm._warm_ctx is None
+    assert warm.gen.warm_ctx is None
     assert warm.num_warm_purges == 1
     assert warm._warm_purge_reasons.get("tpu_corrupt") == 1
+    # the operator's resilience status reads the same state
+    status = warm.warm_status()
+    assert not status["context_ready"]
+    assert status["purges"] == 1
+    assert status["purge_reasons"] == {"tpu_corrupt": 1}
     warm.inject_silent_corruption(False)
     # device-scoped injection purges too
     warm.build_route_db(als, ps, force_full=True)
-    assert warm._warm_ctx is not None
+    assert warm.gen.warm_ctx is not None
     warm.inject_silent_corruption(True, device_index=2)
-    assert warm._warm_ctx is None
+    assert warm.gen.warm_ctx is None
     assert warm.num_warm_purges == 2
 
 
@@ -406,6 +411,98 @@ def test_purge_requests_shadow_verification():
     assert gov.num_shadow_checks == checks + 1
 
 
+def _swap_on_next_build(b, adj, als, ps):
+    # shadow verification serves the oracle's RouteDb in place of the
+    # device's: the governor reports the build as not from the device
+    b.governor.after_device_build = lambda db, *a, **kw: (db, False)
+    b.build_route_db(als, ps, changed_prefixes=set())
+
+
+def _too_large_on_next_build(b, adj, als, ps):
+    b.WARM_MAX_TABLE_BYTES = 0
+    perturb_metric(adj, als["0"], np.random.default_rng(5))
+    b.build_route_db(
+        als, ps, changed_prefixes=set(), force_full=True, warm_delta=True
+    )
+
+
+def _scalar_on_next_build(b, adj, als, ps):
+    b.min_device_prefixes = 10**9
+    b.build_route_db(als, ps, changed_prefixes=set())
+
+
+#: trigger -> (members kept, shadow check requested)
+_RESETS = {
+    "tpu_corrupt": (
+        lambda b, *_: b.inject_silent_corruption(True),
+        {"last_db", "table_synced"},
+        True,
+    ),
+    "tpu_corrupt:dev0": (
+        lambda b, *_: b.inject_silent_corruption(True, device_index=0),
+        {"last_db", "table_synced"},
+        True,
+    ),
+    "quarantine": (
+        lambda b, *_: b.governor.force_quarantine(reason="test"),
+        {"last_db", "table_synced"},
+        True,
+    ),
+    "shadow_swap": (_swap_on_next_build, set(), True),
+    "table_too_large": (
+        _too_large_on_next_build,
+        {"spf_tables", "prev_sel", "last_db", "table_synced", "ksp2"},
+        True,
+    ),
+    "scalar_fallback": (
+        _scalar_on_next_build,
+        {"spf_tables", "warm_ctx", "ksp2"},
+        False,
+    ),
+}
+
+
+@pytest.mark.parametrize("trigger", sorted(_RESETS))
+def test_generation_reset(trigger):
+    """Each reset trigger goes through one of the generation's three
+    resets and keeps exactly the members its event leaves trustworthy:
+    the SPF tables, the warm context, the full-build delta base, the
+    RouteDb base, the candidate table's sync mark, the KSP2 traces."""
+    from openr_tpu.types import PrefixForwardingAlgorithm
+
+    adj, ls, ps = make_world()
+    ps.update_prefix(
+        "node15",
+        "0",
+        PrefixEntry(
+            "10.8.0.0/24",
+            forwarding_algorithm=PrefixForwardingAlgorithm.KSP2_ED_ECMP,
+        ),
+    )
+    als = {"0": ls}
+    b = make_backend(warm=True, shadow_sample_every=1000, jitter_pct=0.0)
+    for _ in range(2):
+        b.build_route_db(als, ps, changed_prefixes=set(), force_full=True)
+    gen = b.gen
+    rec = gen.ksp2_records["0"]
+    members = {
+        "spf_tables": lambda: gen.spf_tables is not None,
+        "warm_ctx": lambda: gen.warm_ctx is not None,
+        "prev_sel": lambda: gen.prev_sel is not None,
+        "last_db": lambda: gen.last_db is not None,
+        "table_synced": lambda: gen.table_synced,
+        "ksp2": lambda: bool(rec.dests),
+    }
+    assert all(alive() for alive in members.values())
+    requests = "resilience.backend.shadow_check_requests"
+    before = b.governor.counters.get(requests)
+    trigger_fn, kept, shadow = _RESETS[trigger]
+    trigger_fn(b, adj, als, ps)
+    assert {m for m, alive in members.items() if alive()} == kept
+    assert (b.governor.counters.get(requests) > before) == shadow
+    assert b.take_full_replace() == (trigger == "shadow_swap")
+
+
 # ---------------------------------------------------------------------------
 # multichip: quarantine re-pack purges; warm sweep survives mid-sweep
 # quarantine with parity
@@ -434,7 +531,7 @@ def test_warm_sweep_with_midsweep_chip_quarantine_and_repack():
             # the warm context (re-pack makes per-chip residency
             # suspect) and the shard plan re-packs onto survivors
             gov.force_quarantine_device(2, reason="test")
-            assert warm._warm_ctx is None
+            assert warm.gen.warm_ctx is None
         db_w = warm.build_route_db(
             als, ps, changed_prefixes=set(), force_full=True,
             warm_delta=True,
@@ -446,7 +543,7 @@ def test_warm_sweep_with_midsweep_chip_quarantine_and_repack():
     assert warm._warm_purge_reasons.get("quarantine", 0) >= 1
     assert not warm.pool.is_healthy(2)
     # the replica cache dropped the quarantined chip's residency
-    assert 2 not in warm._spf_replicas
+    assert 2 not in warm.gen.spf_replicas
 
 
 # ---------------------------------------------------------------------------
