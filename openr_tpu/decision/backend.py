@@ -372,9 +372,14 @@ class TpuBackend(DecisionBackend):
                 tracer=tracer,
                 **gov_kwargs,
             )
-        #: KSP2 seeding work across every engine this backend made
-        #: (``decision.backend.ksp2.*``, see Ksp2DeviceEngine.counts)
+        #: KSP2 seeding work across every engine this backend made, and
+        #: route assembly (``decision.backend.ksp2.*``, see
+        #: Ksp2DeviceEngine.counts and Ksp2RouteCache.assemble)
         self.ksp2_counts: Counter = Counter()
+        #: whether this build may hand out KSP2 route entries an earlier
+        #: build returned: not when the caller mutates the RouteDb it gets
+        #: (``cache_result=False``), since the previous RouteDb shares them
+        self._ksp2_reuse = True
         self.num_encode_hits = 0
         self.num_encodes = 0
         #: warm-start generation-delta rebuild: a warm-eligible topology
@@ -506,6 +511,7 @@ class TpuBackend(DecisionBackend):
                 return self._scalar_fallback(
                     area_link_states, prefix_state, counter="small"
                 )
+            self._ksp2_reuse = cache_result
             db = self._build_device(
                 area_link_states,
                 prefix_state,
@@ -807,6 +813,8 @@ class TpuBackend(DecisionBackend):
             "seeded_dests",
             "reused_dests",
             "retraced_dests",
+            "reused_routes",
+            "rebuilt_routes",
             "full_retrace",
             *(f"full_retrace.{r}" for r in FULL_RETRACE_REASONS),
         ):
@@ -987,7 +995,13 @@ class TpuBackend(DecisionBackend):
         (``ksp2`` = (prefixes, {area: winner nodes})): one batched
         masked re-solve per area seeds the k-path memo, then the scalar
         KSP2 chain assembles each route over it — no host Dijkstra runs
-        (decision/ksp2.py).  Runs outside the ``decode`` phase."""
+        (decision/ksp2.py).  A prefix whose advertisements are the same
+        objects, whose advertisers all kept both traced path families
+        (or whose topology generation is unchanged), and whose node
+        labels, root link addresses and solver settings are equal gets
+        the entry it got before, unless that entry's next hops or best
+        entry were reassigned since (``Ksp2RouteCache``).  Runs outside
+        the ``decode`` phase."""
         from openr_tpu.tracing import pipeline
 
         prefixes, dests = ksp2
@@ -1000,12 +1014,15 @@ class TpuBackend(DecisionBackend):
                 nodes
             )
         with self.probe.phase(pipeline.KSP2_ROUTES):
-            return {
-                prefix: self.solver.create_route_for_prefix(
-                    prefix, area_link_states, prefix_state
-                )
-                for prefix in prefixes
-            }
+            return self.gen.ksp2_routes.assemble(
+                prefixes,
+                self.solver,
+                area_link_states,
+                prefix_state,
+                self.gen.ksp2_records,
+                self.ksp2_counts,
+                reuse=self._ksp2_reuse,
+            )
 
     #: per-platform cold-SPF kernel preference (the ROADMAP policy
     #: hook): maps a jax backend platform name ("cpu"/"tpu"/"gpu", or
@@ -2159,6 +2176,7 @@ class TpuBackend(DecisionBackend):
         # _ksp2_routes raises the flag again on discovery)
         self.gen.ksp2_present = False
         results = {}
+        ksp2_assembled = set()
         for shard in self._stream_row_shards(
             dv, (dist, nh, ovl, soft), per_area, plan, None
         ):
@@ -2190,9 +2208,12 @@ class TpuBackend(DecisionBackend):
                         ksp2,
                     )
                 )
-            results.update(
-                self._ksp2_routes(ksp2, enc, area_link_states, prefix_state)
+            routes = self._ksp2_routes(
+                ksp2, enc, area_link_states, prefix_state
             )
+            ksp2_assembled.update(routes)
+            results.update(routes)
+        self.gen.ksp2_routes.retain(ksp2_assembled)
         self.num_device_builds += 1
         clean = self._retain_prev_sel(plan, D, enc, dv)
         if self._use_pool() and clean:

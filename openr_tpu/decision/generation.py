@@ -11,6 +11,8 @@ from __future__ import annotations
 
 from typing import Optional, Set
 
+from openr_tpu.decision.ksp2 import Ksp2RouteCache
+
 
 class DeviceGeneration:
     def __init__(self) -> None:
@@ -61,11 +63,13 @@ class DeviceGeneration:
         self.enc_cache: dict = {}
         self.last_encode_kind = "cold"  # "cold", "patch" or "slot"
         # KSP2: an engine per (area, topology_seq), a Ksp2Record of traced
-        # k-paths per area, and whether the latest decodes met a KSP2
-        # prefix (its route reads the WHOLE topology, so both row-patch
-        # paths decline while it is set)
+        # k-paths per area, the assembled routes kept across builds, and
+        # whether the latest decodes met a KSP2 prefix (its route reads
+        # the WHOLE topology, so both row-patch paths decline while it is
+        # set)
         self.ksp2_engines: dict = {}
         self.ksp2_records: dict = {}
+        self.ksp2_routes = Ksp2RouteCache()
         self.ksp2_present = False
 
     def attribute(self, table=None, plan=None, rows=None) -> None:
@@ -91,10 +95,11 @@ class DeviceGeneration:
     def purge(self, suspect: bool = True) -> bool:
         """Drop the warm context.  ``suspect`` (the device or a chip may
         have lied) also drops the SPF tables and their replicas, the
-        delta base and every KSP2 record, so the next build solves cold
-        on nothing a corruption may have touched; False (the context is
-        too large to keep) leaves the trusted tables.  Returns whether a
-        warm context was there to drop."""
+        delta base, every KSP2 record and the KSP2 routes assembled over
+        them, so the next build solves cold on nothing a corruption may
+        have touched; False (the context is too large to keep) leaves
+        the trusted tables.  Returns whether a warm context was there to
+        drop."""
         if suspect:
             self.spf_tables = None
             self.spf_enc = None
@@ -103,6 +108,7 @@ class DeviceGeneration:
             self.prev_sel = None
             for rec in self.ksp2_records.values():
                 rec.drop("purge")
+            self.ksp2_routes = Ksp2RouteCache()
         if self.warm_ctx is None and self.warm_changed_nodes is None:
             return False
         self.warm_ctx = None

@@ -32,7 +32,23 @@ node changed and every recorded distance is equal in the fresh field
 2, which is kept only with its family 1).  The batched re-solve of every
 destination is therefore the change detector, and any other change (node
 membership, overload, node metric increment, adjacency label, encoding)
-drops the whole record.
+drops the whole record.  The destinations whose two families a
+generation kept are the record's ``kept`` set, relative to ``prev_seq``.
+
+Kept routes: a KSP2 route reads the prefix's advertisements, each
+advertiser's reachability and drain state, the two families of paths to
+each winner, the metrics of their links, the node segment labels along
+them, the root's first-hop link (address, interface, area, neighbour)
+and the solver's settings.  ``Ksp2RouteCache`` hands a prefix's entry
+out again when its advertisement objects are the same, every advertiser
+was kept in each generation since the entry was assembled (a kept
+destination has equal distances on every node its traces read, so its
+reachability and path costs are equal, and any node-level change drops
+the record), the node labels, the root's link addresses and the settings
+are equal (they move without a new topology generation, so the cache
+compares them on every build), and the entry still holds the next hops
+and best entry it was built with (RibPolicy reassigns them in place).
+Anything else is assembled anew by ``SpfSolver.create_route_for_prefix``.
 """
 
 from __future__ import annotations
@@ -53,10 +69,13 @@ from typing import (
 import numpy as np
 
 from openr_tpu.decision.link_state import Link, LinkState, Path
+from openr_tpu.decision.rib import RibUnicastEntry
 from openr_tpu.ops.csr import EncodedTopology
 from openr_tpu.tracing import disabled_probe, pipeline
+from openr_tpu.types import PrefixForwardingAlgorithm
 
 _BIG = np.float32(3.4e38)
+_KSP2_ED_ECMP = PrefixForwardingAlgorithm.KSP2_ED_ECMP
 
 LinkKey = Tuple[str, str, str, str]
 
@@ -102,12 +121,20 @@ class Ksp2Record:
     engines; bound to one ``LinkState`` object and root.  ``seq`` is the
     generation whose link and node snapshots ``dests`` were traced or
     verified against; ``reason`` (set while nothing is retained) is why
-    the next generation re-traces every destination."""
+    the next generation re-traces every destination.  ``kept`` holds the
+    destinations whose two families generation ``seq`` kept from
+    generation ``prev_seq``."""
 
     def __init__(self, link_state: LinkState, root: str, reason: str = "cold"):
         self.link_state = link_state
         self.root = root
         self.seq: Optional[int] = None
+        self.prev_seq: Optional[int] = None
+        self.kept: Set[str] = set()
+        #: what this generation's seeds keep from: the previous
+        #: generation's dests and the nodes its link changes touched
+        self.prior: Dict[str, Tuple[_Family, _Family]] = {}
+        self.touched: Set[str] = set()
         self.links: Optional[Dict[LinkKey, tuple]] = None
         self.nodes: Optional[Dict[str, tuple]] = None
         self.node_ids: Optional[Dict[str, int]] = None
@@ -119,8 +146,10 @@ class Ksp2Record:
         into the LinkState memo: the next build re-traces them all."""
         if self.dests:
             self.link_state.clear_spf_memoization()
-        self.seq = self.links = self.nodes = self.node_ids = None
-        self.dests = {}
+        self.seq = self.prev_seq = None
+        self.links = self.nodes = self.node_ids = None
+        self.dests, self.prior = {}, {}
+        self.kept, self.touched = set(), set()
         self.reason = reason
 
 
@@ -217,6 +246,7 @@ class Ksp2DeviceEngine:
                 row2 = dist2[row]
                 if old is not None and self._holds(old[1], touched, row2):
                     f2 = old[1]
+                    self.record.kept.add(d)
                     self.counts["reused_dests"] += 1
                 else:
                     ignored = {link_of[k] for p in f1.paths for k in p}
@@ -234,10 +264,13 @@ class Ksp2DeviceEngine:
         """Bring the record to this topology generation.  Returns the
         destinations whose families may be kept, and the endpoints of
         every link whose (up, metric) changed since they were traced.
-        Within one generation nothing is kept: a destination that is not
-        memoized is new to it."""
+        A later seed of the same generation (the next shard of one
+        decode) gets the same two while the node ids hold."""
         rec, ls = self.record, self.link_state
         if rec.links is not None and rec.seq == ls.topology_seq:
+            ids = self.topo.node_ids
+            if ids is rec.node_ids or ids == rec.node_ids:
+                return rec.prior, rec.touched
             return {}, set()
         all_links = ls.all_links()
         links = dict(
@@ -269,8 +302,10 @@ class Ksp2DeviceEngine:
             prior = {}
             self.counts["full_retrace"] += 1
             self.counts[f"full_retrace.{reason}"] += 1
+        rec.prev_seq = rec.seq
         rec.seq, rec.links, rec.nodes = ls.topology_seq, links, nodes
         rec.node_ids, rec.dests, rec.reason = node_ids, {}, None
+        rec.prior, rec.touched, rec.kept = prior, touched, set()
         return prior, touched
 
     @staticmethod
@@ -433,3 +468,160 @@ class Ksp2DeviceEngine:
             paths.append(path)
             path = trace_one(dest)
         return paths, set(pl_cache), reads
+
+
+# -- assembled routes kept across builds -----------------------------------
+
+
+class _Route:
+    """One prefix's assembled KSP2 route (None: no route) and what its
+    assembly read: the advertisement objects, each one's area topology
+    generation, and the context the cache held."""
+
+    __slots__ = ("entry", "nexthops", "best", "selection", "adverts",
+                 "seqs", "context")
+
+
+class Ksp2RouteCache:
+    """KSP2 route entries kept across builds, keyed by prefix (module
+    doc, "Kept routes").  Owned by the device generation, so a suspect
+    purge drops it with the records whose traces it rests on."""
+
+    def __init__(self) -> None:
+        self.routes: Dict[str, _Route] = {}
+        # what every entry reads besides its advertisements and paths:
+        # the solver's settings and, per area, the LinkState object, the
+        # node labels and the root's link addresses; a new key is a new
+        # context object, which no older entry holds
+        self._key = None
+        self._context = None
+
+    def assemble(
+        self,
+        prefixes: Sequence[str],
+        solver,
+        area_link_states: Dict[str, LinkState],
+        prefix_state,
+        records: Dict[str, Ksp2Record],
+        counts: Counter,
+        reuse: bool,
+    ) -> Dict[str, Optional[RibUnicastEntry]]:
+        """Each prefix's route: its kept entry where every input it read
+        is unchanged (and ``reuse``: False when the caller mutates the
+        entries it gets), else ``solver.create_route_for_prefix``'s.
+        Counts ``reused_routes`` and ``rebuilt_routes``."""
+        context = self._context_for(solver, area_link_states)
+        now = {a: ls.topology_seq for a, ls in area_link_states.items()}
+        # areas whose record advanced to this generation: the generation
+        # it kept destinations from, and those destinations
+        since = {
+            a: (rec.prev_seq, rec.kept)
+            for a, rec in records.items()
+            if rec.prev_seq is not None
+            and rec.link_state is area_link_states.get(a)
+            and rec.seq == now[a]
+        }
+        all_entries = prefix_state.prefixes()
+        best_cache = solver.best_routes_cache
+        routes = self.routes
+        out: Dict[str, Optional[RibUnicastEntry]] = {}
+        for prefix in prefixes:
+            c = routes.get(prefix)
+            entries = all_entries.get(prefix)
+            if (
+                reuse
+                and c is not None
+                and c.context is context
+                and entries is not None
+                and self._holds(c, entries, now, since)
+            ):
+                c.seqs = tuple(now.get(a) for (_, a), _ in c.adverts)
+                if c.selection is None:
+                    best_cache.pop(prefix, None)
+                else:
+                    best_cache[prefix] = c.selection
+                out[prefix] = c.entry
+                counts["reused_routes"] += 1
+                continue
+            entry = solver.create_route_for_prefix(
+                prefix, area_link_states, prefix_state
+            )
+            counts["rebuilt_routes"] += 1
+            out[prefix] = entry
+            selection = best_cache.get(prefix)
+            if not entries or (
+                selection is not None
+                and entries[min(selection.all_node_areas)].forwarding_algorithm
+                != _KSP2_ED_ECMP
+            ):
+                # the SP_ECMP chain reads the whole shortest-path DAG,
+                # which a kept trace does not cover
+                routes.pop(prefix, None)
+                continue
+            c = _Route()
+            if entry is not None:
+                # frozen, as the SP_ECMP decode's: a kept entry is shared
+                # by successive RouteDbs and must not change in place
+                entry.nexthops = frozenset(entry.nexthops)
+            c.entry = entry
+            c.nexthops = None if entry is None else entry.nexthops
+            c.best = None if entry is None else entry.best_prefix_entry
+            c.selection = selection
+            c.adverts = tuple(entries.items())
+            c.seqs = tuple(now.get(a) for (_, a), _ in c.adverts)
+            c.context = context
+            routes[prefix] = c
+        return out
+
+    def retain(self, prefixes) -> None:
+        """Forget the entries of every prefix not in ``prefixes``: a full
+        build's KSP2 prefixes."""
+        routes = self.routes
+        for p in [p for p in routes if p not in prefixes]:
+            del routes[p]
+
+    def _context_for(self, solver, area_link_states):
+        me = solver.my_node_name
+        key = (
+            me,
+            solver.enable_v4,
+            solver.v4_over_v6_nexthop,
+            solver.enable_best_route_selection,
+            solver.route_selection_algorithm,
+            [
+                (
+                    area,
+                    ls,
+                    {
+                        n: db.node_label
+                        for n, db in ls.get_adjacency_databases().items()
+                    },
+                    sorted(
+                        (link.key, link.get_nh_v4_from_node(me),
+                         link.get_nh_v6_from_node(me))
+                        for link in ls.links_from_node(me)
+                    ),
+                )
+                for area, ls in sorted(area_link_states.items())
+            ],
+        )
+        if self._context is None or key != self._key:
+            self._key, self._context = key, object()
+        return self._context
+
+    @staticmethod
+    def _holds(c: _Route, entries, now, since) -> bool:
+        """True when ``c`` can be handed out again (module doc)."""
+        if len(entries) != len(c.adverts):
+            return False
+        for ((node, area), entry), seq in zip(c.adverts, c.seqs):
+            if entries.get((node, area)) is not entry:
+                return False
+            if seq != now.get(area):
+                kept = since.get(area)
+                if kept is None or kept[0] != seq or node not in kept[1]:
+                    return False
+        route = c.entry
+        return route is None or (
+            route.nexthops is c.nexthops and route.best_prefix_entry is c.best
+        )

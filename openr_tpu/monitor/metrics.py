@@ -248,6 +248,13 @@ def _build_descriptions() -> Dict[str, str]:
             "seeded KSP2 destinations with at least one path family "
             "traced anew (an input of its trace moved, or it was new)"
         ),
+        "decision.backend.ksp2.reused_routes": (
+            "KSP2 routes handed out again: advertisements, kept path "
+            "families, node labels and root link addresses unchanged"
+        ),
+        "decision.backend.ksp2.rebuilt_routes": (
+            "KSP2 routes assembled anew by the scalar KSP2 chain"
+        ),
         "decision.backend.ksp2.full_retrace": (
             "topology generations that dropped every retained KSP2 "
             "trace and re-traced all destinations"

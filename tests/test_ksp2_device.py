@@ -6,6 +6,8 @@ over device distance fields) must be bit-identical to the scalar chain
 LinkState.cpp:675-699 + SpfSolver KSP2 semantics).
 """
 
+import dataclasses
+
 import pytest
 
 from openr_tpu.decision.backend import ScalarBackend, TpuBackend
@@ -310,6 +312,8 @@ class _Network:
         self.overloaded = []
         self.nodes = sorted({n for e in edges for n in e[:2]})
         self.labels = {n: 100 + i for i, n in enumerate(self.nodes)}
+        #: (node, neighbour) -> the v4 next hop node uses over that link
+        self.nh_v4 = {}
         self.ls = self.fresh()
 
     def edges(self):
@@ -321,6 +325,12 @@ class _Network:
 
         dbs = build_adj_dbs(self.edges(), node_labels=self.labels,
                             overloaded=self.overloaded)
+        for (n, other), address in self.nh_v4.items():
+            dbs[n].adjacencies = [
+                a if a.other_node_name != other
+                else dataclasses.replace(a, next_hop_v4=address)
+                for a in dbs[n].adjacencies
+            ]
         for n in self.nodes:
             dbs.setdefault(n, AdjacencyDatabase(
                 this_node_name=n, adjacencies=[], area="0",
@@ -386,6 +396,9 @@ def _assert_exact(net, backend, ps):
     scalar = ScalarBackend(SpfSolver(net.root)).build_route_db(
         {"0": net.fresh()}, ps)
     assert _db_view(tpu) == _db_view(scalar)
+    # every field, next-hop addresses and the best entry included
+    assert tpu.unicast_routes == scalar.unicast_routes
+    return tpu
 
 
 @pytest.mark.parametrize("seed", [11, 12])
@@ -443,6 +456,37 @@ def test_event_on_a_visited_node_retraces_that_destination(topology):
             if all(a is b for a, b in zip(fams, before[d]))]
     assert kept and dest not in kept
     assert backend.ksp2_counts["reused_dests"] == len(kept)
+
+
+def test_later_seeds_of_one_generation_keep_as_the_first_does():
+    """A decode in several shards seeds each shard's destinations in
+    turn: every seed of the generation keeps from the same prior."""
+    from openr_tpu.decision.ksp2 import Ksp2DeviceEngine, Ksp2Record
+    from openr_tpu.ops.csr import encode_link_state
+
+    make_edges, root = TOPOLOGIES["f4"]
+    kept = {}
+    for shards in (1, 3):
+        net = _Network(make_edges(), root)
+        rec = Ksp2Record(net.ls, root)
+        dests = [n for n in net.nodes if n != root]
+
+        def seed_all():
+            eng = Ksp2DeviceEngine(net.ls, encode_link_state(net.ls), root,
+                                   record=rec)
+            for i in range(shards):
+                eng.seed(dests[i::shards])
+            return eng.counts
+
+        seed_all()
+        net.apply("metric", sorted(net.base)[0], 3)
+        counts = seed_all()
+        for d in dests:
+            for k in (1, 2):
+                assert net.ls.get_kth_paths(root, d, k) == (
+                    net.fresh().get_kth_paths(root, d, k)), (d, k)
+        kept[shards] = (counts["reused_dests"], set(rec.kept))
+    assert kept[3] == kept[1] and kept[1][0] > 0
 
 
 def _one_build_counts(backend, net, ps):
@@ -526,3 +570,193 @@ def test_device_built_masks_equal_link_failure_batch():
     assert got.dtype == want.dtype and got.shape == want.shape
     assert (got == want).all()
     assert not got[0].all() and got[1].all() and got[-1].all()
+
+
+# ---------------------------------------------------------------------------
+# assembled KSP2 routes kept across builds (Ksp2RouteCache)
+# ---------------------------------------------------------------------------
+
+
+def _assembly_spy(backend):
+    """Record the prefixes each build handed to route assembly and the
+    ones the scalar KSP2 chain assembled anew."""
+    spy = {"assembled": [], "rebuilt": []}
+    ksp2_routes = backend._ksp2_routes
+    create = backend.solver.create_route_for_prefix
+
+    def routes(ksp2, *args):
+        spy["assembled"].extend(ksp2[0])
+        return ksp2_routes(ksp2, *args)
+
+    def create_route(prefix, *args):
+        spy["rebuilt"].append(prefix)
+        return create(prefix, *args)
+
+    backend._ksp2_routes = routes
+    backend.solver.create_route_for_prefix = create_route
+    return spy
+
+
+def _build_delta(backend, net, ps, spy):
+    """One exact build: its counter deltas, and the prefixes it rebuilt."""
+    spy["assembled"].clear()
+    spy["rebuilt"].clear()
+    got = _one_build_counts(backend, net, ps)
+    reused = got.get("reused_routes", 0)
+    rebuilt = got.get("rebuilt_routes", 0)
+    assert reused + rebuilt == len(spy["assembled"])
+    assert rebuilt == len(spy["rebuilt"])
+    return reused, set(spy["rebuilt"])
+
+
+def _prefix_of(ps, node):
+    return next(p for p, e in ps.prefixes().items() if (node, "0") in e)
+
+
+def _route_world(topology="fabric"):
+    make_edges, root = TOPOLOGIES[topology]
+    net = _Network(make_edges(), root)
+    ps = _ksp2_prefixes(net.nodes, root)
+    backend = _record_backend(root)
+    spy = _assembly_spy(backend)
+    first = _assert_exact(net, backend, ps)
+    return net, ps, backend, spy, first
+
+
+@pytest.mark.parametrize("topology", sorted(TOPOLOGIES))
+def test_kept_ksp2_routes_match_scalar_across_link_events(topology):
+    import random
+
+    net, ps, backend, spy, _ = _route_world(topology)
+    rng = random.Random(21)
+    links = sorted(net.base)
+    reused_total = 0
+    for _cycle in range(6):
+        gone, bent = rng.sample(links, 2)
+        for kind, link in (("down", gone), ("metric", bent),
+                           ("restore", bent), ("up", gone)):
+            net.apply(kind, link, rng.randint(2, 4))
+            before = backend.gen.ksp2_routes.routes.copy()
+            reused, rebuilt = _build_delta(backend, net, ps, spy)
+            reused_total += reused
+            # a reused prefix is handed the very entry it had
+            for p in set(spy["assembled"]) - rebuilt:
+                assert backend.gen.ksp2_routes.routes[p] is before[p]
+    assert reused_total > 0
+    counts = backend.ksp2_counts
+    assert counts["reused_routes"] + counts["rebuilt_routes"] > reused_total
+    assert backend.num_scalar_builds == 0
+
+
+def test_changed_advertisement_rebuilds_its_route_on_an_unchanged_topology():
+    from openr_tpu.types import PrefixEntry
+
+    net, ps, backend, spy, first = _route_world()
+    prefix = _prefix_of(ps, "rsw2_1")
+    assert first.unicast_routes[prefix].nexthops
+    for min_nexthop in (99, None):
+        ps.update_prefix("rsw2_1", "0", PrefixEntry(
+            prefix, forwarding_type=SR_MPLS, forwarding_algorithm=KSP2,
+            min_nexthop=min_nexthop))
+        reused, rebuilt = _build_delta(backend, net, ps, spy)
+        assert rebuilt == {prefix} and reused > 0
+    # the gate withdrew the route, and the re-advertisement restored it
+    assert prefix in backend.gen.last_db.unicast_routes
+
+
+def test_anycast_prefix_rebuilds_when_one_advertiser_was_not_kept():
+    from openr_tpu.types import PrefixEntry
+
+    net, ps, backend, spy, _ = _route_world()
+    rec = backend.gen.ksp2_records["0"]
+    moved = "rsw1_0"
+    link = next(e for e in sorted(net.base) if moved in e)
+    ends = set(link)
+    # an advertiser whose traces never visit the moved link's ends
+    steady = next(
+        d for d in sorted(rec.dests)
+        if d.startswith("rsw") and d != moved
+        and not any(ends & fam.visited for fam in rec.dests[d])
+    )
+    anycast = "10.250.0.0/24"
+    for node in (moved, steady):
+        ps.update_prefix(node, "0", PrefixEntry(
+            anycast, forwarding_type=SR_MPLS, forwarding_algorithm=KSP2))
+    _build_delta(backend, net, ps, spy)
+    net.apply("metric", link, 3)
+    reused, rebuilt = _build_delta(backend, net, ps, spy)
+    assert steady in rec.kept and moved not in rec.kept
+    assert anycast in rebuilt and _prefix_of(ps, moved) in rebuilt
+    assert _prefix_of(ps, steady) not in rebuilt and reused > 0
+
+
+def test_node_label_change_on_a_kept_path_rebuilds_the_route():
+    net, ps, backend, spy, first = _route_world()
+    dest = "rsw2_1"
+    prefix = _prefix_of(ps, dest)
+    # the first node past the first hop of a second-family path: the top
+    # of its PUSH stack
+    path = net.ls.get_kth_paths(net.root, dest, 2)[0]
+    node = path[1].get_other_node_name(
+        path[0].get_other_node_name(net.root))
+    assert node != dest
+    seq = net.ls.topology_seq
+    net.labels[node] += 1000
+    net.publish(node)
+    assert net.ls.topology_seq == seq  # labels move no topology generation
+    reused, rebuilt = _build_delta(backend, net, ps, spy)
+    assert prefix in rebuilt and reused == 0
+    assert (backend.gen.last_db.unicast_routes[prefix]
+            != first.unicast_routes[prefix])
+
+
+def test_root_link_address_change_rebuilds_the_route():
+    net, ps, backend, spy, first = _route_world()
+    root = net.root
+    prefix = _prefix_of(ps, "rsw2_1")
+    via = sorted(nh.neighbor_node_name
+                 for nh in first.unicast_routes[prefix].nexthops)[0]
+    net.nh_v4[(root, via)] = "10.9.9.9"
+    seq = net.ls.topology_seq
+    net.publish(root)
+    assert net.ls.topology_seq == seq
+    reused, rebuilt = _build_delta(backend, net, ps, spy)
+    assert prefix in rebuilt and reused == 0
+    assert "10.9.9.9" in {
+        nh.address
+        for nh in backend.gen.last_db.unicast_routes[prefix].nexthops
+    }
+
+
+def test_entry_reweighted_in_place_is_rebuilt_and_policy_builds_share_none():
+    from openr_tpu.decision.rib_policy import (
+        RibPolicyStatement,
+        RibRouteActionWeight,
+    )
+
+    net, ps, backend, spy, first = _route_world()
+    prefix = _prefix_of(ps, "rsw2_1")
+    entry = first.unicast_routes[prefix]
+    stmt = RibPolicyStatement(
+        prefixes=[prefix], action=RibRouteActionWeight(default_weight=3))
+    assert stmt.apply_action(entry)
+    reused, rebuilt = _build_delta(backend, net, ps, spy)
+    assert rebuilt == {prefix} and reused > 0
+    assert backend.gen.last_db.unicast_routes[prefix] is not entry
+    # a caller that mutates what it gets shares no entry with the last db
+    last = backend.gen.last_db.unicast_routes
+    before = dict(backend.ksp2_counts)
+    db = backend.build_route_db({"0": net.ls}, ps, cache_result=False)
+    assert backend.ksp2_counts["reused_routes"] == before["reused_routes"]
+    assert all(db.unicast_routes[p] is not e for p, e in last.items())
+
+
+def test_suspect_purge_rebuilds_every_route():
+    net, ps, backend, spy, _ = _route_world()
+    reused, rebuilt = _build_delta(backend, net, ps, spy)
+    assert not rebuilt and reused == len(spy["assembled"]) > 0
+    backend.inject_silent_corruption(True)
+    assert backend.gen.ksp2_routes.routes == {}
+    backend.inject_silent_corruption(False)
+    reused, rebuilt = _build_delta(backend, net, ps, spy)
+    assert reused == 0 and rebuilt == set(spy["assembled"])
