@@ -399,7 +399,7 @@ def bench_parity_device_coverage(results: List[Dict], full: bool) -> None:
         if policy is not None:
             clock = SimClock()
             for d in (db, ref):
-                assert policy.apply_policy(d, clock) > 0
+                assert policy.apply_policy(d, clock).routes_modified > 0
         from openr_tpu.decision.rib import route_db_summary
 
         match = route_db_summary(db) == route_db_summary(ref)

@@ -753,6 +753,15 @@ class Decision(Actor):
             and not policy_active
             and not self._last_policy_active
         )
+        # the policy is the only reason this build is cold: without it a
+        # topology perturbation would be a warm (or structural) delta
+        # build and a prefix-only change an incremental one
+        if (
+            (policy_active or self._last_policy_active)
+            and self._first_build_done
+            and not self._pending_force_full
+        ):
+            self.counters.bump("decision.policy_forced_full_builds")
         changed = self._pending_prefix_changes
         self._pending_prefix_changes = set()
         self._pending_topo_changed = False
@@ -802,8 +811,13 @@ class Decision(Actor):
         self.counters.bump("decision.route_build_runs")
         if new_db is None:
             return
+        from openr_tpu.tracing import pipeline as _pipeline
+
         if self.rib_policy is not None and self.rib_policy.is_active(self.clock):
-            self.rib_policy.apply_policy(new_db, self.clock)
+            with self._backend_probe().phase(_pipeline.RIB_POLICY):
+                stats = self.rib_policy.apply_policy(new_db, self.clock)
+            for name, n in vars(stats).items():
+                self.counters.bump(f"decision.rib_policy.{name}", n)
         if self.backend.take_full_replace():
             # quarantine swap: the backend replaced corrupt device output
             # with the scalar oracle's FULL db — diff everything so
@@ -817,8 +831,6 @@ class Decision(Actor):
                 self.protection.purge_table("full_replace")
         # the RouteDb diff is the pipeline's delta-extract tail: the last
         # host stage between device output and the FIB publication
-        from openr_tpu.tracing import pipeline as _pipeline
-
         with self._backend_probe().phase(_pipeline.DELTA_EXTRACT):
             warm_changed = None
             if force_full:

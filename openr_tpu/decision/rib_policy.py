@@ -36,8 +36,13 @@ class RibPolicyStatement:
     tags: Set[str] = field(default_factory=set)
     action: RibRouteActionWeight = field(default_factory=RibRouteActionWeight)
 
+    def __post_init__(self) -> None:
+        # built once: ``matches`` runs once per route, and a list scan
+        # there costs routes x prefixes
+        self._prefix_set = frozenset(self.prefixes)
+
     def matches(self, entry: RibUnicastEntry) -> bool:
-        if self.prefixes and entry.prefix in self.prefixes:
+        if self.prefixes and entry.prefix in self._prefix_set:
             return True
         if self.tags and self.tags & entry.best_prefix_entry.tags:
             return True
@@ -76,6 +81,21 @@ class RibPolicyStatement:
 
 
 @dataclass
+class RibPolicyStats:
+    """What one ``apply_policy`` pass did (Decision's
+    ``decision.rib_policy.*`` counters)."""
+
+    #: routes some statement matched
+    routes_matched: int = 0
+    #: matched routes whose next hops changed (re-weighted or dropped)
+    routes_modified: int = 0
+    #: next hops a weight of 0 removed
+    nexthops_dropped: int = 0
+    #: routes left with no next hop, and so removed
+    routes_dropped: int = 0
+
+
+@dataclass
 class RibPolicy:
     statements: List[RibPolicyStatement] = field(default_factory=list)
     #: absolute expiry on the shared clock; None = no policy
@@ -84,25 +104,31 @@ class RibPolicy:
     def is_active(self, clock: Clock) -> bool:
         return clock.now() < self.valid_until
 
-    def apply_policy(self, route_db: DecisionRouteDb, clock: Clock) -> int:
-        """Apply to every matching route; returns number modified
-        (RibPolicy::applyPolicy, used in Decision.cpp:917-950)."""
+    def apply_policy(
+        self, route_db: DecisionRouteDb, clock: Clock
+    ) -> RibPolicyStats:
+        """Apply to every matching route (RibPolicy::applyPolicy, used in
+        Decision.cpp:917-950); returns what it matched, changed and
+        dropped."""
+        stats = RibPolicyStats()
         if not self.is_active(clock):
-            return 0
-        modified = 0
+            return stats
         for entry in route_db.unicast_routes.values():
             for stmt in self.statements:
                 if stmt.matches(entry):
+                    stats.routes_matched += 1
+                    before = len(entry.nexthops)
                     if stmt.apply_action(entry):
-                        modified += 1
+                        stats.routes_modified += 1
+                        stats.nexthops_dropped += before - len(entry.nexthops)
                     break  # first matching statement wins
         # drop routes whose nexthops were all zero-weighted
         for prefix in [
             p for p, e in route_db.unicast_routes.items() if not e.nexthops
         ]:
             del route_db.unicast_routes[prefix]
-            modified += 1
-        return modified
+            stats.routes_dropped += 1
+        return stats
 
     # -- persistence (FLAGS_rib_policy_file pattern) -----------------------
 

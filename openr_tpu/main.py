@@ -291,25 +291,6 @@ class OpenrNode:
             clock=clock,
         )
         self.policy_manager = PolicyManager(config.policy_config)
-        self.prefix_manager = PrefixManager(
-            node_name=self.name,
-            clock=clock,
-            kv_request_queue=self.kv_request_q,
-            static_route_updates_queue=self.static_route_updates_q,
-            prefix_updates_reader=self.prefix_updates_q.get_reader(),
-            fib_route_updates_reader=self.fib_route_updates_q.get_reader(),
-            areas=areas,
-            originated_prefixes=config.originated_prefixes,
-            initialization_cb=on_init,
-            counters=self.counters,
-            policy_manager=self.policy_manager,
-            area_import_policies={
-                a.area_id: a.import_policy
-                for a in config.areas
-                if a.import_policy
-            },
-            lsdb_wire_format=config.lsdb_wire_format,
-        )
         solver = SpfSolver(
             self.name,
             enable_v4=config.enable_v4,
@@ -364,7 +345,8 @@ class OpenrNode:
         )
         self.init_tracker.add_listener(self.decision.on_initialization_event)
         # the backend's PipelineProbe (None on the scalar backend): Fib
-        # records its phase on the ledger Decision and the backend use
+        # and PrefixManager record their phases on the ledger Decision
+        # and the backend use
         probe = getattr(backend, "probe", None)
         self.fib = Fib(
             node_name=self.name,
@@ -377,6 +359,26 @@ class OpenrNode:
             counters=self.counters,
             dryrun=config.dryrun,
             tracer=self.tracer,
+            probe=probe,
+        )
+        self.prefix_manager = PrefixManager(
+            node_name=self.name,
+            clock=clock,
+            kv_request_queue=self.kv_request_q,
+            static_route_updates_queue=self.static_route_updates_q,
+            prefix_updates_reader=self.prefix_updates_q.get_reader(),
+            fib_route_updates_reader=self.fib_route_updates_q.get_reader(),
+            areas=areas,
+            originated_prefixes=config.originated_prefixes,
+            initialization_cb=on_init,
+            counters=self.counters,
+            policy_manager=self.policy_manager,
+            area_import_policies={
+                a.area_id: a.import_policy
+                for a in config.areas
+                if a.import_policy
+            },
+            lsdb_wire_format=config.lsdb_wire_format,
             probe=probe,
         )
         # the serving plane fronts Decision's fleet/what-if engines with

@@ -28,6 +28,7 @@ from openr_tpu.common.runtime import Actor, Clock, CounterMap
 from openr_tpu.config import OriginatedPrefix
 from openr_tpu.decision.rib import DecisionRouteUpdate, RibUnicastEntry
 from openr_tpu.messaging.queue import RQueue, ReplicateQueue
+from openr_tpu.tracing import pipeline
 from openr_tpu.types import (
     InitializationEvent,
     KeyValueRequest,
@@ -72,6 +73,7 @@ class PrefixManager(Actor):
         policy_manager=None,
         area_import_policies: Optional[Dict[str, str]] = None,
         lsdb_wire_format: str = "json",
+        probe=None,
     ) -> None:
         super().__init__("prefix_manager", clock, counters)
         self.node_name = node_name
@@ -106,6 +108,9 @@ class PrefixManager(Actor):
         #: (area, key) currently present in kvstore
         self._advertised_keys: Set[Tuple[str, str]] = set()
         self._synced_signaled = False
+        #: the node's PipelineProbe (the one Decision's backend and Fib
+        #: record on)
+        self.probe = probe if probe is not None else pipeline.disabled_probe()
 
     # -- lifecycle ---------------------------------------------------------
 
@@ -155,15 +160,16 @@ class PrefixManager(Actor):
     # -- fib feedback: originated support + redistribution -----------------
 
     def _on_fib_update(self, update: DecisionRouteUpdate) -> None:
-        changed = False
-        for prefix, entry in update.unicast_routes_to_update.items():
-            changed |= self._update_supporting(prefix, present=True)
-            changed |= self._maybe_redistribute(prefix, entry)
-        for prefix in update.unicast_routes_to_delete:
-            changed |= self._update_supporting(prefix, present=False)
-            changed |= self._withdraw_redistribution(prefix)
-        if changed:
-            self._sync_kv_store()
+        with self.probe.phase(pipeline.PREFIX_REDISTRIBUTE):
+            changed = False
+            for prefix, entry in update.unicast_routes_to_update.items():
+                changed |= self._update_supporting(prefix, present=True)
+                changed |= self._maybe_redistribute(prefix, entry)
+            for prefix in update.unicast_routes_to_delete:
+                changed |= self._update_supporting(prefix, present=False)
+                changed |= self._withdraw_redistribution(prefix)
+            if changed:
+                self._sync_kv_store()
 
     # -- originated prefix aggregation (PrefixManager.h:325-346) -----------
 
@@ -291,6 +297,8 @@ class PrefixManager(Actor):
             return False
         self._redistributed[prefix] = (src_area, per_area)
         self.counters.bump("prefix_manager.redistributed")
+        for area in per_area:
+            self.counters.bump(f"prefix_manager.redistributed.{area}")
         return True
 
     def _withdraw_redistribution(self, prefix: str) -> bool:

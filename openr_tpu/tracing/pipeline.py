@@ -82,6 +82,13 @@ phase              meaning
                    stacks (``SpfSolver.create_route_for_prefix``)
 ``label_routes``   the backend's node-segment-label MPLS routes
                    (``SpfSolver._build_node_label_routes``)
+``rib_policy``     Decision applying an active RibPolicy to a new RouteDb
+                   (``RibPolicy.apply_policy``): statement matching,
+                   re-weighted next hops, emptied routes dropped
+``prefix_redistribute``  PrefixManager's work on one FIB update: the
+                   supporting-route count of originated prefixes, the
+                   cross-area redistribution of each programmed route
+                   and the KvStore sync of what it advertises
 =================  ========================================================
 
 Surfaces: every phase sample lands in a ``pipeline.{phase}.ms``
@@ -137,6 +144,8 @@ KSP2_RESOLVE = "ksp2_resolve"
 KSP2_TRACE = "ksp2_trace"
 KSP2_ROUTES = "ksp2_routes"
 LABEL_ROUTES = "label_routes"
+RIB_POLICY = "rib_policy"
+PREFIX_REDISTRIBUTE = "prefix_redistribute"
 
 PHASES = (
     HOST_FETCH,
@@ -164,6 +173,8 @@ PHASES = (
     KSP2_TRACE,
     KSP2_ROUTES,
     LABEL_ROUTES,
+    RIB_POLICY,
+    PREFIX_REDISTRIBUTE,
 )
 
 #: phases only the warm-start generation-delta rebuild exercises — a
@@ -189,7 +200,8 @@ PROTECTION_PHASES = (PROTECTION_MINT, PROTECTION_APPLY)
 
 #: phases of a convergence event outside the cold rebuild lifecycle:
 #: Decision's ingest, the backend's patched RouteDb, the governor's
-#: sampled shadow check and Fib — a rebuild measured on its own (the
+#: sampled shadow check, Fib, an active RibPolicy and PrefixManager's
+#: answer to a FIB update — a rebuild measured on its own (the
 #: BENCH_PIPELINE rounds) records nothing here
 EVENT_PHASES = (
     ROUTE_PATCH,
@@ -197,6 +209,8 @@ EVENT_PHASES = (
     SHADOW_COMPARE,
     DECISION_INGEST,
     FIB_PROGRAM,
+    RIB_POLICY,
+    PREFIX_REDISTRIBUTE,
 )
 
 #: phases only a build with KSP2_ED_ECMP prefixes or SR-MPLS node

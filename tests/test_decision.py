@@ -243,6 +243,65 @@ def test_expired_adj_key_removes_node():
     run(main())
 
 
+def test_rib_policy_statement_matches_prefixes_through_a_set():
+    """``matches`` looks a route's prefix up in a set built once, never
+    in the configured list, and matches what the list holds, by prefix
+    or by tag, exactly as before."""
+    from openr_tpu.decision.rib import RibUnicastEntry
+
+    class NoScan(list):
+        def __contains__(self, item):
+            raise AssertionError("a list scan per route")
+
+    def entry(prefix, tags=()):
+        return RibUnicastEntry(prefix=prefix, best_prefix_entry=PrefixEntry(
+            prefix, tags=set(tags)))
+
+    by_prefix = RibPolicyStatement(
+        prefixes=NoScan(["10.0.0.0/24", "10.0.2.0/24"]))
+    assert by_prefix.matches(entry("10.0.2.0/24"))
+    assert not by_prefix.matches(entry("10.0.1.0/24"))
+    by_tag = RibPolicyStatement(tags={"RACK"})
+    assert by_tag.matches(entry("10.0.1.0/24", ["RACK", "X"]))
+    assert not by_tag.matches(entry("10.0.1.0/24", ["X"]))
+    assert not RibPolicyStatement().matches(entry("10.0.0.0/24", ["RACK"]))
+
+
+def test_rib_policy_apply_counts_what_it_did():
+    """apply_policy: the first matching statement re-weights a route's
+    next hops, weight 0 drops one, and a route left with none goes;
+    the stats count each."""
+    from openr_tpu.decision.rib import DecisionRouteDb, RibUnicastEntry
+    from openr_tpu.types import NextHop
+
+    def route(prefix, nbrs, tags=("RACK",)):
+        return RibUnicastEntry(
+            prefix=prefix,
+            nexthops={NextHop(address=f"fe80::{n}", neighbor_node_name=n,
+                              area="a") for n in nbrs},
+            best_prefix_entry=PrefixEntry(prefix, tags=set(tags)))
+
+    clock = SimClock()
+    db = DecisionRouteDb(unicast_routes={
+        r.prefix: r for r in (route("10.0.0.0/24", ["n1", "n2"]),
+                              route("10.0.1.0/24", ["n1"]),
+                              route("10.0.2.0/24", ["n2"], tags=()))})
+    policy = RibPolicy(statements=[RibPolicyStatement(
+        tags={"RACK"}, action=RibRouteActionWeight(
+            default_weight=1, area_to_weight={"a": 2},
+            neighbor_to_weight={"n1": 0}))], valid_until=60.0)
+    stats = policy.apply_policy(db, clock)
+    assert (stats.routes_matched, stats.routes_modified,
+            stats.nexthops_dropped, stats.routes_dropped) == (2, 2, 2, 1)
+    assert set(db.unicast_routes) == {"10.0.0.0/24", "10.0.2.0/24"}
+    (nh,) = db.unicast_routes["10.0.0.0/24"].nexthops
+    assert (nh.neighbor_node_name, nh.weight) == ("n2", 2)
+    assert {nh.weight for nh in db.unicast_routes["10.0.2.0/24"].nexthops} \
+        == {0}
+    # an expired policy touches nothing
+    assert policy.apply_policy(db, SimClock(start=61.0)).routes_matched == 0
+
+
 def test_rib_policy_apply_and_persist(tmp_path):
     async def main():
         clock = SimClock()

@@ -505,12 +505,133 @@ def _decision_and_fib(counters):
     assert counters.histogram(pipeline.hist_key(pipeline.DELTA_EXTRACT))
 
 
+def _decision_with_policy(counters):
+    """Decision with a RibPolicy set builds twice: once because the
+    policy was set, once for a prefix-only change that only the policy
+    keeps cold; each applies the policy in its phase and counts what it
+    did."""
+    import json
+
+    from openr_tpu.config import DecisionConfig
+    from openr_tpu.decision.decision import Decision
+    from openr_tpu.decision.rib_policy import (
+        RibPolicy,
+        RibPolicyStatement,
+        RibRouteActionWeight,
+    )
+    from openr_tpu.messaging.queue import ReplicateQueue
+    from openr_tpu.types import (
+        InitializationEvent,
+        PrefixDatabase,
+        Publication,
+        Value,
+        adj_key,
+        prefix_key,
+    )
+
+    def value(node, payload):
+        return Value(version=1, originator_id=node, ttl=300000,
+                     value=json.dumps(payload).encode())
+
+    def advertise(kv_q, i, tags):
+        pfx = f"10.7.{i}.0/24"
+        db = PrefixDatabase(this_node_name=f"node{i}", area="0",
+                            prefix_entries=[PrefixEntry(pfx, tags=tags)])
+        kv_q.push(Publication(key_vals={
+            prefix_key(f"node{i}", pfx): value(f"node{i}", db.to_wire())}))
+
+    async def main():
+        clock = SimClock()
+        kv_q = ReplicateQueue("kvpubs")
+        decision = Decision(
+            "node0", clock, DecisionConfig(unblock_initial_routes_ms=120000),
+            ReplicateQueue("routeUpdates"),
+            kv_store_updates_reader=kv_q.get_reader(),
+            backend=make_backend(clock, counters), counters=counters)
+        decision.start()
+        decision.on_initialization_event(InitializationEvent.KVSTORE_SYNCED)
+        adj = build_adj_dbs(ring_edges(6))
+        kv_q.push(Publication(key_vals={
+            adj_key(n): value(n, db.to_wire()) for n, db in adj.items()}))
+        advertise(kv_q, 3, {"RACK"})
+        await clock.run_for(1.0)
+        # node0 reaches node3 over node1 and node5; node1, weighed 0,
+        # is the only next hop toward node1's own prefix, which goes
+        decision.set_rib_policy(RibPolicy(
+            statements=[RibPolicyStatement(
+                tags={"RACK"}, action=RibRouteActionWeight(
+                    default_weight=3, neighbor_to_weight={"node1": 0}))],
+            valid_until=clock.now() + 3600))
+        await clock.run_for(1.0)
+        advertise(kv_q, 1, {"RACK"})
+        await clock.run_for(1.0)
+        route = decision.get_route_db().unicast_routes["10.7.3.0/24"]
+        assert {nh.weight for nh in route.nexthops} == {3}
+        assert "10.7.1.0/24" not in decision.get_route_db().unicast_routes
+        await decision.stop()
+
+    _run(main())
+    assert counters.get("decision.policy_forced_full_builds") == 1
+    assert counters.get("decision.rib_policy.routes_matched") == 3
+    assert counters.get("decision.rib_policy.routes_modified") == 3
+    assert counters.get("decision.rib_policy.routes_dropped") == 1
+    assert counters.get("decision.rib_policy.nexthops_dropped") == 3
+
+
+def _prefix_manager_redistributes(counters):
+    """An ABR's PrefixManager re-advertises a FIB route of area a into
+    area b, with a's IGP cost added and a on the stack, in its phase."""
+    from openr_tpu.decision.rib import DecisionRouteUpdate, RibUnicastEntry
+    from openr_tpu.messaging.queue import ReplicateQueue
+    from openr_tpu.prefix_manager.prefix_manager import (
+        PrefixManager,
+        deserialize_prefix_db,
+    )
+    from openr_tpu.types import NextHop, PrefixMetrics
+
+    clock = SimClock()
+    kv_q = ReplicateQueue("kvRequests")
+    requests = kv_q.get_reader()
+    fib_q = ReplicateQueue("fibRouteUpdates")
+    pm = PrefixManager("node0", clock, kv_q,
+                       fib_route_updates_reader=fib_q.get_reader(),
+                       areas=["a", "b"], counters=counters,
+                       probe=PipelineProbe(clock, counters))
+    entry = RibUnicastEntry(
+        prefix="10.7.3.0/24", nexthops={NextHop(address="fe80::1",
+                                                 area="a")},
+        best_prefix_entry=PrefixEntry(
+            "10.7.3.0/24", metrics=PrefixMetrics(distance=1)),
+        best_area="a", igp_cost=2)
+
+    async def main():
+        pm.start()
+        fib_q.push(DecisionRouteUpdate(
+            unicast_routes_to_update={entry.prefix: entry}))
+        await clock.run_for(1.0)
+        await pm.stop()
+
+    _run(main())
+    # the initial sync repeats the persist; KvStore takes it as a no-op
+    sent = set()
+    while (r := requests.try_get()) is not None:
+        sent.add((r.area, r.key, r.value))
+    ((area, _key, value),) = sent
+    assert area == "b"
+    (got,) = deserialize_prefix_db(value).prefix_entries
+    assert got.metrics.distance == 3 and got.area_stack == ["a"]
+    assert counters.get("prefix_manager.redistributed.b") == 1
+    assert counters.get("prefix_manager.redistributed.a") == 0
+
+
 EVENT_PATHS = {
     pipeline.SHADOW_REBUILD: _shadow_checked_builds,
     pipeline.SHADOW_COMPARE: _shadow_checked_builds,
     pipeline.ROUTE_PATCH: _incremental_build,
     pipeline.DECISION_INGEST: _decision_and_fib,
     pipeline.FIB_PROGRAM: _decision_and_fib,
+    pipeline.RIB_POLICY: _decision_with_policy,
+    pipeline.PREFIX_REDISTRIBUTE: _prefix_manager_redistributes,
 }
 
 
